@@ -10,10 +10,12 @@ one budget block buys d pool blocks. On a budget-bound pool that is
 the capacity the guard trades against the collective surcharge
 (``tp_collective_ms_per_token``) when it prices the layout.
 
-Measured panel (subprocess — the forced-host device flag must predate
-the jax import, and ``benchmarks/run.py`` imports every figure into
-one process): two pools drain the SAME decode-heavy trace under the
-same tight ``kv_block_budget``, one pinned to the tp_degrees=(1,)
+Measured panel (a CPU subprocess — the forced-host device flag must
+predate the jax import, ``benchmarks/run.py`` imports every figure into
+one process, and the child is pinned to ``JAX_PLATFORMS=cpu`` so it
+never contends for a chip the parent holds): two pools drain the SAME
+decode-heavy trace under the same tight ``kv_block_budget``, one
+pinned to the tp_degrees=(1,)
 layout, one at the tp_degree=2 layout the guard may now pick. Engine
 admission reserves worst-case blocks per request, so the tp=1 pool
 holds half the residents and its queue waits double; goodput (requests
@@ -143,6 +145,9 @@ def _measure() -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC
     env.pop("XLA_FLAGS", None)
+    # a virtual-CPU-mesh measurement: the child must never reach for an
+    # accelerator, which the parent process may already hold
+    env["JAX_PLATFORMS"] = "cpu"
     code = f"P = {json.dumps(params)}\n" + _CODE_BODY
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=1200)

@@ -4,7 +4,8 @@ latencies and all.
 
 Thin wrapper around the importable entry point
 ``repro.launch.engine_serve`` (also reachable as
-``python -m repro.launch.serve --engine [--exec-mode continuous]``).
+``python -m repro.launch.serve --engine --reduced
+[--exec-mode continuous]``).
 
 Run:  PYTHONPATH=src python examples/serve_llm.py [round|continuous]
 """
@@ -17,7 +18,7 @@ from repro.launch import engine_serve  # noqa: E402
 
 
 def main(exec_mode: str = "round"):
-    engine_serve.main(exec_mode=exec_mode)
+    engine_serve.main(exec_mode=exec_mode, reduced=True)
 
 
 if __name__ == "__main__":

@@ -1,22 +1,24 @@
 """Flash-decoding: one query token against a long KV cache, Pallas TPU.
 
-Grid (batch, q_head, cache_blocks) with the cache sweep innermost and
-sequential; the running max / denominator / accumulator live in VMEM
-scratch. Cache blocks stream HBM->VMEM; the query row and accumulator stay
+The cache sweep is the innermost, sequential grid axis; the running
+max / denominator / accumulator live in VMEM scratch. Cache blocks
+stream HBM->VMEM; the query row and accumulator stay
 resident. Invalid cache slots (ring-buffer holes, unwritten tail) are
 masked via the ``valid`` operand, which also carries per-row positions so
 the same kernel serves linear and ring caches.
 
-Two cache layouts share the same online-softmax body:
+Two cache layouts:
 
-* ``decode_attention``        — dense (B, C, KV, hd) per-slot caches.
+* ``decode_attention``        — dense (B, C, KV, hd) per-slot caches, grid
+  (batch, q_head, cache_blocks).
 * ``paged_decode_attention``  — vLLM-style block pool (N, bs, KV, hd)
   indirected through a per-sequence **block table** (docs/ARCHITECTURE.md
-  §5): the grid sweeps *logical* blocks and the block table, scalar-
-  prefetched so the index map can resolve logical→physical before the
-  DMA is issued, picks the physical pool block to stream. The ragged
-  tail is masked from per-sequence lengths (the paged counterpart of the
-  ``valid`` operand).
+  §5): the grid sweeps (batch, *logical* blocks) and the block table,
+  scalar-prefetched so the index map can resolve logical→physical before
+  the DMA is issued, picks the physical pool block to stream — all KV
+  heads of it at once, so the pool is read in its own layout. The
+  ragged tail is masked from per-sequence lengths (the paged
+  counterpart of the ``valid`` operand).
 """
 from __future__ import annotations
 
@@ -109,11 +111,65 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return jnp.moveaxis(out, 1, 2)
 
 
+def online_softmax_update(q, k, v, mask, scale, m_scr, l_scr, acc_scr):
+    """One flash step: fold a (bs, hd) K/V block into the (rows, 1)
+    running max / denominator and the (rows, hd) accumulator."""
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    s = jnp.where(mask, s, NEG)                   # (rows, bs)
+    m_prev = m_scr[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * alpha + jnp.dot(
+        p, v, preferred_element_type=jnp.float32)
+    m_scr[...] = m_new
+
+
+def softmax_scratch(rows: int, hd: int) -> list:
+    """VMEM carry for :func:`online_softmax_update`: ``rows`` query rows
+    (2-D throughout, one row per query head and chunk position)."""
+    return [pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, hd), jnp.float32)]
+
+
+def pool_block_spec(pool: jax.Array, index_map) -> pl.BlockSpec:
+    """One physical block ``(1, bs, KV, hd)`` of an ``(N, bs, KV, hd)``
+    pool, all KV heads at once.
+
+    The TPU lowering needs a block's last two dims to be divisible by
+    (8, 128) or to equal the array's own. A per-head block
+    ``(1, bs, 1, hd)`` fails that, and viewing the pool as
+    ``(N, bs, KV*hd)`` is no fix: HBM tiles the last two dims, so that
+    view is a relayout copy of the whole pool before every call. Taking
+    every KV head keeps the last two dims ``(KV, hd)`` whole: each grid
+    step streams one contiguous block and the kernel picks heads out of
+    it in VMEM."""
+    _, bs, kv, hd = pool.shape
+    return pl.BlockSpec((1, bs, kv, hd), index_map)
+
+
+def _decode_heads(q_ref, k_ref, v_ref, mask, scale, m_scr, l_scr, acc_scr,
+                  *, n_kv: int, qpk: int):
+    """Fold one (bs, KV, hd) pool block into the carry of every query
+    head: query heads ``h*qpk .. (h+1)*qpk-1`` read KV head ``h``."""
+    for h in range(n_kv):
+        rows = pl.ds(h * qpk, qpk)
+        online_softmax_update(q_ref[0, 0, rows, :].astype(jnp.float32),
+                              k_ref[0, :, h, :].astype(jnp.float32),
+                              v_ref[0, :, h, :].astype(jnp.float32),
+                              mask, scale, m_scr.at[rows], l_scr.at[rows],
+                              acc_scr.at[rows])
+
+
 def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                          m_scr, l_scr, acc_scr, *, scale: float,
-                         block_size: int, n_blocks: int):
+                         block_size: int, n_blocks: int, n_kv: int,
+                         qpk: int):
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -125,30 +181,16 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     # not the table width: blocks past the frontier are skipped entirely
     @pl.when(j * block_size < len_ref[b])
     def _update():
-        q = q_ref[0, 0].astype(jnp.float32)            # (1, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)         # (bs, hd)
-        v = v_ref[0, :, 0].astype(jnp.float32)         # (bs, hd)
         # ragged tail: logical slot j*bs + i is valid iff < seq_len[b]
         slot = jax.lax.broadcasted_iota(jnp.int32, (1, block_size), 1) \
             + j * block_size
-        valid = slot < len_ref[b]
-        s = jnp.dot(q, k.T,
-                    preferred_element_type=jnp.float32) * scale  # (1,bs)
-        s = jnp.where(valid, s, NEG)
-
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
+        _decode_heads(q_ref, k_ref, v_ref, slot < len_ref[b], scale,
+                      m_scr, l_scr, acc_scr, n_kv=n_kv, qpk=qpk)
 
     @pl.when(j == n_blocks - 1)
     def _finalize():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 def _dead_to_null(j, tbl, lens, b, bs):
@@ -170,7 +212,9 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
 
     q (B,1,H,hd); k_pool/v_pool (N, bs, KV, hd) physical blocks;
     block_tables (B, nb) int32 — logical block j of sequence b lives in
-    physical block ``block_tables[b, j]``. Entries past a sequence's
+    physical block ``block_tables[b, j]``. Grid (batch, logical_blocks):
+    each step streams one whole pool block (:func:`pool_block_spec`)
+    and folds it into every query head. Entries past a sequence's
     frontier (``j*bs >= seq_lens[b]``) are **never read**: the index map
     redirects dead columns to the null block and ``pl.when`` skips their
     compute, so the serial sweep is bounded by each sequence's live
@@ -180,56 +224,47 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     logical slots per sequence. Returns (B,1,H,hd).
     """
     B, _, H, hd = q.shape
-    bs = k_pool.shape[1]
-    KV = k_pool.shape[2]
-    qpk = H // KV
+    bs, KV = k_pool.shape[1], k_pool.shape[2]
     nb = block_tables.shape[1]
     if max_blocks is not None:
         nb = max(1, min(nb, max_blocks))
-    qt = jnp.moveaxis(q, 2, 1)  # (B,H,1,hd)
+
+    def kv_map(b, j, tbl, lens, _bs=bs):
+        return (_dead_to_null(j, tbl, lens, b, _bs), 0, 0, 0)
+
+    def q_map(b, j, tbl, lens):
+        return (b, 0, 0, 0)
 
     kernel = functools.partial(_paged_decode_kernel, scale=scale,
-                               block_size=bs, n_blocks=nb)
+                               block_size=bs, n_blocks=nb, n_kv=KV,
+                               qpk=H // KV)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # block_tables, seq_lens
-        grid=(B, H, nb),
+        grid=(B, nb),
         in_specs=[
-            pl.BlockSpec((1, 1, 1, hd),
-                         lambda b, h, j, tbl, lens: (b, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, hd),
-                         lambda b, h, j, tbl, lens, _qpk=qpk, _bs=bs:
-                         (_dead_to_null(j, tbl, lens, b, _bs),
-                          0, h // _qpk, 0)),
-            pl.BlockSpec((1, bs, 1, hd),
-                         lambda b, h, j, tbl, lens, _qpk=qpk, _bs=bs:
-                         (_dead_to_null(j, tbl, lens, b, _bs),
-                          0, h // _qpk, 0)),
+            pl.BlockSpec((1, 1, H, hd), q_map),
+            pool_block_spec(k_pool, kv_map),
+            pool_block_spec(v_pool, kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, 1, hd),
-                               lambda b, h, j, tbl, lens: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1, hd), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, 1, H, hd), q_map),
+        scratch_shapes=softmax_scratch(H, hd),
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, 1, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, 1, H, hd), q.dtype),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
-      qt, k_pool, v_pool)
-    return jnp.moveaxis(out, 1, 2)
+      q, k_pool, v_pool)
 
 
 def _paged_splitk_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref,
                          o_ref, m_ref, l_ref, m_scr, l_scr, acc_scr, *,
                          scale: float, block_size: int,
-                         blocks_per_split: int):
+                         blocks_per_split: int, n_kv: int, qpk: int):
     b = pl.program_id(0)
-    s = pl.program_id(2)
-    jj = pl.program_id(3)
+    s = pl.program_id(1)
+    jj = pl.program_id(2)
     j = s * blocks_per_split + jj  # logical block index
 
     @pl.when(jj == 0)
@@ -240,33 +275,19 @@ def _paged_splitk_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref,
 
     @pl.when(j * block_size < len_ref[b])
     def _update():
-        q = q_ref[0, 0].astype(jnp.float32)            # (1, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)         # (bs, hd)
-        v = v_ref[0, :, 0].astype(jnp.float32)         # (bs, hd)
         slot = jax.lax.broadcasted_iota(jnp.int32, (1, block_size), 1) \
             + j * block_size
-        valid = slot < len_ref[b]
-        sc = jnp.dot(q, k.T,
-                     preferred_element_type=jnp.float32) * scale  # (1,bs)
-        sc = jnp.where(valid, sc, NEG)
-
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(sc - m_new[:, None])
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
+        _decode_heads(q_ref, k_ref, v_ref, slot < len_ref[b], scale,
+                      m_scr, l_scr, acc_scr, n_kv=n_kv, qpk=qpk)
 
     @pl.when(jj == blocks_per_split - 1)
     def _finalize():
         # per-split partials: UNNORMALIZED accumulator plus the split's
         # running max / denominator; the host-side reduction combines
         # them with a stable log-sum-exp
-        o_ref[0, 0, 0] = acc_scr[0].astype(o_ref.dtype)
-        m_ref[0, 0, 0] = m_scr[...]
-        l_ref[0, 0, 0] = l_scr[...]
+        o_ref[0, 0] = acc_scr[...].astype(o_ref.dtype)
+        m_ref[0, 0] = m_scr[...]
+        l_ref[0, 0] = l_scr[...]
 
 
 @functools.partial(jax.jit,
@@ -290,69 +311,60 @@ def paged_decode_attention_splitk(q: jax.Array, k_pool: jax.Array,
     whose every block is dead contributes weight exp(NEG - m) = 0.
     """
     B, _, H, hd = q.shape
-    bs = k_pool.shape[1]
-    KV = k_pool.shape[2]
-    qpk = H // KV
+    bs, KV = k_pool.shape[1], k_pool.shape[2]
     nb = block_tables.shape[1]
     n_splits = max(1, min(n_splits, nb))
     bps = -(-nb // n_splits)          # blocks per split
     n_splits = -(-nb // bps)          # drop splits that would be empty
-    qt = jnp.moveaxis(q, 2, 1)  # (B,H,1,hd)
 
-    def _phys(j, tbl, lens, b, _bs=bs, _nb=nb):
+    def kv_map(b, s, jj, tbl, lens, _bps=bps, _bs=bs, _nb=nb):
         # clamp j for the (padded) final split before the table read;
         # dead blocks (incl. all j >= nb, whose slots are >= lens) are
         # redirected to the null block and skipped in-kernel
-        jc = jnp.minimum(j, _nb - 1)
-        return jnp.where(j * _bs < lens[b], tbl[b, jc], 0)
+        j = s * _bps + jj
+        phys = jnp.where(j * _bs < lens[b], tbl[b, jnp.minimum(j, _nb - 1)],
+                         0)
+        return (phys, 0, 0, 0)
+
+    def part_map(b, s, jj, tbl, lens):
+        return (b, s, 0, 0)
 
     kernel = functools.partial(_paged_splitk_kernel, scale=scale,
-                               block_size=bs, blocks_per_split=bps)
+                               block_size=bs, blocks_per_split=bps,
+                               n_kv=KV, qpk=H // KV)
+    # every partial's block spans its last two dims whole: (H, hd)
+    # accumulators and (H, 1) statistics per (sequence, split)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # block_tables, seq_lens
-        grid=(B, H, n_splits, bps),
+        grid=(B, n_splits, bps),
         in_specs=[
-            pl.BlockSpec((1, 1, 1, hd),
-                         lambda b, h, s, jj, tbl, lens: (b, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, hd),
-                         lambda b, h, s, jj, tbl, lens, _qpk=qpk, _bps=bps:
-                         (_phys(s * _bps + jj, tbl, lens, b),
-                          0, h // _qpk, 0)),
-            pl.BlockSpec((1, bs, 1, hd),
-                         lambda b, h, s, jj, tbl, lens, _qpk=qpk, _bps=bps:
-                         (_phys(s * _bps + jj, tbl, lens, b),
-                          0, h // _qpk, 0)),
+            pl.BlockSpec((1, 1, H, hd),
+                         lambda b, s, jj, tbl, lens: (b, 0, 0, 0)),
+            pool_block_spec(k_pool, kv_map),
+            pool_block_spec(v_pool, kv_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, 1, hd),
-                         lambda b, h, s, jj, tbl, lens: (b, h, s, 0)),
-            pl.BlockSpec((1, 1, 1, 1),
-                         lambda b, h, s, jj, tbl, lens: (b, h, s, 0)),
-            pl.BlockSpec((1, 1, 1, 1),
-                         lambda b, h, s, jj, tbl, lens: (b, h, s, 0)),
+            pl.BlockSpec((1, 1, H, hd), part_map),
+            pl.BlockSpec((1, 1, H, 1), part_map),
+            pl.BlockSpec((1, 1, H, 1), part_map),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1, hd), jnp.float32),
-        ],
+        scratch_shapes=softmax_scratch(H, hd),
     )
     o_p, m_p, l_p = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, n_splits, hd), jnp.float32),
-            jax.ShapeDtypeStruct((B, H, n_splits, 1), jnp.float32),
-            jax.ShapeDtypeStruct((B, H, n_splits, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, n_splits, H, hd), jnp.float32),
+            jax.ShapeDtypeStruct((B, n_splits, H, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, n_splits, H, 1), jnp.float32),
         ],
         interpret=interpret,
     )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
-      qt, k_pool, v_pool)
+      q, k_pool, v_pool)
 
     # reduction pass: rescale each split's partials to the global max
-    m_p, l_p = m_p[..., 0], l_p[..., 0]            # (B,H,S)
-    m_g = jnp.max(m_p, axis=-1, keepdims=True)     # (B,H,1)
+    m_g = jnp.max(m_p, axis=1, keepdims=True)      # (B,1,H,1)
     w = jnp.exp(m_p - m_g)                         # empty split -> 0
-    l_g = jnp.maximum(jnp.sum(l_p * w, axis=-1), 1e-30)      # (B,H)
-    o = jnp.sum(o_p * w[..., None], axis=2) / l_g[..., None]  # (B,H,hd)
+    l_g = jnp.maximum(jnp.sum(l_p * w, axis=1), 1e-30)       # (B,H,1)
+    o = jnp.sum(o_p * w, axis=1) / l_g                       # (B,H,hd)
     return o[:, None].astype(q.dtype)

@@ -1,9 +1,10 @@
 """jit'd dispatch wrappers for the Pallas kernels.
 
-On TPU the kernels lower natively; everywhere else (this CPU container)
-they run in ``interpret=True`` mode, which executes the kernel body with
-the same blocking/masking logic — that is what the per-kernel allclose
-tests validate. ``ref.py`` holds the pure-jnp oracles.
+On the CPU backend the kernels run in ``interpret=True`` mode, which
+executes the kernel body with the same blocking/masking logic — that is
+what the per-kernel allclose tests validate; on any other backend they
+lower natively (tests/test_tpu_compile.py compiles them for v5e).
+``ref.py`` holds the pure-jnp oracles.
 """
 from __future__ import annotations
 
@@ -26,7 +27,7 @@ from repro.kernels.rwkv6_scan import rwkv6_scan as _rwkv
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 def flash_attention(q, k, v, *, positions=None, window: Optional[int] = None,

@@ -7,9 +7,11 @@ queries attend *directly* through the block table, streaming physical
 pool blocks HBM->VMEM exactly like :mod:`repro.kernels.decode_attention`
 but with a whole query chunk resident instead of one row.
 
-Grid (batch, q_head, logical_blocks) with the KV sweep innermost and
-sequential; the online-softmax carry (running max / denominator /
-accumulator, one row per chunk query) lives in VMEM scratch. The block
+Grid (batch, query tile, logical_blocks) with the KV sweep innermost
+and sequential; each step streams one whole pool block (all KV heads)
+into every query head, and the online-softmax carry (running max /
+denominator / accumulator, one row per head and chunk query) lives in
+VMEM scratch. The block
 table and per-sequence lengths are scalar-prefetched so the index map
 resolves logical→physical before the DMA is issued. Three masks happen
 in-kernel:
@@ -32,14 +34,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG = -1.0e30
+from repro.kernels.decode_attention import (NEG, online_softmax_update,
+                                           pool_block_spec)
+
+#: chunk rows per query tile: bounds the VMEM the query block, output
+#: block and softmax carry take, whatever the chunk length
+Q_TILE = 128
 
 
 def _paged_prefill_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                           m_scr, l_scr, acc_scr, *, scale: float,
                           block_size: int, n_blocks: int, chunk: int,
-                          t_pad: int):
+                          q_tile: int, n_kv: int, qpk: int):
     b = pl.program_id(0)
+    i = pl.program_id(1)
     j = pl.program_id(2)
 
     @pl.when(j == 0)
@@ -52,33 +60,28 @@ def _paged_prefill_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     # skip the matmul and leave the carry untouched
     @pl.when(j * block_size < len_ref[b])
     def _update():
-        q = q_ref[0, 0].astype(jnp.float32)        # (t_pad, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)     # (bs, hd)
-        v = v_ref[0, :, 0].astype(jnp.float32)     # (bs, hd)
-        s = jnp.dot(q, k.T,
-                    preferred_element_type=jnp.float32) * scale  # (t_pad,bs)
-        # query row i is at absolute position pos[b]+i = len[b]-chunk+i
-        # and attends logical slots <= its own position (this single
+        # query row r of tile i is at absolute position
+        # pos[b] + i*q_tile + r = len[b] - chunk + i*q_tile + r and
+        # attends logical slots <= its own position (this single
         # comparison is both the causal mask and the ragged tail mask)
-        slot = jax.lax.broadcasted_iota(jnp.int32, (t_pad, block_size), 1) \
+        slot = jax.lax.broadcasted_iota(jnp.int32, (q_tile, block_size), 1) \
             + j * block_size
-        qpos = jax.lax.broadcasted_iota(jnp.int32, (t_pad, block_size), 0) \
-            + (len_ref[b] - chunk)
-        s = jnp.where(slot <= qpos, s, NEG)
-
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
+        qpos = jax.lax.broadcasted_iota(jnp.int32, (q_tile, block_size), 0) \
+            + (len_ref[b] - chunk + i * q_tile)
+        mask = slot <= qpos
+        # query heads h*qpk .. (h+1)*qpk-1 read KV head h of the block
+        for h in range(n_kv):
+            k = k_ref[0, :, h, :].astype(jnp.float32)
+            v = v_ref[0, :, h, :].astype(jnp.float32)
+            for hq in range(h * qpk, (h + 1) * qpk):
+                online_softmax_update(q_ref[0, hq].astype(jnp.float32), k,
+                                      v, mask, scale, m_scr.at[hq],
+                                      l_scr.at[hq], acc_scr.at[hq])
 
     @pl.when(j == n_blocks - 1)
     def _finalize():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -94,43 +97,41 @@ def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
     the table; block_tables (B, nb) int32 — entries for blocks past the
     chunk frontier are never read (they may hold arbitrary values);
     pos (B,) int32 chunk start positions. Returns (B,T,H,hd) where row i
-    attended logical slots ``0..pos[b]+i``.
+    attended logical slots ``0..pos[b]+i``. Grid (batch, query tile,
+    logical block): each step streams one whole pool block
+    (``pool_block_spec``) into every query head of the tile.
     """
     B, T, H, hd = q.shape
     bs, KV = k_pool.shape[1], k_pool.shape[2]
-    qpk = H // KV
     nb = block_tables.shape[1]
-    t_pad = -(-T // 8) * 8  # sublane-align the chunk axis
+    q_tile = min(Q_TILE, -(-T // 8) * 8)  # sublane-aligned
+    t_pad = -(-T // q_tile) * q_tile
     qt = jnp.moveaxis(q, 2, 1)  # (B,H,T,hd)
     if t_pad != T:
         qt = jnp.pad(qt, ((0, 0), (0, 0), (0, t_pad - T), (0, 0)))
     lens = pos.astype(jnp.int32) + T  # live logical slots per sequence
 
+    def kv_map(b, i, j, tbl, lens, _bs=bs):
+        return (jnp.where(j * _bs < lens[b], tbl[b, j], 0), 0, 0, 0)
+
+    def q_map(b, i, j, tbl, lens):
+        return (b, 0, i, 0)
+
     kernel = functools.partial(_paged_prefill_kernel, scale=scale,
                                block_size=bs, n_blocks=nb, chunk=T,
-                               t_pad=t_pad)
+                               q_tile=q_tile, n_kv=KV, qpk=H // KV)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # block_tables, lens
-        grid=(B, H, nb),
+        grid=(B, t_pad // q_tile, nb),
         in_specs=[
-            pl.BlockSpec((1, 1, t_pad, hd),
-                         lambda b, h, j, tbl, lens: (b, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, hd),
-                         lambda b, h, j, tbl, lens, _qpk=qpk, _bs=bs:
-                         (jnp.where(j * _bs < lens[b], tbl[b, j], 0),
-                          0, h // _qpk, 0)),
-            pl.BlockSpec((1, bs, 1, hd),
-                         lambda b, h, j, tbl, lens, _qpk=qpk, _bs=bs:
-                         (jnp.where(j * _bs < lens[b], tbl[b, j], 0),
-                          0, h // _qpk, 0)),
+            pl.BlockSpec((1, H, q_tile, hd), q_map),
+            pool_block_spec(k_pool, kv_map),
+            pool_block_spec(v_pool, kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, t_pad, hd),
-                               lambda b, h, j, tbl, lens: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((t_pad,), jnp.float32),
-            pltpu.VMEM((t_pad,), jnp.float32),
-            pltpu.VMEM((t_pad, hd), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, H, q_tile, hd), q_map),
+        scratch_shapes=[pltpu.VMEM((H, q_tile, 1), jnp.float32),
+                        pltpu.VMEM((H, q_tile, 1), jnp.float32),
+                        pltpu.VMEM((H, q_tile, hd), jnp.float32)],
     )
     out = pl.pallas_call(
         kernel,

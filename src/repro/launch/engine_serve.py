@@ -1,10 +1,11 @@
 """Real-engine serving driver (importable entry point for
 ``python -m repro.launch.serve --engine``; docs/ARCHITECTURE.md §6).
 
-The BCEdge scheduler batching REAL model inference — a reduced
-architecture running under jit on this host, wall-clock latencies and
-all. Requests with token prompts arrive Poisson; utilities are computed
-from measured latencies (Eq. 3).
+The BCEdge scheduler batching REAL model inference — the registered
+architecture at its published widths (or, with ``reduced=True``, the
+2-layer smoke reduction the CPU recipes use) running under jit,
+wall-clock latencies and all. Requests with token prompts arrive
+Poisson; utilities are computed from measured latencies (Eq. 3).
 
 Three serving modes:
 
@@ -18,10 +19,10 @@ Three serving modes:
   runtime, with the ``PoolScheduler`` driving the REAL (b, m_c) action
   per model (docs/RUNTIME.md; continuous-only).
 
-Run:  PYTHONPATH=src python -m repro.launch.serve --engine
-      PYTHONPATH=src python -m repro.launch.serve --engine \
+Run:  PYTHONPATH=src python -m repro.launch.serve --engine --reduced
+      PYTHONPATH=src python -m repro.launch.serve --engine --reduced \
           --exec-mode continuous
-      PYTHONPATH=src python -m repro.launch.serve --engine \
+      PYTHONPATH=src python -m repro.launch.serve --engine --reduced \
           --models qwen3-0.6b,recurrentgemma-2b --exec-mode continuous
 """
 from __future__ import annotations
@@ -31,8 +32,8 @@ from typing import Callable, Dict, Optional, Sequence
 
 import numpy as np
 
-from repro.config import get_reduced_config
-from repro.config.base import ServingConfig
+from repro.config import get_config, get_reduced_config
+from repro.config.base import ModelConfig, ServingConfig
 from repro.core.interference import NNInterferencePredictor
 from repro.core.sac import SACAgent, SACConfig
 from repro.core.utility import utility
@@ -58,6 +59,16 @@ def _serve_max_seq(shared_prefix_tokens: int, default: int = 128) -> int:
     return max(default, bucket + 64)
 
 
+def load_config(arch: str, reduced: bool) -> ModelConfig:
+    """The registered config at its published widths, or its smoke
+    reduction when ``reduced`` (an explicit choice, as in
+    ``repro.launch.train --reduced``)."""
+    cfg = get_reduced_config(arch) if reduced else get_config(arch)
+    print(f"loading {'reduced' if reduced else 'full'} {cfg.name} "
+          f"(d={cfg.d_model}, L={cfg.n_layers})...")
+    return cfg
+
+
 def _report(served: int, violations: int, rounds: int, lat_sum: float,
             dur: float, slo_ms: float, label: str) -> None:
     print(f"[{label}] served {served} requests in {dur:.1f}s "
@@ -67,11 +78,10 @@ def _report(served: int, violations: int, rounds: int, lat_sum: float,
 
 
 def serve_round(arch: str = "qwen3-0.6b", duration_s: float = 20.0,
-                rps: float = 12.0, slo_ms: float = 1500.0) -> None:
+                rps: float = 12.0, slo_ms: float = 1500.0,
+                reduced: bool = False) -> None:
     """Round mode: SAC picks b per round, engine runs it to completion."""
-    cfg = get_reduced_config(arch)
-    print(f"loading reduced {cfg.name} "
-          f"(d={cfg.d_model}, L={cfg.n_layers})...")
+    cfg = load_config(arch, reduced)
     engine = InferenceEngine(cfg, max_seq=128)
     # warm the compile cache
     engine.generate([np.arange(8, dtype=np.int32)], max_new_tokens=2)
@@ -147,7 +157,7 @@ def serve_continuous(arch: str = "qwen3-0.6b", duration_s: float = 20.0,
                      token_budget: Optional[int] = None,
                      prefix_cache: bool = False,
                      shared_prefix_tokens: int = 0,
-                     spec_k: int = 0) -> None:
+                     spec_k: int = 0, reduced: bool = False) -> None:
     """Continuous mode: arrivals are submitted into the slot engine as
     they land and join the running batch at iteration boundaries. With
     ``kv_layout="paged"``, ``kv_block_budget`` caps the engine's block
@@ -160,14 +170,12 @@ def serve_continuous(arch: str = "qwen3-0.6b", duration_s: float = 20.0,
     decoding: up to k n-gram drafts per slot verified in one forward
     (docs/ARCHITECTURE.md §speculation); models whose cache cannot
     rewind serve with it off."""
-    cfg = get_reduced_config(arch)
+    cfg = load_config(arch, reduced)
     if spec_k > 0 and not supports_speculation(cfg):
         print(f"{cfg.name}: cache not rewindable "
               f"(recurrent/windowed layers); serving with spec_k=0")
         spec_k = 0
-    print(f"loading reduced {cfg.name} "
-          f"(d={cfg.d_model}, L={cfg.n_layers}), "
-          f"{max_slots} slots, {kv_layout} KV, "
+    print(f"[continuous] {max_slots} slots, {kv_layout} KV, "
           f"token budget {token_budget or 'uncapped'}, "
           f"prefix cache {'on' if prefix_cache else 'off'}, "
           f"spec_k {spec_k or 'off'}...")
@@ -225,7 +233,7 @@ def serve_pool(models: Sequence[str] = ("qwen3-0.6b", "recurrentgemma-2b"),
                preempt_mode: str = "auto",
                prefix_cache: bool = False,
                shared_prefix_tokens: int = 0,
-               spec_k: int = 0
+               spec_k: int = 0, reduced: bool = False
                ) -> Dict[str, Dict[str, float]]:
     """Multi-model pool serve (docs/RUNTIME.md): Poisson arrivals per
     model are routed by deadline into a ``ModelInstancePool`` of live
@@ -245,10 +253,7 @@ def serve_pool(models: Sequence[str] = ("qwen3-0.6b", "recurrentgemma-2b"),
     depth as the FOURTH scheduler axis (k ∈ {0, k/2, k}; rewind-capable
     models only, docs/ARCHITECTURE.md §speculation).
     Returns the pool's per-model report."""
-    cfgs = {m: get_reduced_config(m) for m in models}
-    for m, cfg in cfgs.items():
-        print(f"loading reduced {cfg.name} "
-              f"(d={cfg.d_model}, L={cfg.n_layers})...")
+    cfgs = {m: load_config(m, reduced) for m in models}
     pool = ModelInstancePool(cfgs, max_instances=max_instances,
                              max_slots=max_slots,
                              max_seq=_serve_max_seq(shared_prefix_tokens),
@@ -336,15 +341,18 @@ def serve_http(models: Sequence[str] = ("qwen3-0.6b",),
                kv_block_budget: Optional[int] = None,
                backpressure: bool = True, max_queue_depth: int = 8,
                control_ms: float = 500.0,
-               ready: Optional[Callable[[int], None]] = None,
-               configs: Optional[Dict] = None) -> None:
+               ready: Optional[Callable[..., None]] = None,
+               configs: Optional[Dict] = None,
+               reduced: bool = False) -> None:
     """Push-mode HTTP serving (docs/RUNTIME.md §11): the pool runs on a
     background :class:`~repro.serving.driver.ServingDriver` thread with
     the ``PoolScheduler`` re-deciding (b, m_c) on a wall-clock tick, and
     an asyncio :class:`~repro.launch.server.ServingFrontend` streams
-    per-token events over HTTP until interrupted. ``ready(port)`` fires
-    once the socket is bound (``port=0`` picks an ephemeral port).
-    ``configs`` overrides the registry lookup with explicit
+    per-token events over HTTP until interrupted, or until another
+    thread calls the front-end's ``close_threadsafe()``. ``ready(fe)``
+    fires with the :class:`~repro.launch.server.ServingFrontend` once
+    its socket is bound (``fe.port``; ``port=0`` picks an ephemeral
+    port). ``configs`` overrides the registry lookup with explicit
     ``ModelConfig`` objects — tools/server_smoke.py serves a tiny
     throwaway model that way."""
     import asyncio
@@ -352,10 +360,7 @@ def serve_http(models: Sequence[str] = ("qwen3-0.6b",),
     from repro.launch.server import ServingFrontend
     from repro.serving.driver import ServingDriver
 
-    cfgs = configs or {m: get_reduced_config(m) for m in models}
-    for m, cfg in cfgs.items():
-        print(f"loading reduced {cfg.name} "
-              f"(d={cfg.d_model}, L={cfg.n_layers})...")
+    cfgs = configs or {m: load_config(m, reduced) for m in models}
     pool = ModelInstancePool(cfgs, max_instances=max_instances,
                              max_slots=max_slots, max_seq=128, seed=seed,
                              kv_layout=kv_layout,
@@ -385,7 +390,7 @@ def serve_http(models: Sequence[str] = ("qwen3-0.6b",),
                   f"http://{host}:{fe.port} "
                   f"(backpressure {'on' if backpressure else 'off'})")
             if ready is not None:
-                ready(fe.port)
+                ready(fe)
             try:
                 await fe.serve_forever()
             except asyncio.CancelledError:
@@ -410,9 +415,11 @@ def main(exec_mode: str = "round", arch: str = "qwen3-0.6b",
          preempt_mode: str = "auto", prefix_cache: bool = False,
          shared_prefix_tokens: float = 0.0, spec_k: int = 0,
          serve_http_port: Optional[int] = None,
-         backpressure: bool = True, max_queue_depth: int = 8) -> None:
+         backpressure: bool = True, max_queue_depth: int = 8,
+         reduced: bool = False) -> None:
     if serve_http_port is not None:
         serve_http(models or [arch], port=serve_http_port, slo_ms=slo_ms,
+                   reduced=reduced,
                    max_instances=max_instances,
                    kv_layout=kv_layout if kv_layout else "paged",
                    kv_block_budget=kv_block_budget,
@@ -430,7 +437,7 @@ def main(exec_mode: str = "round", arch: str = "qwen3-0.6b",
                    preempt_mode=preempt_mode,
                    prefix_cache=prefix_cache,
                    shared_prefix_tokens=int(shared_prefix_tokens),
-                   spec_k=spec_k)
+                   spec_k=spec_k, reduced=reduced)
     elif exec_mode == "continuous":
         serve_continuous(arch, duration_s, rps, slo_ms,
                          kv_layout=kv_layout,
@@ -438,7 +445,7 @@ def main(exec_mode: str = "round", arch: str = "qwen3-0.6b",
                          token_budget=token_budget,
                          prefix_cache=prefix_cache,
                          shared_prefix_tokens=int(shared_prefix_tokens),
-                         spec_k=spec_k)
+                         spec_k=spec_k, reduced=reduced)
     else:
         if kv_layout != "dense":
             print("round mode always uses the dense per-round cache; "
@@ -448,7 +455,7 @@ def main(exec_mode: str = "round", arch: str = "qwen3-0.6b",
             print("chunked prefill / preemption / prefix caching / "
                   "speculation / KV offload are continuous-engine "
                   "features; ignored in round mode")
-        serve_round(arch, duration_s, rps, slo_ms)
+        serve_round(arch, duration_s, rps, slo_ms, reduced=reduced)
 
 
 if __name__ == "__main__":

@@ -11,14 +11,8 @@ from __future__ import annotations
 import jax
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """jax >= 0.4.35 takes explicit axis_types; older versions (like the
-    CI container's) have no ``jax.sharding.AxisType`` and default every
-    axis to Auto anyway."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+def _auto_axes(n_axes: int) -> tuple:
+    return (jax.sharding.AxisType.Auto,) * n_axes
 
 
 def _require_devices(n: int, what: str) -> None:
@@ -39,7 +33,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     n = 512 if multi_pod else 256
     _require_devices(n, f"make_production_mesh(multi_pod={multi_pod})")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto_axes(len(axes)))
 
 
 def make_debug_mesh(n_data: int = 2, n_model: int = 2):
@@ -47,7 +41,7 @@ def make_debug_mesh(n_data: int = 2, n_model: int = 2):
     _require_devices(n_data * n_model,
                      f"make_debug_mesh({n_data}, {n_model})")
     return jax.make_mesh((n_data, n_model), ("data", "model"),
-                         **_axis_type_kwargs(2))
+                         axis_types=_auto_axes(2))
 
 
 def make_tp_mesh(tp_degree: int, devices=None):
@@ -67,7 +61,7 @@ def make_tp_mesh(tp_degree: int, devices=None):
         raise ValueError(
             f"make_tp_mesh({tp_degree}) given {len(devices)} devices")
     return jax.sharding.Mesh(np.asarray(devices), ("model",),
-                             **_axis_type_kwargs(1))
+                             axis_types=_auto_axes(1))
 
 
 def batch_axes(mesh) -> tuple:

@@ -7,15 +7,20 @@ the real-JAX engine (``--engine``), in round or continuous execution mode
     PYTHONPATH=src python -m repro.launch.serve --exec-mode continuous \
         --decode-steps 6
     PYTHONPATH=src python -m repro.launch.serve --engine --arch qwen3-0.6b
-    PYTHONPATH=src python -m repro.launch.serve --engine \
+    PYTHONPATH=src python -m repro.launch.serve --engine --reduced \
         --models qwen3-0.6b,recurrentgemma-2b --exec-mode continuous \
         --max-instances 4
     PYTHONPATH=src python -m repro.launch.serve --engine --serve-http \
         --port 8808 --models qwen3-0.6b
+
+``--engine`` serves the registered config at its published widths;
+``--reduced`` swaps in the 2-layer smoke reduction the CPU recipes use.
 """
 from __future__ import annotations
 
 import argparse
+
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -36,7 +41,10 @@ def main() -> None:
                     help="mean decode iterations per request (geometric); "
                          ">1 makes the workload autoregressive")
     ap.add_argument("--engine", action="store_true",
-                    help="serve a real reduced model instead of the sim")
+                    help="serve a real model instead of the sim")
+    ap.add_argument("--reduced", action="store_true",
+                    help="--engine: serve the 2-layer smoke reduction "
+                         "of each arch instead of its published widths")
     ap.add_argument("--arch", default="qwen3-0.6b")
     ap.add_argument("--models", default=None,
                     help="comma-separated arch ids for the multi-model "
@@ -134,6 +142,7 @@ def main() -> None:
         ap.error("--preempt-mode swap needs --kv-host-blocks > 0 "
                  "(there is nowhere to swap to)")
 
+    enable_compile_cache()
     if args.engine:
         from repro.launch import engine_serve
 
@@ -152,7 +161,8 @@ def main() -> None:
                           serve_http_port=args.port if args.serve_http
                           else None,
                           backpressure=not args.no_backpressure,
-                          max_queue_depth=args.max_queue_depth)
+                          max_queue_depth=args.max_queue_depth,
+                          reduced=args.reduced)
         return
 
     from repro.config.base import ServingConfig

@@ -94,12 +94,19 @@ class ServingFrontend:
         self.n_throttled = 0
         self.n_disconnects = 0
         self._server: Optional[asyncio.base_events.Server] = None
+        self._loop: Optional[asyncio.AbstractEventLoop] = None
 
     # ---- lifecycle -------------------------------------------------------
     async def start(self) -> None:
+        self._loop = asyncio.get_running_loop()
         self._server = await asyncio.start_server(
             self._handle, self.host, self.port)
         self.port = self._server.sockets[0].getsockname()[1]
+
+    def close_threadsafe(self) -> None:
+        """Stop listening, from any thread: ``serve_forever`` then ends
+        with ``CancelledError`` on the serving loop."""
+        self._loop.call_soon_threadsafe(self._server.close)
 
     async def stop(self) -> None:
         if self._server is not None:

@@ -82,9 +82,13 @@ def init_params(rng, cfg: ModelConfig, dtype=jnp.float32) -> Dict:
     if n_units:
         units = []
         for pos, kind in enumerate(cfg.block_pattern):
-            per_unit = [_layer_init(next(ks), cfg, kind, dtype, cross)
-                        for _ in range(n_units)]
-            units.append(_stack(per_unit))
+            # one vmapped init per kind (bit-identical to initialising
+            # the units one by one and stacking), so the traced program
+            # does not grow with depth
+            keys = jnp.stack([next(ks) for _ in range(n_units)])
+            units.append(jax.vmap(functools.partial(
+                _layer_init, cfg=cfg, kind=kind, dtype=dtype,
+                cross=cross))(keys))
         p["units"] = tuple(units)
     if tail_kinds:
         p["tail"] = tuple(_layer_init(next(ks), cfg, kind, dtype, cross)
@@ -654,18 +658,24 @@ class Model:
     def abstract_params(self, dtype=jnp.float32):
         return abstract_params(self.cfg, dtype)
 
+    def _trunk(self, params, batch, *, return_cache: bool, remat: bool):
+        """Embed ``batch`` and run the whole trunk: (x, aux, cache)."""
+        cfg = self.cfg
+        x, positions, mrope = _embed_inputs(params, batch, cfg)
+        ctx = {"positions": positions, "mrope_positions": mrope,
+               "attn_impl": self.attn_impl, "chunk": self.chunk,
+               "return_cache": return_cache}
+        if cfg.enc_dec:
+            ctx["enc_out"] = _encoder_apply(params, batch["frontend_embeds"],
+                                            cfg, params["frontend_proj"])
+        return _trunk_full(params, x, cfg, ctx, remat=remat)
+
     # ---- forward: train ----------------------------------------------
     def loss(self, params, batch) -> jax.Array:
         cfg = self.cfg
         params = self._cast(params)
-        x, positions, mrope = _embed_inputs(params, batch, cfg)
-        ctx = {"positions": positions, "mrope_positions": mrope,
-               "attn_impl": self.attn_impl, "chunk": self.chunk,
-               "return_cache": False}
-        if cfg.enc_dec:
-            ctx["enc_out"] = _encoder_apply(params, batch["frontend_embeds"],
-                                            cfg, params["frontend_proj"])
-        x, aux, _ = _trunk_full(params, x, cfg, ctx, remat=self.remat)
+        x, aux, _ = self._trunk(params, batch, return_cache=False,
+                                remat=self.remat)
         if cfg.frontend is not None and not cfg.enc_dec:
             F = batch["frontend_embeds"].shape[1]
             x = x[:, F:, :]
@@ -674,18 +684,19 @@ class Model:
 
     # ---- forward: prefill ----------------------------------------------
     def prefill(self, params, batch):
-        cfg = self.cfg
         params = self._cast(params)
-        x, positions, mrope = _embed_inputs(params, batch, cfg)
-        ctx = {"positions": positions, "mrope_positions": mrope,
-               "attn_impl": self.attn_impl, "chunk": self.chunk,
-               "return_cache": True}
-        if cfg.enc_dec:
-            ctx["enc_out"] = _encoder_apply(params, batch["frontend_embeds"],
-                                            cfg, params["frontend_proj"])
-        x, _, cache = _trunk_full(params, x, cfg, ctx, remat=False)
-        logits = _lm_logits(params, x[:, -1:, :], cfg)
+        x, _, cache = self._trunk(params, batch, return_cache=True,
+                                  remat=False)
+        logits = _lm_logits(params, x[:, -1:, :], self.cfg)
         return logits, cache
+
+    def logits(self, params, batch) -> jax.Array:
+        """Full-sequence forward: (B,S,V) logits at every position, no
+        cache — the plain reference a served greedy sequence is checked
+        against (teacher-forced over prompt + generated tokens)."""
+        params = self._cast(params)
+        x, _, _ = self._trunk(params, batch, return_cache=False, remat=False)
+        return _lm_logits(params, x, self.cfg)
 
     # ---- forward: chunked prefill ---------------------------------------
     def prefill_chunk(self, params, cache, batch):

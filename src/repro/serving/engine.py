@@ -16,8 +16,9 @@ actual model (``repro.launch.engine_serve``):
 Both keep the jit compile cache small via shape bucketing: prompts are
 padded to power-of-two-ish buckets, and the continuous engine decodes a
 single fixed (n_slots, cache_len) shape for its whole lifetime.
-On CPU they serve the reduced configs; on a TPU pod the same code runs
-the full configs under the production mesh.
+The CPU recipes serve the reduced configs; on one TPU v5e chip the same
+code serves a config at its published widths (chip_smoke.py), and a
+config one chip cannot hold spans a tensor-parallel ``mesh``.
 """
 from __future__ import annotations
 
@@ -896,11 +897,11 @@ class ContinuousBatchingEngine:
                 raise ValueError(
                     f"engine mesh needs a 'model' axis, got "
                     f"{mesh.axis_names}")
-            if not self.chunked:
+            tp = int(mesh.shape["model"])
+            if tp > 1 and not self.chunked:
                 raise NotImplementedError(
                     "tensor-parallel serving needs the chunked-prefill "
                     "path (frontend engines stay single-device)")
-            tp = int(mesh.shape["model"])
             if cfg.n_kv_heads % tp or cfg.n_heads % tp:
                 raise ValueError(
                     f"{cfg.name}: tp_degree {tp} must divide n_heads "
@@ -913,11 +914,11 @@ class ContinuousBatchingEngine:
             # below stays per-instance. Sharing requires the SAME mesh:
             # the donor's params live in that layout and its jits carry
             # its in/out shardings, so the pool keys templates by
-            # (model, tp_degree).
+            # (model, device ids).
             if getattr(share_from, "mesh", None) != mesh:
                 raise ValueError(
                     "share_from donor spans a different mesh; instances "
-                    "share weights/jit only at the same TP degree")
+                    "share weights/jit only on the same devices")
             self.model = share_from.model
             self.params = share_from.params
             self._prefill = share_from._prefill
@@ -928,10 +929,16 @@ class ContinuousBatchingEngine:
                 self._verify = jax.jit(self.model.verify_step)
         else:
             self.model = build_model(cfg, remat=False)
-            self.params = self.model.init(jax.random.PRNGKey(seed), dtype)
-            if mesh is not None:
-                self.params = jax.device_put(
-                    self.params, engine_param_shardings(mesh, self.params))
+            key = jax.random.PRNGKey(seed)
+            if mesh is None:
+                self.params = self.model.init(key, dtype)
+            else:
+                # generated in place, shard by shard (out_shardings): a
+                # model no single device can hold never exists whole
+                self.params = jax.jit(
+                    functools.partial(self.model.init, dtype=dtype),
+                    out_shardings=engine_param_shardings(
+                        mesh, self.model.abstract_params(dtype)))(key)
             self._prefill = jax.jit(self.model.prefill)
             if mesh is None:
                 self._prefill_chunk = jax.jit(self.model.prefill_chunk) \
@@ -953,7 +960,7 @@ class ContinuousBatchingEngine:
                 raise ValueError(
                     "kv_host_blocks needs kv_layout='paged' (the host "
                     "tier swaps block-granular KV)")
-            if mesh is not None:
+            if mesh is not None and mesh.size > 1:
                 raise ValueError(
                     "the host KV tier is single-device for now: swap-in "
                     "writes outside jit would drop the pool's sharding")
@@ -973,40 +980,37 @@ class ContinuousBatchingEngine:
             self.allocator = BlockAllocator(kv_blocks, block_size,
                                             host_blocks=kv_host_blocks)
             # pool array includes the null block 0 (id range 0..kv_blocks)
-            self.cache = self.model.init_paged_cache(
-                self.n_slots, self.cache_len, kv_blocks + 1, block_size,
-                dtype)
+            init_cache = functools.partial(
+                self.model.init_paged_cache, self.n_slots, self.cache_len,
+                kv_blocks + 1, block_size, dtype)
             self.block_tables = np.zeros(
                 (self.n_slots, self.blocks_per_slot), np.int32)
-            if self.swap_ok:
-                self.host_pool = self._make_host_pool()
-                self.allocator.spill_fn = self._spill_block
-                self.allocator.unspill_fn = self._unspill_block
-            else:
-                self.host_pool = None
         else:
             self.block_size = 0
             self.allocator = None
             self.block_tables = None
-            self.host_pool = None
             # speculative verify writes up to spec_max rows past a slot's
             # frontier before acceptance is known; dynamic_update_slice
             # CLAMPS out-of-bounds starts (it would silently overwrite
             # valid earlier rows), so the physical slab carries a scratch
             # margin. cache_len stays the LOGICAL capacity everywhere.
-            self.cache = self.model.init_cache(
-                self.n_slots, self.cache_len + self.spec_max, dtype)
-        if mesh is not None:
-            # place the cache across the mesh (heads sharded, block axis
-            # whole: tables gather it locally on every shard), then jit
+            init_cache = functools.partial(
+                self.model.init_cache, self.n_slots,
+                self.cache_len + self.spec_max, dtype)
+        if mesh is None:
+            self.cache = init_cache()
+        else:
+            # made in place like the params: heads sharded, block axis
+            # whole (tables gather it locally on every shard). Then jit
             # the step functions with explicit NamedSharding in/out
             # specs. The batch dict — tokens, pos, block tables — is
             # replicated: every shard sees the same schedule. The same
             # cache shardings tree serves the per-slot staging caches
             # non-fused layouts chunk into (same pytree structure, and
             # specs never shard the batch/length dims that differ).
-            cshard = engine_cache_shardings(mesh, self.cache)
-            self.cache = jax.device_put(self.cache, cshard)
+            cshard = engine_cache_shardings(mesh,
+                                            jax.eval_shape(init_cache))
+            self.cache = jax.jit(init_cache, out_shardings=cshard)()
             if self._decode is None:
                 pshard = engine_param_shardings(mesh, self.params)
                 rep = replicated(mesh)
@@ -1023,6 +1027,11 @@ class ContinuousBatchingEngine:
                     in_shardings=(pshard, cshard, rep),
                     out_shardings=(rep, cshard)) \
                     if supports_speculation(cfg) else None
+        self.host_pool = None
+        if self.swap_ok:
+            self.host_pool = self._make_host_pool()
+            self.allocator.spill_fn = self._spill_block
+            self.allocator.unspill_fn = self._unspill_block
         self.pos = np.zeros((self.n_slots,), np.int32)
         self.pending_tok = np.zeros((self.n_slots,), np.int32)
         self.slots = [_Slot() for _ in range(self.n_slots)]

@@ -148,6 +148,13 @@ class PoolResult:
         return self.rejected or self.latency_ms > self.slo_ms
 
 
+def _mesh_devices(mesh) -> tuple:
+    """Devices a mesh spans; empty for an unplaced engine (None: it runs
+    on the default device). An instance's device set, and with the
+    model name the key of its weight/jit template."""
+    return tuple(mesh.devices.flat) if mesh is not None else ()
+
+
 class ModelInstance:
     """One live engine instance plus its lifecycle state and the pool's
     per-instance bookkeeping (resident requests, Eq.-1 slot share)."""
@@ -173,6 +180,12 @@ class ModelInstance:
     @property
     def free_capacity(self) -> int:
         return self.engine.n_slots - self.n_resident
+
+    @property
+    def devices(self) -> tuple:
+        """Devices the engine's mesh spans (``_mesh_devices``)."""
+        return _mesh_devices(self.engine.mesh if self.engine is not None
+                             else None)
 
     @property
     def slo_sum_ms(self) -> float:
@@ -251,14 +264,11 @@ class ModelInstancePool:
         #: change drains mismatched instances and respawns via scale_to.
         self.tp_degrees: Dict[str, int] = {
             m: max(1, tp_degree) for m in configs}
+        #: with ``n_devices`` set, the shared device set is the first
+        #: ``n_devices`` of ``jax.devices()`` and every spawn is carved
+        #: from its FREE devices (``_carve_mesh``), so co-resident
+        #: instances sit on disjoint devices, tp=1 included
         self.n_devices = n_devices
-        #: one mesh per degree, lazily built over the FIRST tp devices —
-        #: value-equal meshes are what lets same-degree instances share
-        #: the weight/jit template (engine.share_from requires it). On
-        #: the symmetric host meshes this runtime targets, which slice
-        #: an instance sits on is interchangeable; the scheduler prices
-        #: the device BUDGET, not slice identity.
-        self._meshes: Dict[int, object] = {}
         #: target grant for a paged instance; default = dense-equivalent
         #: worst case. Sizing it from measured occupancy
         #: (``occupancy_tokens_per_seq``) is how a paged pool fits more
@@ -315,10 +325,10 @@ class ModelInstancePool:
             m: [] for m in self.configs}
         self.slot_caps: Dict[str, int] = {m: max_slots for m in self.configs}
         self.queues: Dict[str, List[tuple]] = {m: [] for m in self.configs}
-        #: weight/jit donors keyed (model, tp_degree) — instances share
-        #: a template only at the same degree (sharded params live on
-        #: that degree's mesh)
-        self._templates: Dict[Tuple[str, int],
+        #: weight/jit donors keyed (model, device ids) — instances share
+        #: a template only on the same devices (engine.share_from needs
+        #: an equal mesh: the params live there)
+        self._templates: Dict[Tuple[str, tuple],
                               ContinuousBatchingEngine] = {}
         self.admission_log: List[Tuple[int, int]] = []  # (request, instance)
         self.retired: List[ModelInstance] = []
@@ -433,19 +443,28 @@ class ModelInstancePool:
         TP degree jointly partition the shared device set."""
         return sum(i.tp_degree for i in self.live())
 
-    def _tp_mesh(self, tp: int):
-        """The shared 1D ``("model",)`` mesh for degree ``tp`` (None for
-        tp=1: single-device engines never touch jax device state).
-        Cached per degree so every same-degree instance spans a
-        value-equal mesh and can share the weight/jit template."""
-        if tp <= 1:
+    def _carve_mesh(self, tp: int):
+        """The 1D ``("model",)`` mesh a new instance at degree ``tp``
+        spans, carved from the devices no live instance holds — of the
+        first ``n_devices`` of ``jax.devices()``, or of all of them when
+        ``n_devices`` is None — so placed instances never share a
+        device. A tp=1 instance gets a one-device mesh when
+        ``n_devices`` is set, and stays unplaced otherwise (None: it
+        runs on the default device and holds no device of the set)."""
+        if tp <= 1 and self.n_devices is None:
             return None
-        mesh = self._meshes.get(tp)
-        if mesh is None:
-            from repro.launch.mesh import make_tp_mesh
-            mesh = make_tp_mesh(tp)
-            self._meshes[tp] = mesh
-        return mesh
+        import jax
+        from repro.launch.mesh import make_tp_mesh
+        devices = jax.devices()
+        if self.n_devices is not None:
+            devices = devices[:self.n_devices]
+        held = {d for i in self.live() for d in i.devices}
+        free = [d for d in devices if d not in held]
+        if len(free) < tp:
+            raise RuntimeError(
+                f"tp_degree={tp} needs {tp} free devices, have "
+                f"{len(free)} of {len(devices)}")
+        return make_tp_mesh(tp, free[:tp])
 
     def set_tp_degree(self, model: str, tp: int) -> None:
         """The fifth knob (docs/RUNTIME.md §10): TP degree for future
@@ -550,16 +569,18 @@ class ModelInstancePool:
                   "kv_host_blocks": self.kv_host_blocks if tp == 1 else 0}
         if self.spec_cap > 0 and supports_speculation(self.configs[model]):
             kw["spec_k"] = self.spec_cap
-        tmpl = self._templates.get((model, tp))
+        mesh = self._carve_mesh(tp)
+        key = (model, _mesh_devices(mesh))
+        tmpl = self._templates.get(key)
         eng = ContinuousBatchingEngine(
             self.configs[model], max_slots=self.max_slots,
             max_seq=self.max_seq, seed=self.seed, share_from=tmpl,
             token_budget=self.token_budgets.get(model),
-            mesh=self._tp_mesh(tp), **kw)
+            mesh=mesh, **kw)
         # spawn into the CURRENT scheduler-set depth (≤ the built cap)
         eng.spec_k = min(self.spec_ks.get(model, 0), eng.spec_max)
         if tmpl is None:
-            self._templates[(model, tp)] = eng
+            self._templates[key] = eng
         inst = ModelInstance(self._next_iid, model, eng, kv_blocks=charge,
                              tp_degree=tp)
         # push-mode hooks (docs/RUNTIME.md §11): the engine reports
@@ -668,13 +689,14 @@ class ModelInstancePool:
                 else:
                     keep.append(inst)
             self.instances[model] = keep
+            # drop weight/jit templates no live instance of the model
+            # spans any more, so those devices' memory really frees
+            # (live instances hold their own references: always safe)
+            spans = {i.devices for i in keep}
+            for key in [k for k in self._templates
+                        if k[0] == model and k[1] not in spans]:
+                self._templates.pop(key)
             if not keep:
-                # last instance gone: drop the shared weight/jit
-                # templates (every degree) so the model's memory really
-                # frees (live instances hold their own references, so
-                # this is always safe)
-                for key in [k for k in self._templates if k[0] == model]:
-                    self._templates.pop(key)
                 if retired_any:
                     # per-model preemption bookkeeping dies with the
                     # last instance: a model respawned after scale_to(0)

@@ -245,6 +245,32 @@ def test_pool_preempt_mode(mode, swaps):
         assert a.n_host_free + a.n_host_cached == a.n_host_blocks
 
 
+@pytest.mark.parametrize("n_devices", [None, 1], ids=["unplaced", "carved"])
+def test_pool_swap_resume_token_identical(n_devices):
+    """A swap-preempted request resumes token-identical to an
+    uninterrupted run and leaves no block live on either tier — also on
+    an instance carved onto a one-device mesh (``n_devices`` set), where
+    the engine runs its mesh path and swap-ins write outside jit."""
+    pool, rng = _calibrated_swap_pool(preempt_mode="swap",
+                                      n_devices=n_devices)
+    eng = pool.running("tiny")[0].engine
+    assert (eng.mesh is not None) == (n_devices is not None)
+    replay = np.random.default_rng()
+    replay.bit_generator.state = rng.bit_generator.state
+    prompts = [_prompt(replay, 8), _prompt(replay, 6)]
+    hog, urgent = _preempt_once(pool, rng)
+    got = {r.request_id: r.tokens for r in pool.run_until_drained()}
+    assert pool.n_swap_preempted == 1
+    ref = make_cont_engine(TINY, max_slots=1, kv_layout="paged",
+                           block_size=8)
+    for rid, p, n in ((hog, prompts[0], 24), (urgent, prompts[1], 2)):
+        want = ref.run([p], max_new_tokens=n)[0].tokens
+        np.testing.assert_array_equal(got[rid], want)
+    al = pool.running("tiny")[0].engine.allocator
+    assert al.n_live == 0 and al.n_reserved == 0 and al.n_host_live == 0
+    assert al.n_host_free + al.n_host_cached == al.n_host_blocks
+
+
 def test_pool_auto_mode_prices_with_calibrated_fits():
     """With both fits calibrated, auto picks the cheaper side — force
     each side with extreme stub costs."""

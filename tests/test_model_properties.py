@@ -1,9 +1,13 @@
 """Property tests on model-layer invariants (hypothesis)."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
+
+from conftest import KIND_CFGS
 
 from repro.models.rope import apply_rope, default_positions
 
@@ -119,3 +123,27 @@ def test_sliding_window_blocks_distant_context(window):
                                np.asarray(pert[:, window:, :]), atol=1e-5)
     assert not np.allclose(np.asarray(base[:, :window, :]),
                            np.asarray(pert[:, :window, :]), atol=1e-3)
+
+
+@pytest.mark.parametrize("kind", sorted(KIND_CFGS))
+def test_init_params_vmapped_units_match_per_layer_init(kind):
+    """Each scan-stacked unit kind is initialised by one vmap over its
+    layer keys: bit-identical to initialising every layer on its own key
+    and stacking the results."""
+    from repro.models.transformer import _layer_init, _stack, init_params
+
+    base = KIND_CFGS[kind]
+    cfg = dataclasses.replace(base, n_layers=3 * base.n_layers)
+    rng = jax.random.PRNGKey(3)
+    params = init_params(rng, cfg)
+    ks = iter(jax.random.split(rng, 64))
+    next(ks)                       # embed
+    if not cfg.tie_embeddings:
+        next(ks)                   # lm_head
+    n_units = cfg.n_layers // len(cfg.block_pattern)
+    assert n_units > 1
+    for pos, layer_kind in enumerate(cfg.block_pattern):
+        want = _stack([_layer_init(next(ks), cfg, layer_kind, jnp.float32,
+                                   cfg.enc_dec) for _ in range(n_units)])
+        jax.tree.map(lambda a, b: np.testing.assert_array_equal(
+            np.asarray(a), np.asarray(b)), params["units"][pos], want)

@@ -55,6 +55,9 @@ def toks(cfg, mesh, share_from=None, **kw):
     return eng, [r.tokens for r in eng.run(prompts(cfg),
                                            max_new_tokens=8)]
 
+def template_ids(pool):
+    return sorted((m, tuple(d.id for d in ds)) for m, ds in pool._templates)
+
 def check(name, ref, got):
     assert len(ref) == len(got), name
     for i, (r, g) in enumerate(zip(ref, got)):
@@ -127,12 +130,13 @@ check("speculative", ref, got)
 
 @pytest.mark.slow
 def test_pool_spawns_tp_instances():
-    """ModelInstancePool carves TP instances from the shared device
-    set: instances span their degree's mesh, devices_in_use sums the
-    degrees, head-sharding discounts the KV budget charge (one budget
-    block buys tp pool blocks), outputs stay identical to a plain
-    engine, and set_tp_degree drains mismatched instances so the next
-    scale_to respawns at the new degree."""
+    """ModelInstancePool carves TP instances from the FREE devices of
+    the shared set: instances span disjoint meshes (tp=1 included),
+    devices_in_use sums the degrees, head-sharding discounts the KV
+    budget charge (one budget block buys tp pool blocks), each device
+    set keys its own weight/jit template, outputs stay identical to a
+    plain engine, and set_tp_degree drains mismatched instances so the
+    next scale_to respawns at the new degree."""
     out = _run_sub("""
 from repro.serving.runtime import ModelInstancePool
 
@@ -149,8 +153,9 @@ assert pool.devices_in_use() == 4
 for i in insts:
     assert i.kv_blocks == 16 and i.engine.allocator.n_blocks == 32
 assert pool.kv_blocks_free == 64 - 32
-# same-degree instances share one weight/jit template
-assert len(pool._templates) == 1 and ("tiny", 2) in pool._templates
+# disjoint device pairs, one weight/jit template per device set
+assert [[d.id for d in i.devices] for i in insts] == [[0, 1], [2, 3]]
+assert template_ids(pool) == [("tiny", (0, 1)), ("tiny", (2, 3))]
 print("spawn OK")
 
 ref = ContinuousBatchingEngine(TINY2, max_slots=1, max_seq=128, seed=0)
@@ -168,10 +173,12 @@ pool.set_tp_degree("tiny", 1)
 assert not pool.running("tiny")      # old degree drains
 assert pool.scale_to("tiny", 1) == 1
 inst = pool.running("tiny")[0]
-assert inst.tp_degree == 1 and inst.engine.mesh is None
+# the draining pair still holds devices 0-3: the new one lands on 4
+assert inst.tp_degree == 1 and [d.id for d in inst.devices] == [4]
 assert inst.kv_blocks == inst.engine.allocator.n_blocks == 32
 pool.step()                          # sweep retires the drained pair
 assert pool.devices_in_use() == 1
+assert template_ids(pool) == [("tiny", (4,))]
 assert pool.kv_blocks_free == 64 - 32
 print("retune OK")
 """)
@@ -182,7 +189,8 @@ print("retune OK")
 @pytest.mark.slow
 def test_pool_device_budget_bounds_joint_partition():
     """m_c and TP degree jointly partition the shared device set:
-    scale_to clamps when Σ tp_degree would exceed n_devices."""
+    scale_to clamps when Σ tp_degree would exceed n_devices. Without a
+    device budget, tp>1 instances still take disjoint devices."""
     out = _run_sub("""
 from repro.serving.runtime import ModelInstancePool
 
@@ -197,8 +205,16 @@ assert pool.devices_in_use() == 0
 assert pool.scale_to("tiny4", 3) == 3
 assert pool.devices_in_use() == 3
 print("budget OK")
+
+# without a device budget, higher degrees still carve disjoint sets
+free = ModelInstancePool({"tiny4": TINY4}, max_instances=8, max_slots=2,
+                         max_seq=128, tp_degree=2)
+assert free.scale_to("tiny4", 2) == 2
+assert [[d.id for d in i.devices] for i in free.running("tiny4")] \
+    == [[0, 1], [2, 3]]
+print("unbudgeted OK")
 """)
-    assert "budget OK" in out
+    assert "budget OK" in out and "unbudgeted OK" in out
 
 
 # ------------------------------------------------- in-process error paths

@@ -5,7 +5,8 @@ honest.
 Checks, across the repo:
 
 1. every ``*.md`` file referenced from a Python docstring/comment under
-   ``src/``, ``tests/``, ``benchmarks/`` or ``examples/`` exists
+   ``src/``, ``tests/``, ``benchmarks/`` or ``examples/``, or in a
+   script at the repo root (``chip_smoke.py``), exists
    (this is what used to rot: docstrings cited a ``DESIGN.md`` that was
    never committed);
 2. every ``docs/ARCHITECTURE.md §N`` citation points at a section that
@@ -16,7 +17,8 @@ Checks, across the repo:
    Python sources (so CLI documentation tracks argparse reality);
 5. every backticked path ending in ``.py``/``.md`` (or ``path/``)
    mentioned in README/docs exists, resolved against the repo root and
-   ``src/``.
+   ``src/`` — or lies in a directory ``.gitignore`` lists (generated
+   output such as ``benchmarks/out/``, which a clean checkout lacks).
 
 Run:  python tools/check_docs_links.py   (exit 1 on any broken ref)
 """
@@ -42,6 +44,9 @@ CODE_PATH = re.compile(r"`([A-Za-z0-9_\-./]+(?:\.py|\.md|/))`")
 
 
 def _py_files():
+    for f in sorted(os.listdir(ROOT)):
+        if f.endswith(".py"):
+            yield os.path.join(ROOT, f)
     for d in PY_DIRS:
         for dirpath, _, files in os.walk(os.path.join(ROOT, d)):
             if "__pycache__" in dirpath:
@@ -55,6 +60,20 @@ def _exists(rel: str) -> bool:
     rel = rel.strip("`'\"")
     return (os.path.exists(os.path.join(ROOT, rel))
             or os.path.exists(os.path.join(ROOT, "src", rel)))
+
+
+def _ignored_dirs() -> tuple:
+    """Plain directory entries of .gitignore (``name/``, no globs)."""
+    path = os.path.join(ROOT, ".gitignore")
+    if not os.path.exists(path):
+        return ()
+    with open(path) as f:
+        return tuple(line.strip().lstrip("/") for line in f
+                     if line.strip().endswith("/") and "*" not in line)
+
+
+def _generated(rel: str) -> bool:
+    return rel.strip("`'\"").startswith(_ignored_dirs())
 
 
 def _arch_sections() -> set:
@@ -108,7 +127,7 @@ def main() -> int:
                 errors.append(f"{doc}: documents flag {flag} not found in "
                               "any Python source")
         for m in CODE_PATH.finditer(text):
-            if not _exists(m.group(1)):
+            if not _exists(m.group(1)) and not _generated(m.group(1)):
                 errors.append(f"{doc}: mentions path `{m.group(1)}` which "
                               "does not exist (checked root and src/)")
         for m in SECTION_REF.finditer(text):

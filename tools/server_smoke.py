@@ -47,8 +47,8 @@ def _start_server() -> int:
     bound: list = []
     ev = threading.Event()
 
-    def ready(port: int) -> None:
-        bound.append(port)
+    def ready(fe) -> None:
+        bound.append(fe.port)
         ev.set()
 
     t = threading.Thread(
