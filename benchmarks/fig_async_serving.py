@@ -37,6 +37,7 @@ import asyncio
 import json
 import os
 import sys
+from typing import Dict, List
 
 import numpy as np
 
@@ -45,9 +46,9 @@ from repro.config.base import ModelConfig
 from repro.launch.server import ServingFrontend
 from repro.serving.driver import ServingDriver
 from repro.serving.runtime import ModelInstancePool
-from repro.serving.workload import (ArrivalTrace, http_generate,
-                                    make_trace_requests, run_closed_loop,
-                                    summarize_outcomes)
+from repro.serving.workload import (ArrivalTrace, ClientOutcome,
+                                    http_generate, make_trace_requests,
+                                    run_closed_loop)
 
 OUT_DIR = os.path.join(os.path.dirname(__file__), "out")
 
@@ -150,7 +151,7 @@ async def _episode_async(backpressure: bool, smoke: bool,
     finally:
         await fe.stop()
         driver.stop()
-    row = summarize_outcomes(outcomes)
+    row = _summarize(outcomes)
     row.update({f"leak_{k}": float(v) for k, v in _leaked(pool).items()})
     stats = pool.stats()
     row.update({
@@ -170,6 +171,29 @@ async def _episode_async(backpressure: bool, smoke: bool,
         "pool_tpot_ms_p99": float(stats.get("tpot_ms_p99", 0.0)),
     })
     return row
+
+
+def _summarize(outcomes: List[ClientOutcome]) -> Dict[str, float]:
+    """Outcome counts, TTFT/TPOT percentiles (finished requests), and
+    per-tier SLO attainment over ALL issued requests of that tier —
+    throttled and abandoned clients count against attainment, which is
+    exactly why backpressure has to EARN its 429s."""
+    out: Dict[str, float] = {"n": float(len(outcomes))}
+    for kind in ("finished", "rejected", "throttled", "abandoned",
+                 "cancelled", "error"):
+        out[f"n_{kind}"] = float(
+            sum(1 for o in outcomes if o.outcome == kind))
+    ttfts = [o.ttft_s * 1000.0 for o in outcomes if o.ttft_s >= 0]
+    tpots = [o.tpot_s * 1000.0 for o in outcomes if o.tpot_s >= 0]
+    out["ttft_ms_p50"] = float(np.percentile(ttfts, 50)) if ttfts else 0.0
+    out["ttft_ms_p99"] = float(np.percentile(ttfts, 99)) if ttfts else 0.0
+    out["tpot_ms_p50"] = float(np.percentile(tpots, 50)) if tpots else 0.0
+    out["tpot_ms_p99"] = float(np.percentile(tpots, 99)) if tpots else 0.0
+    for tier in sorted({o.tier for o in outcomes}):
+        of_tier = [o for o in outcomes if o.tier == tier]
+        out[f"attainment_{tier}"] = \
+            sum(1 for o in of_tier if o.attained) / len(of_tier)
+    return out
 
 
 def _episode(backpressure: bool, smoke: bool, seed: int = 7) -> dict:

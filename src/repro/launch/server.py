@@ -214,15 +214,15 @@ class ServingFrontend:
 
         # submit + listener registration under one lock acquisition so
         # no event can fire before the listener is attached
-        with self.driver.lock:
+        with self.driver.locked() as pool:
             try:
-                rid = self.driver.pool.submit(
+                rid = pool.submit(
                     model, prompt, slo_ms=slo_ms, max_new_tokens=max_new)
             except ValueError as e:  # never-fitting shape
                 writer.write(_json_response("400 Bad Request",
                                             {"error": str(e)}))
                 return
-            self.driver.pool.add_listener(rid, listener)
+            pool.add_listener(rid, listener)
 
         writer.write((
             "HTTP/1.1 200 OK\r\n"

@@ -30,6 +30,7 @@ from repro.core.interference import NNInterferencePredictor
 from repro.launch.roofline import ICI_BW
 from repro.serving import latency_model as lm
 from repro.serving.simulator import EdgeServingEnv
+from repro.serving.tracing import span
 
 
 @dataclasses.dataclass
@@ -472,12 +473,17 @@ class PoolScheduler:
         once per Eq.-1 slot (docs/RUNTIME.md)."""
         applied = {}
         for model, agent in self.agents.items():
-            s = self._state(model)
+            with span("repro.scheduler.state"):
+                s = self._state(model)
             if self.learn and model in self._last:
-                s0, a0 = self._last[model]
-                agent.observe(s0, a0, self._reward(model), s, False)
-                agent.update()
-            a = self._apply(model, agent.act(s))
+                with span("repro.scheduler.update"):
+                    s0, a0 = self._last[model]
+                    agent.observe(s0, a0, self._reward(model), s, False)
+                    agent.update()
+            with span("repro.scheduler.act"):
+                raw = agent.act(s)
+            with span("repro.scheduler.apply"):
+                a = self._apply(model, raw)
             self._last[model] = (s, a)
             applied[model] = self.cfg.action_to_pair(a)
         return applied
@@ -496,14 +502,16 @@ class PoolScheduler:
         if pool is not None and pool is not self.pool:
             raise ValueError("tick() got a different pool than the one "
                              "this scheduler controls")
-        for model in self.pool.configs:
-            hist = self.pool.results(model)
-            seen = self._tick_seen.get(model, 0)
-            if seen > len(hist):  # pool.reset_metrics() cleared history
-                seen = 0
-            self.record(hist[seen:])
-            self._tick_seen[model] = len(hist)
-        return self.control()
+        with span("repro.scheduler.tick"):
+            with span("repro.scheduler.harvest"):
+                for model in self.pool.configs:
+                    hist = self.pool.results(model)
+                    seen = self._tick_seen.get(model, 0)
+                    if seen > len(hist):  # reset_metrics() cleared history
+                        seen = 0
+                    self.record(hist[seen:])
+                    self._tick_seen[model] = len(hist)
+            return self.control()
 
 
 def collect_interference_dataset(cfg: ServingConfig, n: int = 2000,

@@ -40,6 +40,7 @@ from repro.models import build_model
 from repro.models.transformer import (_split_layers, pad_cache,
                                       paged_layer_kind, scatter_blocks,
                                       scatter_blocks_stacked)
+from repro.serving.tracing import span
 
 
 def _bucket(n: int, buckets=(1, 2, 4, 8, 16, 32, 64, 128)) -> int:
@@ -608,6 +609,7 @@ class _Slot:
     # chunked prefill state machine
     seq_tokens: Optional[np.ndarray] = None  # padded prompt (+ resume ctx)
     base_len: int = 0           # padded-prompt length at FIRST admission
+    n_pad: int = 0              # leading bucket-padding rows of seq_tokens
     prefill_pos: int = 0        # tokens of seq_tokens processed so far
     staging: object = None      # single-seq cache chunks accumulate into
     # accounting satellites
@@ -663,6 +665,7 @@ class PreemptedRequest:
     truncated: bool
     n_preempted: int
     first_token_s: float = -1.0
+    n_pad: int = 0              # leading bucket-padding rows of seq_tokens
     # ---- swap-mode state (None/unused for recompute snapshots) ----
     tokens: Optional[List[int]] = None   # emitted tokens (swap carries
     #                                      them outside seq_tokens)
@@ -692,7 +695,7 @@ def to_recompute(req: PreemptedRequest) -> PreemptedRequest:
         req.request_id, seq, base_len=req.base_len, max_new=req.max_new,
         submit_s=req.submit_s, requested_new=req.requested_new,
         truncated=req.truncated, n_preempted=req.n_preempted,
-        first_token_s=req.first_token_s)
+        first_token_s=req.first_token_s, n_pad=req.n_pad)
 
 
 @dataclasses.dataclass
@@ -707,6 +710,7 @@ class _WaitingReq:
     submit_s: float
     prepadded: bool = False
     base_len: int = -1          # resumes only
+    n_pad: int = 0              # resumes only
     requested_new: int = 0
     truncated: bool = False
     n_preempted: int = 0
@@ -883,6 +887,9 @@ class ContinuousBatchingEngine:
         self.n_prefix_hits = 0
         self.n_prefix_hit_tokens = 0
         self.n_prefill_chunk_tokens = 0
+        #: of those, rows that were bucket padding, not the users' own
+        #: prompt tokens
+        self.n_prefill_pad_rows = 0
         #: tensor parallelism (docs/ARCHITECTURE.md §11): a 1D
         #: ``("model",)`` mesh (launch/mesh.make_tp_mesh) this instance
         #: spans. Params are placed under the launch TP rules, the KV
@@ -1063,6 +1070,10 @@ class ContinuousBatchingEngine:
         #: token-cost calibration reads both (docs/RUNTIME.md §8)
         self.last_step_tokens = 0
         self.last_step_compiled = False
+        #: steps that compiled a new shape, and the rows of live
+        #: sequences every decode (or verify) call carried
+        self.n_compiled_steps = 0
+        self.n_decode_rows = 0
         self._decode_warm = False
         self.prefill_shapes: Set[Tuple[int, int]] = set()
         self._next_id = 0
@@ -1071,6 +1082,12 @@ class ContinuousBatchingEngine:
     # ---- bookkeeping -----------------------------------------------------
     def _now(self) -> float:
         return time.perf_counter() - self._t0
+
+    def _note_compile(self) -> None:
+        """This step dispatches a shape it has not compiled before."""
+        if not self.last_step_compiled:
+            self.last_step_compiled = True
+            self.n_compiled_steps += 1
 
     def _note_tokens(self, s: _Slot, n_new: int) -> None:
         """Stamp ``first_token_s`` and fire ``on_token`` for the last
@@ -1251,6 +1268,7 @@ class ContinuousBatchingEngine:
         self.waiting.append(_WaitingReq(
             rid, np.asarray(req.seq_tokens, np.int32), req.max_new,
             req.submit_s, prepadded=True, base_len=req.base_len,
+            n_pad=req.n_pad,
             requested_new=req.requested_new, truncated=req.truncated,
             n_preempted=req.n_preempted,
             first_token_s=req.first_token_s,
@@ -1573,10 +1591,12 @@ class ContinuousBatchingEngine:
             if w.prepadded:
                 seq = w.prompt
                 base_len = w.base_len
+                n_pad = w.n_pad
             else:
                 S = _bucket(len(w.prompt), buckets=SEQ_BUCKETS)
                 F = self._frontend_tokens()
                 base_len = F + S
+                n_pad = S - len(w.prompt)
                 seq = None
                 if self.chunked:
                     seq = np.zeros((S,), np.int32)
@@ -1656,7 +1676,8 @@ class ContinuousBatchingEngine:
                     submit_s=w.submit_s, admit_s=self._now(), blocks=ids,
                     n_outstanding=reserved - (n0 - len(shared_ids)),
                     n_shared=len(shared_ids), seq_tokens=seq,
-                    base_len=base_len, prefill_pos=pos0, staging=staging,
+                    base_len=base_len, n_pad=n_pad, prefill_pos=pos0,
+                    staging=staging,
                     requested_new=w.requested_new, truncated=w.truncated,
                     n_preempted=w.n_preempted,
                     first_token_s=w.first_token_s)
@@ -1699,7 +1720,8 @@ class ContinuousBatchingEngine:
             submit_s=req.submit_s, admit_s=self._now(), blocks=ids,
             n_outstanding=need - n_have, n_shared=0,
             seq_tokens=np.asarray(req.seq_tokens, np.int32),
-            base_len=req.base_len, prefill_pos=len(req.seq_tokens),
+            base_len=req.base_len, n_pad=req.n_pad,
+            prefill_pos=len(req.seq_tokens),
             requested_new=req.requested_new, truncated=req.truncated,
             n_preempted=req.n_preempted, first_token_s=req.first_token_s)
         self.pos[slot] = req.pos
@@ -1776,22 +1798,25 @@ class ContinuousBatchingEngine:
                 rem = len(s.seq_tokens) - s.prefill_pos
                 c = min(rem, budget_left, _MAX_CHUNK)
                 c = 1 << (c.bit_length() - 1)  # largest power of two <= c
-                toks = s.seq_tokens[s.prefill_pos:s.prefill_pos + c]
-                shape = (c, self.cache_len)
-                if shape not in self.prefill_shapes:
-                    self.prefill_shapes.add(shape)
-                    self.last_step_compiled = True
-                batch = {"tokens": jnp.asarray(toks[None, :]),
-                         "pos": jnp.asarray([s.prefill_pos], jnp.int32)}
-                if self.fused_prefill:
-                    tbl = np.zeros((1, self.blocks_per_slot), np.int32)
-                    tbl[0, :len(s.blocks)] = s.blocks
-                    batch["block_tables"] = jnp.asarray(tbl)
-                    logits, self.cache = self._prefill_chunk(
-                        self.params, self.cache, batch)
-                else:
-                    logits, s.staging = self._prefill_chunk(
-                        self.params, s.staging, batch)
+                with span("repro.engine.prefill_piece"):
+                    toks = s.seq_tokens[s.prefill_pos:s.prefill_pos + c]
+                    shape = (c, self.cache_len)
+                    if shape not in self.prefill_shapes:
+                        self.prefill_shapes.add(shape)
+                        self._note_compile()
+                    batch = {"tokens": jnp.asarray(toks[None, :]),
+                             "pos": jnp.asarray([s.prefill_pos], jnp.int32)}
+                    if self.fused_prefill:
+                        tbl = np.zeros((1, self.blocks_per_slot), np.int32)
+                        tbl[0, :len(s.blocks)] = s.blocks
+                        batch["block_tables"] = jnp.asarray(tbl)
+                        logits, self.cache = self._prefill_chunk(
+                            self.params, self.cache, batch)
+                    else:
+                        logits, s.staging = self._prefill_chunk(
+                            self.params, s.staging, batch)
+                self.n_prefill_pad_rows += max(
+                    0, min(s.prefill_pos + c, s.n_pad) - s.prefill_pos)
                 s.prefill_pos += c
                 budget_left -= c
                 done_tokens += c
@@ -1823,7 +1848,8 @@ class ContinuousBatchingEngine:
             self._graft(s.staging, slot)
         s.staging = None
         self.pos[slot] = s.prefill_pos
-        self.pending_tok[slot] = int(sample_tokens(logits[0, -1, :]))
+        with span("repro.engine.prefill_readback"):
+            self.pending_tok[slot] = int(sample_tokens(logits[0, -1, :]))
         if self.on_state is not None:
             self.on_state(s.request_id, "decode")
 
@@ -1909,7 +1935,7 @@ class ContinuousBatchingEngine:
                 max_new=s.remaining, submit_s=s.submit_s,
                 requested_new=s.requested_new, truncated=s.truncated,
                 n_preempted=s.n_preempted + 1,
-                first_token_s=s.first_token_s,
+                first_token_s=s.first_token_s, n_pad=s.n_pad,
                 tokens=list(s.tokens), pos=int(self.pos[slot]),
                 pending_tok=int(self.pending_tok[slot]),
                 host_blocks=host_ids, host_engine_id=id(self))
@@ -1921,7 +1947,7 @@ class ContinuousBatchingEngine:
                 s.request_id, seq, base_len=s.base_len, max_new=s.remaining,
                 submit_s=s.submit_s, requested_new=s.requested_new,
                 truncated=s.truncated, n_preempted=s.n_preempted + 1,
-                first_token_s=s.first_token_s)
+                first_token_s=s.first_token_s, n_pad=s.n_pad)
         if self.kv_layout == "paged":
             self.allocator.free(s.blocks)
             self.allocator.unreserve(s.n_outstanding)
@@ -1932,7 +1958,7 @@ class ContinuousBatchingEngine:
         if requeue:
             self.waiting.insert(0, _WaitingReq(
                 req.request_id, req.seq_tokens, req.max_new, req.submit_s,
-                prepadded=True, base_len=req.base_len,
+                prepadded=True, base_len=req.base_len, n_pad=req.n_pad,
                 requested_new=req.requested_new, truncated=req.truncated,
                 n_preempted=req.n_preempted,
                 first_token_s=req.first_token_s,
@@ -1960,7 +1986,7 @@ class ContinuousBatchingEngine:
             max_new=req.max_new, submit_s=req.submit_s,
             requested_new=req.requested_new, truncated=req.truncated,
             n_preempted=req.n_preempted,
-            first_token_s=req.first_token_s)
+            first_token_s=req.first_token_s, n_pad=req.n_pad)
 
     # ---- cancellation (docs/RUNTIME.md §11) ------------------------------
     def cancel(self, request_id: int) -> Optional[ContinuousResult]:
@@ -2036,10 +2062,12 @@ class ContinuousBatchingEngine:
         sequences that finished this iteration. Inactive slots decode a
         dummy token in place (their cache row is masked by ``pos`` and
         overwritten at the next admission), keeping the compiled decode
-        shape fixed at (n_slots, 1).
+        shape fixed at (n_slots, 1). Each part runs under its profiler
+        span (``repro.engine.*``, ``tracing.py``).
         """
         self.last_step_compiled = False
-        self.admit()
+        with span("repro.engine.admit"):
+            self.admit()
         n_dec = len(self.decoding_slots)
         budget = self.token_budget if self.token_budget is not None \
             else 1 << 62
@@ -2052,33 +2080,46 @@ class ContinuousBatchingEngine:
         if eff_k > 0:
             return self._step_speculative(active, eff_k)
         self.last_step_tokens += len(active)
-        for i in active:
-            s = self.slots[i]
-            s.tokens.append(int(self.pending_tok[i]))
-            s.n_emitted += 1
-            s.remaining -= 1
-            self._note_tokens(s, 1)
-        batch = {"tokens": jnp.asarray(self.pending_tok[:, None]),
-                 "pos": jnp.asarray(self.pos)}
-        if self.kv_layout == "paged":
-            # alloc-on-decode-boundary: the write at ``pos`` needs its
-            # block mapped before the decode runs; the admission
-            # reservation guarantees the free list cannot be empty here
-            bs = self.block_size
+        self.n_decode_rows += len(active)
+        with span("repro.engine.emit"):
             for i in active:
                 s = self.slots[i]
-                while self.pos[i] >= len(s.blocks) * bs:
-                    bid = self.allocator.alloc_reserved()
-                    s.n_outstanding -= 1
-                    self.block_tables[i, len(s.blocks)] = bid
-                    s.blocks.append(bid)
-            batch["block_tables"] = jnp.asarray(self.block_tables)
+                s.tokens.append(int(self.pending_tok[i]))
+                s.n_emitted += 1
+                s.remaining -= 1
+                self._note_tokens(s, 1)
+        with span("repro.engine.decode_batch"):
+            batch = {"tokens": jnp.asarray(self.pending_tok[:, None]),
+                     "pos": jnp.asarray(self.pos)}
+            if self.kv_layout == "paged":
+                # alloc-on-decode-boundary: the write at ``pos`` needs its
+                # block mapped before the decode runs; the admission
+                # reservation guarantees the free list cannot be empty
+                bs = self.block_size
+                for i in active:
+                    s = self.slots[i]
+                    while self.pos[i] >= len(s.blocks) * bs:
+                        bid = self.allocator.alloc_reserved()
+                        s.n_outstanding -= 1
+                        self.block_tables[i, len(s.blocks)] = bid
+                        s.blocks.append(bid)
+                batch["block_tables"] = jnp.asarray(self.block_tables)
         if not self._decode_warm:
             self._decode_warm = True
-            self.last_step_compiled = True
-        logits, self.cache = self._decode(self.params, self.cache, batch)
-        nxt = sample_tokens(logits[:, -1, :])
+            self._note_compile()
+        with span("repro.engine.decode_dispatch"):
+            logits, self.cache = self._decode(self.params, self.cache, batch)
+        with span("repro.engine.decode_readback"):
+            nxt = sample_tokens(logits[:, -1, :])
         self.n_iters += 1
+        with span("repro.engine.retire"):
+            return self._retire(active, nxt)
+
+    def _retire(self, active: List[int],
+                nxt: np.ndarray) -> List[ContinuousResult]:
+        """After a decode: finish and evict every slot with nothing left
+        to emit (or no cache room left), advance the others by the token
+        just sampled."""
         finished: List[ContinuousResult] = []
         now = self._now()
         for i in active:
@@ -2145,7 +2186,26 @@ class ContinuousBatchingEngine:
         sole-reference decode-region blocks (asserted in
         :meth:`_trim_blocks`)."""
         W = 1 + k
-        toks = np.zeros((self.n_slots, W), np.int32)
+        with span("repro.engine.decode_batch"):
+            toks, k_eff, batch = self._verify_batch(active, k)
+        if W not in self._spec_shapes:
+            self._spec_shapes.add(W)
+            self._note_compile()
+        self.n_decode_rows += len(active)
+        with span("repro.engine.decode_dispatch"):
+            logits, self.cache = self._verify(self.params, self.cache, batch)
+        with span("repro.engine.decode_readback"):
+            nxt_all = sample_tokens(logits)  # (n_slots, W) verify argmax
+        self.n_iters += 1
+        self.n_spec_steps += 1
+        with span("repro.engine.retire"):
+            return self._accept(active, toks, k_eff, nxt_all)
+
+    def _verify_batch(self, active: List[int], k: int):
+        """Host side of a speculative iteration: each slot's pending
+        token and drafts, how many drafts each slot runs, and the verify
+        forward's batch."""
+        toks = np.zeros((self.n_slots, 1 + k), np.int32)
         k_eff: Dict[int, int] = {}
         for i in active:
             s = self.slots[i]
@@ -2187,13 +2247,14 @@ class ContinuousBatchingEngine:
                           np.int32)
             vt[:, :self.blocks_per_slot] = self.block_tables
             batch["block_tables"] = jnp.asarray(vt)
-        if W not in self._spec_shapes:
-            self._spec_shapes.add(W)
-            self.last_step_compiled = True
-        logits, self.cache = self._verify(self.params, self.cache, batch)
-        nxt_all = sample_tokens(logits)  # (n_slots, W) verify argmax
-        self.n_iters += 1
-        self.n_spec_steps += 1
+        return toks, k_eff, batch
+
+    def _accept(self, active: List[int], toks: np.ndarray,
+                k_eff: Dict[int, int],
+                nxt_all: np.ndarray) -> List[ContinuousResult]:
+        """After a verify forward: emit each slot's pending token and its
+        accepted drafts, roll back the rejected tail, finish and evict
+        the slots that are done."""
         finished: List[ContinuousResult] = []
         now = self._now()
         for i in active:
@@ -2413,6 +2474,10 @@ class ContinuousBatchingEngine:
         alloc = float(self.kv_allocated_tokens)
         return {
             "n_iters": float(self.n_iters),
+            "n_compiled_steps": float(self.n_compiled_steps),
+            "n_decode_rows": float(self.n_decode_rows),
+            "n_prefill_chunk_tokens": float(self.n_prefill_chunk_tokens),
+            "n_prefill_pad_rows": float(self.n_prefill_pad_rows),
             "n_admitted": float(self.n_admitted),
             "n_evicted": float(self.n_evicted),
             "n_prefill_shapes": float(len(self.prefill_shapes)),

@@ -56,6 +56,7 @@ from repro.serving.engine import (ContinuousBatchingEngine,
                                   supports_prefix_cache,
                                   supports_speculation, to_recompute)
 from repro.serving.request import RequestLifecycle
+from repro.serving.tracing import span
 
 # instance lifecycle states (docs/RUNTIME.md state machine)
 STARTING = "starting"
@@ -68,6 +69,10 @@ _seq = itertools.count()
 #: trailing window the contention/occupancy fits read (and the bound the
 #: sample lists are trimmed to, so long-lived serving loops do not leak)
 _SAMPLE_WINDOW = 512
+
+#: engine counters ``stats()`` sums over the live instances
+ENGINE_COUNTERS = ("n_decode_rows", "n_prefill_chunk_tokens",
+                   "n_prefill_pad_rows", "n_compiled_steps")
 
 
 @dataclasses.dataclass
@@ -1180,29 +1185,44 @@ class ModelInstancePool:
     def step(self) -> List[PoolResult]:
         """One pool iteration: sweep retirements, route admissions, then
         run ONE decode iteration on every busy live instance. Returns the
-        requests that finished (or were rejected) this iteration."""
-        self._sweep()
-        out: List[PoolResult] = list(self.route())
+        requests that finished (or were rejected) this iteration. Each
+        part runs under its profiler span (``repro.pool.*``,
+        ``tracing.py``)."""
+        with span("repro.pool.sweep"):
+            self._sweep()
+        with span("repro.pool.route"):
+            out: List[PoolResult] = list(self.route())
         busy = [i for i in self.live()
                 if i.engine.active_slots or i.engine.waiting]
         if not busy:
             self.n_steps += 1
             return out
-        # the latency a sequence experiences per decode token is the wall
-        # time of the WHOLE pool iteration (every busy instance steps once
-        # before any sequence advances again) — that is the quantity the
-        # contention model calibrates against the overlap level. Steps
-        # that do prefill-chunk work are excluded from the CONTENTION fit
-        # (their cost scales with chunk tokens, not overlap) but feed the
-        # token-cost fit below, which prices exactly that.
-        overlap = len(busy)
         pure_decode = not any(i.engine.prefill_backlog_tokens
                               for i in busy)
         t0 = time.perf_counter()
         for inst in busy:
-            for r in inst.engine.step():
-                out.append(self._finish(inst, r))
+            with span("repro.pool.instance_step"):
+                done = inst.engine.step()
+            with span("repro.pool.finish"):
+                out.extend(self._finish(inst, r) for r in done)
         iter_ms = (time.perf_counter() - t0) * 1000.0
+        with span("repro.pool.calibrate"):
+            self._calibrate(busy, pure_decode, iter_ms)
+        self.n_steps += 1
+        return out
+
+    def _calibrate(self, busy: List[ModelInstance], pure_decode: bool,
+                   iter_ms: float) -> None:
+        """Feed one iteration's wall time to the latency fits.
+
+        The latency a sequence experiences per decode token is the wall
+        time of the WHOLE pool iteration (every busy instance steps once
+        before any sequence advances again) — that is the quantity the
+        contention model calibrates against the overlap level. Steps
+        that do prefill-chunk work are excluded from the CONTENTION fit
+        (their cost scales with chunk tokens, not overlap) but feed the
+        token-cost fit, which prices exactly that."""
+        overlap = len(busy)
         compiled = any(i.engine.last_step_compiled for i in busy)
         if not compiled:
             # (tokens processed, wall ms) — the fit behind the
@@ -1240,8 +1260,6 @@ class ModelInstancePool:
                                     self.m_c(inst.model),
                                     inst.n_resident, overlap),
                     iter_ms / 1000.0)
-        self.n_steps += 1
-        return out
 
     def _work_pending(self) -> bool:
         return any(self.queues.values()) \
@@ -1503,6 +1521,10 @@ class ModelInstancePool:
             "swap_base_ms": swap_base,
             "swap_ms_per_mb": per_mb,
             "spec_accept_rate": self.spec_accept_rate(),
+            # work the live instances' engines did: rows of the decode
+            # calls, prefill rows and their padding, steps that compiled
+            **{k: float(sum(getattr(i.engine, k) for i in self.live()))
+               for k in ENGINE_COUNTERS},
             # client-observed timing percentiles over the trailing window
             # (pool clock, HTTP-independent); 0.0 before any completion
             "ttft_ms_p50": float(np.percentile(self.ttft_samples, 50))

@@ -392,28 +392,3 @@ async def run_closed_loop(host: str, port: int,
             return res
 
     return list(await asyncio.gather(*(one(tr) for tr in requests)))
-
-
-def summarize_outcomes(outcomes: Sequence[ClientOutcome]
-                       ) -> Dict[str, float]:
-    """Client-observed serving metrics over a closed-loop run: outcome
-    counts, TTFT/TPOT percentiles (finished requests), and per-tier SLO
-    attainment over ALL issued requests of that tier — throttled and
-    abandoned clients count against attainment, which is exactly why
-    backpressure has to EARN its 429s."""
-    out: Dict[str, float] = {"n": float(len(outcomes))}
-    for kind in ("finished", "rejected", "throttled", "abandoned",
-                 "cancelled", "error"):
-        out[f"n_{kind}"] = float(
-            sum(1 for o in outcomes if o.outcome == kind))
-    ttfts = [o.ttft_s * 1000.0 for o in outcomes if o.ttft_s >= 0]
-    tpots = [o.tpot_s * 1000.0 for o in outcomes if o.tpot_s >= 0]
-    out["ttft_ms_p50"] = float(np.percentile(ttfts, 50)) if ttfts else 0.0
-    out["ttft_ms_p99"] = float(np.percentile(ttfts, 99)) if ttfts else 0.0
-    out["tpot_ms_p50"] = float(np.percentile(tpots, 50)) if tpots else 0.0
-    out["tpot_ms_p99"] = float(np.percentile(tpots, 99)) if tpots else 0.0
-    for tier in sorted({o.tier for o in outcomes}):
-        of_tier = [o for o in outcomes if o.tier == tier]
-        out[f"attainment_{tier}"] = \
-            sum(1 for o in of_tier if o.attained) / len(of_tier)
-    return out
