@@ -135,14 +135,6 @@ def attention_full(p: Dict, x: jax.Array, cfg: ModelConfig,
 
 
 # ---------------------------------------------------------------- decode
-def init_kv_cache(cfg: ModelConfig, batch: int, cache_len: int, dtype) -> Dict:
-    hd = cfg.head_dim
-    return {
-        "k": jnp.zeros((batch, cache_len, cfg.n_kv_heads, hd), dtype),
-        "v": jnp.zeros((batch, cache_len, cfg.n_kv_heads, hd), dtype),
-    }
-
-
 def kv_cache_spec(cfg: ModelConfig, batch: int, cache_len: int, dtype) -> Dict:
     hd = cfg.head_dim
     shp = (batch, cache_len, cfg.n_kv_heads, hd)
@@ -150,19 +142,12 @@ def kv_cache_spec(cfg: ModelConfig, batch: int, cache_len: int, dtype) -> Dict:
             "v": jax.ShapeDtypeStruct(shp, dtype)}
 
 
-def init_paged_kv_cache(cfg: ModelConfig, n_blocks: int, block_size: int,
+def paged_kv_cache_spec(cfg: ModelConfig, n_blocks: int, block_size: int,
                         dtype) -> Dict:
     """Block-pool KV layout (docs/ARCHITECTURE.md §5): one physical pool
     of ``n_blocks`` blocks of ``block_size`` tokens shared by every
     sequence, indirected through per-sequence block tables. Block 0 is
     conventionally the *null block* (sink for inactive batch rows)."""
-    hd = cfg.head_dim
-    shp = (n_blocks, block_size, cfg.n_kv_heads, hd)
-    return {"k": jnp.zeros(shp, dtype), "v": jnp.zeros(shp, dtype)}
-
-
-def paged_kv_cache_spec(cfg: ModelConfig, n_blocks: int, block_size: int,
-                        dtype) -> Dict:
     hd = cfg.head_dim
     shp = (n_blocks, block_size, cfg.n_kv_heads, hd)
     return {"k": jax.ShapeDtypeStruct(shp, dtype),
@@ -178,77 +163,97 @@ def _write_cache(cache: jax.Array, new: jax.Array, slot: jax.Array) -> jax.Array
     return jax.vmap(row)(cache, new, slot)
 
 
-def _write_paged(pool: jax.Array, new: jax.Array, tables: jax.Array,
-                 pos: jax.Array) -> jax.Array:
-    """pool (N,bs,KV,hd); new (B,1,KV,hd); tables (B,nb); pos (B,).
+def _pool_index(layer: Optional[jax.Array], *idx) -> Tuple:
+    """Index into a block pool (N,bs,KV,hd), or into layer ``layer`` of
+    a scan-stacked one (U,N,bs,KV,hd) — one gather or scatter over the
+    stacked array, so no layer slice of it is ever materialised."""
+    return idx if layer is None else (layer,) + idx
 
-    Scatter each sequence's new K/V row into physical slot
-    ``tables[b, pos//bs] * bs + pos % bs``. Distinct live sequences own
-    distinct blocks, so the only colliding writes are inactive rows
-    aimed at the null block — last-write-wins there is harmless because
-    null-block contents are never read as valid."""
-    N, bs = pool.shape[0], pool.shape[1]
+
+def _write_paged(pool: jax.Array, new: jax.Array, tables: jax.Array,
+                 pos: jax.Array, layer: Optional[jax.Array] = None
+                 ) -> jax.Array:
+    """pool (N,bs,KV,hd) or, with ``layer``, (U,N,bs,KV,hd); new
+    (B,1,KV,hd); tables (B,nb); pos (B,).
+
+    Scatter each sequence's new K/V row into block
+    ``tables[b, pos//bs]`` at offset ``pos % bs``. Distinct live
+    sequences own distinct blocks, so the only colliding writes are
+    inactive rows aimed at the null block — last-write-wins there is
+    harmless because null-block contents are never read as valid."""
+    bs = pool.shape[-3]
     B = new.shape[0]
-    flat = pool.reshape((N * bs,) + pool.shape[2:])
-    phys = tables[jnp.arange(B), pos // bs] * bs + pos % bs
-    flat = flat.at[phys].set(new[:, 0])
-    return flat.reshape(pool.shape)
+    blk = tables[jnp.arange(B), pos // bs]
+    return pool.at[_pool_index(layer, blk, pos % bs)].set(new[:, 0])
+
+
+def _gather_paged(pool: jax.Array, tables: jax.Array,
+                  layer: Optional[jax.Array] = None) -> jax.Array:
+    """The logical view (B, nb*bs, KV, hd) of each sequence's blocks."""
+    B, nb = tables.shape
+    g = pool[_pool_index(layer, tables)]
+    return g.reshape((B, nb * g.shape[2]) + g.shape[3:])
 
 
 def attention_decode_paged(p: Dict, x: jax.Array, cache: Dict,
                            tables: jax.Array, pos: jax.Array,
-                           cfg: ModelConfig, *, impl: str = "auto"
+                           cfg: ModelConfig, *, impl: str = "auto",
+                           layer: Optional[jax.Array] = None
                            ) -> Tuple[jax.Array, Dict]:
     """Paged-counterpart of :func:`attention_decode` for linear
     (non-windowed) layers: the new K/V is scattered through the block
     table and the query attends the gathered logical view. Attended
     positions are exactly ``slots <= pos`` — the same set the dense
     layout attends — so greedy decode is token-identical across
-    layouts."""
+    layouts. With ``layer``, ``cache`` holds the scan-stacked pools and
+    only that layer's blocks are written and read, in place."""
     B = x.shape[0]
     nb = tables.shape[1]
-    bs = cache["k"].shape[1]
+    bs = cache["k"].shape[-3]
     q, k_new, v_new = _project_qkv(p, x, cfg, pos[:, None])
-    cache = {"k": _write_paged(cache["k"], k_new, tables, pos),
-             "v": _write_paged(cache["v"], v_new, tables, pos)}
+    cache = {"k": _write_paged(cache["k"], k_new, tables, pos, layer),
+             "v": _write_paged(cache["v"], v_new, tables, pos, layer)}
     scale = 1.0 / float(cfg.head_dim) ** 0.5
     if impl == "kernel":
         from repro.kernels import ops as kops
 
-        out = kops.paged_decode_attention(q, cache["k"], cache["v"],
+        k_pool, v_pool = (cache["k"], cache["v"]) if layer is None else \
+            (cache["k"][layer], cache["v"][layer])
+        out = kops.paged_decode_attention(q, k_pool, v_pool,
                                           tables, pos + 1, scale)
     else:
-        k = cache["k"][tables].reshape((B, nb * bs) + cache["k"].shape[2:])
-        v = cache["v"][tables].reshape((B, nb * bs) + cache["v"].shape[2:])
+        k = _gather_paged(cache["k"], tables, layer)
+        v = _gather_paged(cache["v"], tables, layer)
         valid = jnp.arange(nb * bs, dtype=jnp.int32)[None, :] <= pos[:, None]
         out = _sdpa(q, k, v, valid[:, None, :], scale)
     return out.reshape(B, 1, -1) @ p["wo"], cache
 
 
 def _write_paged_chunk(pool: jax.Array, new: jax.Array, tables: jax.Array,
-                       pos: jax.Array) -> jax.Array:
-    """pool (N,bs,KV,hd); new (B,T,KV,hd); tables (B,nb); pos (B,).
+                       pos: jax.Array, layer: Optional[jax.Array] = None
+                       ) -> jax.Array:
+    """pool as in :func:`_write_paged`; new (B,T,KV,hd); tables (B,nb);
+    pos (B,).
 
     Multi-row counterpart of :func:`_write_paged`: row ``j`` of each
-    sequence's chunk lands in ``tables[b, (pos+j)//bs] * bs +
-    (pos+j) % bs``. Table columns past a sequence's allocated blocks are
-    the null block, so out-of-range rows (speculative drafts past a
+    sequence's chunk lands in block ``tables[b, (pos+j)//bs]`` at offset
+    ``(pos+j) % bs``. Table columns past a sequence's allocated blocks
+    are the null block, so out-of-range rows (speculative drafts past a
     slot's participation depth, inactive batch rows) collide harmlessly
     there; callers must pad ``tables`` wide enough that ``(pos+T-1)//bs``
     never clips into a LIVE column (JAX clamps out-of-bounds gathers)."""
-    N, bs = pool.shape[0], pool.shape[1]
+    bs = pool.shape[-3]
     B, T = new.shape[0], new.shape[1]
-    flat = pool.reshape((N * bs,) + pool.shape[2:])
     p = pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]  # (B,T)
-    blk = jnp.take_along_axis(tables, p // bs, axis=1)
-    phys = (blk * bs + p % bs).reshape(-1)
-    flat = flat.at[phys].set(new.reshape((B * T,) + new.shape[2:]))
-    return flat.reshape(pool.shape)
+    blk = jnp.take_along_axis(tables, p // bs, axis=1).reshape(-1)
+    rows = new.reshape((B * T,) + new.shape[2:])
+    return pool.at[_pool_index(layer, blk, (p % bs).reshape(-1))].set(rows)
 
 
 def attention_chunk_paged(p: Dict, x: jax.Array, cache: Dict,
                           tables: jax.Array, pos: jax.Array,
-                          cfg: ModelConfig, *, impl: str = "auto"
+                          cfg: ModelConfig, *, impl: str = "auto",
+                          layer: Optional[jax.Array] = None
                           ) -> Tuple[jax.Array, Dict]:
     """Speculative-verification chunk over the paged layout
     (docs/ARCHITECTURE.md §5): score ``T`` candidate tokens ``x`` (B,T,d)
@@ -267,23 +272,26 @@ def attention_chunk_paged(p: Dict, x: jax.Array, cache: Dict,
     attend shared blocks directly through the table with no staging
     gather. ``impl="kernel"`` dispatches to the fused Pallas kernel
     (:func:`repro.kernels.ops.paged_prefill_attention`), which streams
-    physical blocks instead of gathering the logical view."""
+    physical blocks instead of gathering the logical view. ``layer`` as
+    in :func:`attention_decode_paged`."""
     B, T, _ = x.shape
     nb = tables.shape[1]
-    bs = cache["k"].shape[1]
+    bs = cache["k"].shape[-3]
     q_pos = pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
     q, k_new, v_new = _project_qkv(p, x, cfg, q_pos)
-    cache = {"k": _write_paged_chunk(cache["k"], k_new, tables, pos),
-             "v": _write_paged_chunk(cache["v"], v_new, tables, pos)}
+    cache = {"k": _write_paged_chunk(cache["k"], k_new, tables, pos, layer),
+             "v": _write_paged_chunk(cache["v"], v_new, tables, pos, layer)}
     scale = 1.0 / float(cfg.head_dim) ** 0.5
     if impl == "kernel":
         from repro.kernels import ops as kops
 
-        out = kops.paged_prefill_attention(q, cache["k"], cache["v"],
+        k_pool, v_pool = (cache["k"], cache["v"]) if layer is None else \
+            (cache["k"][layer], cache["v"][layer])
+        out = kops.paged_prefill_attention(q, k_pool, v_pool,
                                            tables, pos, scale)
     else:
-        k = cache["k"][tables].reshape((B, nb * bs) + cache["k"].shape[2:])
-        v = cache["v"][tables].reshape((B, nb * bs) + cache["v"].shape[2:])
+        k = _gather_paged(cache["k"], tables, layer)
+        v = _gather_paged(cache["v"], tables, layer)
         mask = jnp.arange(nb * bs, dtype=jnp.int32)[None, None, :] \
             <= q_pos[:, :, None]
         out = _sdpa(q, k, v, mask, scale)

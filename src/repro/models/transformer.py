@@ -158,7 +158,11 @@ def _layer_full(p: Dict, x: jax.Array, cfg: ModelConfig, kind: str,
 
 
 def _layer_decode(p: Dict, x: jax.Array, cfg: ModelConfig, kind: str,
-                  cache: Dict, ctx: Dict) -> Tuple[jax.Array, Dict]:
+                  cache: Dict, ctx: Dict,
+                  layer: Optional[jax.Array] = None
+                  ) -> Tuple[jax.Array, Dict]:
+    """One-token layer. ``layer`` indexes a scan-stacked block pool
+    (paged leaves only; see :func:`_trunk_cached`)."""
     window = _window_for(cfg, kind)
     if kind == "rwkv":
         return rk.rwkv_block(p, x, cfg, cache, decode=True,
@@ -170,13 +174,13 @@ def _layer_decode(p: Dict, x: jax.Array, cfg: ModelConfig, kind: str,
         new_cache = new_state
     else:
         h = apply_norm(p["attn_norm"], x, cfg.norm)
-        tables = ctx.get("block_tables")
-        if tables is not None and window is None:
+        if _paged_here(cfg, kind, ctx):
             # paged layout covers linear KV layers only; ring buffers
             # (windowed) are already bounded by the window and stay dense
             out, kv = attn.attention_decode_paged(
-                p["attn"], h, {"k": cache["k"], "v": cache["v"]}, tables,
-                ctx["pos"], cfg, impl=ctx["attn_impl"])
+                p["attn"], h, {"k": cache["k"], "v": cache["v"]},
+                ctx["block_tables"], ctx["pos"], cfg,
+                impl=ctx["attn_impl"], layer=layer)
         else:
             out, kv = attn.attention_decode(
                 p["attn"], h, {"k": cache["k"], "v": cache["v"]},
@@ -194,11 +198,14 @@ def _layer_decode(p: Dict, x: jax.Array, cfg: ModelConfig, kind: str,
 
 
 def _layer_chunk(p: Dict, x: jax.Array, cfg: ModelConfig, kind: str,
-                 cache: Dict, ctx: Dict) -> Tuple[jax.Array, Dict]:
+                 cache: Dict, ctx: Dict,
+                 layer: Optional[jax.Array] = None
+                 ) -> Tuple[jax.Array, Dict]:
     """Chunked-prefill continuation layer (docs/ARCHITECTURE.md §5):
     process T tokens starting at ``ctx["pos"]`` against a dense decode
     cache. Recurrent layers run their sequence form from the carried
-    state; attention layers attend cache + causal chunk prefix."""
+    state; attention layers attend cache + causal chunk prefix.
+    ``layer`` as in :func:`_layer_decode`."""
     window = _window_for(cfg, kind)
     if kind == "rwkv":
         return rk.rwkv_block(p, x, cfg, cache, decode=False,
@@ -210,13 +217,13 @@ def _layer_chunk(p: Dict, x: jax.Array, cfg: ModelConfig, kind: str,
         new_cache = new_state
     else:
         h = apply_norm(p["attn_norm"], x, cfg.norm)
-        tables = ctx.get("block_tables")
-        if tables is not None and window is None:
+        if _paged_here(cfg, kind, ctx):
             # paged layout covers linear KV layers only (same gate as
-            # _layer_decode) — used by the speculative verify forward
+            # _layer_decode): fused chunked prefill and speculative verify
             out, kv = attn.attention_chunk_paged(
-                p["attn"], h, {"k": cache["k"], "v": cache["v"]}, tables,
-                ctx["pos"], cfg, impl=ctx["attn_impl"])
+                p["attn"], h, {"k": cache["k"], "v": cache["v"]},
+                ctx["block_tables"], ctx["pos"], cfg,
+                impl=ctx["attn_impl"], layer=layer)
         else:
             out, kv = attn.attention_prefill_chunk(
                 p["attn"], h, {"k": cache["k"], "v": cache["v"]},
@@ -318,53 +325,53 @@ def _trunk_full(params: Dict, x: jax.Array, cfg: ModelConfig, ctx: Dict,
     return x, aux_total, caches
 
 
-def _trunk_decode(params: Dict, x: jax.Array, cfg: ModelConfig,
-                  cache: Dict, ctx: Dict) -> Tuple[jax.Array, Dict]:
+def _paged_here(cfg: ModelConfig, kind: str, ctx: Dict) -> bool:
+    """True when ``kind``'s cache leaf in this step is a block pool: the
+    step carries block tables and the layer is linear attention KV."""
+    return (ctx.get("block_tables") is not None
+            and paged_layer_kind(cfg, kind))
+
+
+def _trunk_cached(params: Dict, x: jax.Array, cfg: ModelConfig,
+                  cache: Dict, ctx: Dict, layer_fn) -> Tuple[jax.Array, Dict]:
+    """Run a cached step (decode, chunked prefill, verify) over the trunk.
+
+    The stacked unit caches ride in the scan's carry, not its xs/ys, so
+    the step updates them in place (docs/ARCHITECTURE.md §5): a paged
+    pool leaf gets its new rows scattered at ``(layer, block, offset)``
+    and its attended view gathered as ``pool[layer, tables]``, with no
+    per-layer slice and no stacked output written; a per-slot leaf
+    (dense KV, ring buffer, recurrent state) is sliced at the layer and
+    written back to the same carried array. Together with the engine
+    donating the cache, no step copies the whole pool."""
     n_units, tail_kinds = _split_layers(cfg)
     new_cache: Dict[str, Any] = {}
     if n_units:
-        def unit_body(x, scanned):
-            unit_params, unit_cache = scanned
+        def unit_body(carry, scanned):
+            x, unit_cache = carry
+            unit_params, layer = scanned
             new_unit_cache = []
             for pos, kind in enumerate(cfg.block_pattern):
-                x, c = _layer_decode(unit_params[pos], x, cfg, kind,
-                                     unit_cache[pos], ctx)
+                p, c = unit_params[pos], unit_cache[pos]
+                if _paged_here(cfg, kind, ctx):
+                    x, c = layer_fn(p, x, cfg, kind, c, ctx, layer=layer)
+                else:
+                    here = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
+                        a, layer, keepdims=False), c)
+                    x, here = layer_fn(p, x, cfg, kind, here, ctx)
+                    c = jax.tree.map(
+                        lambda a, n: jax.lax.dynamic_update_index_in_dim(
+                            a, n, layer, 0), c, here)
                 new_unit_cache.append(c)
-            return x, tuple(new_unit_cache)
+            return (x, tuple(new_unit_cache)), None
 
-        x, unit_caches = jax.lax.scan(
-            unit_body, x, (params["units"], cache["units"]))
-        new_cache["units"] = unit_caches
+        (x, new_cache["units"]), _ = jax.lax.scan(
+            unit_body, (x, cache["units"]),
+            (params["units"], jnp.arange(n_units, dtype=jnp.int32)))
     if tail_kinds:
         tail_caches = []
         for p_l, kind, c_l in zip(params["tail"], tail_kinds, cache["tail"]):
-            x, c = _layer_decode(p_l, x, cfg, kind, c_l, ctx)
-            tail_caches.append(c)
-        new_cache["tail"] = tuple(tail_caches)
-    return x, new_cache
-
-
-def _trunk_chunk(params: Dict, x: jax.Array, cfg: ModelConfig,
-                 cache: Dict, ctx: Dict) -> Tuple[jax.Array, Dict]:
-    n_units, tail_kinds = _split_layers(cfg)
-    new_cache: Dict[str, Any] = {}
-    if n_units:
-        def unit_body(x, scanned):
-            unit_params, unit_cache = scanned
-            new_unit_cache = []
-            for pos, kind in enumerate(cfg.block_pattern):
-                x, c = _layer_chunk(unit_params[pos], x, cfg, kind,
-                                    unit_cache[pos], ctx)
-                new_unit_cache.append(c)
-            return x, tuple(new_unit_cache)
-
-        x, unit_caches = jax.lax.scan(
-            unit_body, x, (params["units"], cache["units"]))
-        new_cache["units"] = unit_caches
-    if tail_kinds:
-        tail_caches = []
-        for p_l, kind, c_l in zip(params["tail"], tail_kinds, cache["tail"]):
-            x, c = _layer_chunk(p_l, x, cfg, kind, c_l, ctx)
+            x, c = layer_fn(p_l, x, cfg, kind, c_l, ctx)
             tail_caches.append(c)
         new_cache["tail"] = tuple(tail_caches)
     return x, new_cache
@@ -459,42 +466,30 @@ def paged_layer_kind(cfg: ModelConfig, kind: str) -> bool:
 
 
 def _layer_cache_spec(cfg: ModelConfig, kind: str, batch: int,
-                      cache_len: int, dtype, abstract: bool,
+                      cache_len: int, dtype,
                       paged: Optional[Tuple[int, int]] = None) -> Dict:
     window = _window_for(cfg, kind)
     if kind == "rwkv":
-        fn = rk.rwkv_state_spec if abstract else rk.rwkv_state_init
-        return fn(cfg, batch, dtype)
+        return rk.rwkv_state_spec(cfg, batch, dtype)
     if kind == "rglru":
-        fn = rg.rglru_state_spec if abstract else rg.rglru_state_init
-        return fn(cfg, batch, dtype)
+        return rg.rglru_state_spec(cfg, batch, dtype)
     if paged is not None and paged_layer_kind(cfg, kind):
         n_blocks, block_size = paged
-        fn = attn.paged_kv_cache_spec if abstract else attn.init_paged_kv_cache
-        return fn(cfg, n_blocks, block_size, dtype)
+        return attn.paged_kv_cache_spec(cfg, n_blocks, block_size, dtype)
     clen = min(cache_len, window) if window is not None else cache_len
-    fn = attn.kv_cache_spec if abstract else attn.init_kv_cache
-    c = fn(cfg, batch, clen, dtype)
+    c = attn.kv_cache_spec(cfg, batch, clen, dtype)
     if cfg.enc_dec:
         enc_len = ModelSpecs.enc_len(cache_len)
         shp = (batch, enc_len, cfg.n_kv_heads, cfg.head_dim)
-        if abstract:
-            c["ck"] = jax.ShapeDtypeStruct(shp, dtype)
-            c["cv"] = jax.ShapeDtypeStruct(shp, dtype)
-        else:
-            c["ck"] = jnp.zeros(shp, dtype)
-            c["cv"] = jnp.zeros(shp, dtype)
+        c["ck"] = jax.ShapeDtypeStruct(shp, dtype)
+        c["cv"] = jax.ShapeDtypeStruct(shp, dtype)
     return c
 
 
 def _stack_spec(specs: List[Any]) -> Any:
-    def stack_leaf(*leaves):
-        if isinstance(leaves[0], jax.ShapeDtypeStruct):
-            return jax.ShapeDtypeStruct((len(leaves),) + leaves[0].shape,
-                                        leaves[0].dtype)
-        return jnp.stack(leaves)
-
-    return jax.tree.map(stack_leaf, *specs)
+    return jax.tree.map(
+        lambda *leaves: jax.ShapeDtypeStruct(
+            (len(leaves),) + leaves[0].shape, leaves[0].dtype), *specs)
 
 
 def pad_cache(cfg: ModelConfig, cache: Dict, extra: int) -> Dict:
@@ -548,19 +543,19 @@ def make_cache(cfg: ModelConfig, batch: int, cache_len: int, dtype,
     n_units, tail_kinds = _split_layers(cfg)
     cache: Dict[str, Any] = {}
     if n_units:
-        units = []
-        for kind in cfg.block_pattern:
-            per = [_layer_cache_spec(cfg, kind, batch, cache_len, dtype,
-                                     abstract, paged)
-                   for _ in range(n_units)]
-            units.append(_stack_spec(per))
-        cache["units"] = tuple(units)
+        cache["units"] = tuple(
+            _stack_spec([_layer_cache_spec(cfg, kind, batch, cache_len,
+                                           dtype, paged)] * n_units)
+            for kind in cfg.block_pattern)
     if tail_kinds:
         cache["tail"] = tuple(
-            _layer_cache_spec(cfg, kind, batch, cache_len, dtype, abstract,
-                              paged)
+            _layer_cache_spec(cfg, kind, batch, cache_len, dtype, paged)
             for kind in tail_kinds)
-    return cache
+    if abstract:
+        return cache
+    # every decode state starts at zero; made at its stacked shape, so
+    # no per-layer copy of the pool exists beside the stacked one
+    return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), cache)
 
 
 # =====================================================================
@@ -722,7 +717,8 @@ class Model:
         x = apply_embed(params["embed"], batch["tokens"])
         ctx = {"pos": batch["pos"], "attn_impl": self.attn_impl,
                "block_tables": batch.get("block_tables")}
-        x, new_cache = _trunk_chunk(params, x, cfg, cache, ctx)
+        x, new_cache = _trunk_cached(params, x, cfg, cache, ctx,
+                                     _layer_chunk)
         logits = _lm_logits(params, x[:, -1:, :], cfg)
         return logits, new_cache
 
@@ -751,7 +747,8 @@ class Model:
         x = apply_embed(params["embed"], batch["tokens"])
         ctx = {"pos": batch["pos"], "attn_impl": self.attn_impl,
                "block_tables": batch.get("block_tables")}
-        x, new_cache = _trunk_chunk(params, x, cfg, cache, ctx)
+        x, new_cache = _trunk_cached(params, x, cfg, cache, ctx,
+                                     _layer_chunk)
         logits = _lm_logits(params, x, cfg)
         return logits, new_cache
 
@@ -764,7 +761,8 @@ class Model:
         x = apply_embed(params["embed"], batch["tokens"])
         ctx = {"pos": batch["pos"], "attn_impl": self.attn_impl,
                "block_tables": batch.get("block_tables")}
-        x, new_cache = _trunk_decode(params, x, cfg, cache, ctx)
+        x, new_cache = _trunk_cached(params, x, cfg, cache, ctx,
+                                     _layer_decode)
         logits = _lm_logits(params, x, cfg)
         return logits, new_cache
 

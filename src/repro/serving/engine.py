@@ -97,6 +97,16 @@ def sample_tokens(logits, greedy: bool = True, seed: int = 0) -> np.ndarray:
         jax.random.categorical(key, jnp.asarray(logits)).astype(jnp.int32))
 
 
+def jit_cache_step(step: Callable, **shardings) -> Callable:
+    """Jit a model step ``step(params, cache, batch) -> (out, cache)``
+    with the cache DONATED: the step updates it in place
+    (docs/ARCHITECTURE.md §5), so the caller must rebind the cache it
+    passed to the one returned and keep no other reference to it.
+    ``shardings``: the sharded engine's ``in_shardings`` /
+    ``out_shardings``."""
+    return jax.jit(step, donate_argnums=(1,), **shardings)
+
+
 #: largest chunked-prefill piece; pieces are powers of two up to this, so
 #: the chunk compile cache is bounded at one shape per piece size
 _MAX_CHUNK = 512
@@ -154,7 +164,7 @@ class InferenceEngine:
         self.model = build_model(cfg, remat=False)
         self.params = self.model.init(jax.random.PRNGKey(seed), dtype)
         self._prefill = jax.jit(self.model.prefill)
-        self._decode = jax.jit(self.model.decode_step)
+        self._decode = jit_cache_step(self.model.decode_step)
 
     def _make_batch(self, prompts: List[np.ndarray]
                     ) -> Tuple[Dict, int, np.ndarray]:
@@ -933,7 +943,7 @@ class ContinuousBatchingEngine:
             self._decode = share_from._decode
             self._verify = getattr(share_from, "_verify", None)
             if self._verify is None and supports_speculation(cfg):
-                self._verify = jax.jit(self.model.verify_step)
+                self._verify = jit_cache_step(self.model.verify_step)
         else:
             self.model = build_model(cfg, remat=False)
             key = jax.random.PRNGKey(seed)
@@ -948,10 +958,10 @@ class ContinuousBatchingEngine:
                         mesh, self.model.abstract_params(dtype)))(key)
             self._prefill = jax.jit(self.model.prefill)
             if mesh is None:
-                self._prefill_chunk = jax.jit(self.model.prefill_chunk) \
-                    if self.chunked else None
-                self._decode = jax.jit(self.model.decode_step)
-                self._verify = jax.jit(self.model.verify_step) \
+                self._prefill_chunk = jit_cache_step(
+                    self.model.prefill_chunk) if self.chunked else None
+                self._decode = jit_cache_step(self.model.decode_step)
+                self._verify = jit_cache_step(self.model.verify_step) \
                     if supports_speculation(cfg) else None
             else:
                 # sharded step jits need the cache pytree for their
@@ -1019,20 +1029,16 @@ class ContinuousBatchingEngine:
                                             jax.eval_shape(init_cache))
             self.cache = jax.jit(init_cache, out_shardings=cshard)()
             if self._decode is None:
-                pshard = engine_param_shardings(mesh, self.params)
                 rep = replicated(mesh)
-                self._prefill_chunk = jax.jit(
-                    self.model.prefill_chunk,
-                    in_shardings=(pshard, cshard, rep),
-                    out_shardings=(rep, cshard)) if self.chunked else None
-                self._decode = jax.jit(
-                    self.model.decode_step,
-                    in_shardings=(pshard, cshard, rep),
+                sharded = functools.partial(
+                    jit_cache_step,
+                    in_shardings=(engine_param_shardings(mesh, self.params),
+                                  cshard, rep),
                     out_shardings=(rep, cshard))
-                self._verify = jax.jit(
-                    self.model.verify_step,
-                    in_shardings=(pshard, cshard, rep),
-                    out_shardings=(rep, cshard)) \
+                self._prefill_chunk = sharded(self.model.prefill_chunk) \
+                    if self.chunked else None
+                self._decode = sharded(self.model.decode_step)
+                self._verify = sharded(self.model.verify_step) \
                     if supports_speculation(cfg) else None
         self.host_pool = None
         if self.swap_ok:

@@ -3,7 +3,7 @@ simulator parity with round mode (docs/ARCHITECTURE.md §5/§6)."""
 import numpy as np
 import pytest
 
-from conftest import TINY
+from conftest import KIND_CFGS, TINY, make_cont_engine
 from repro.config.base import ServingConfig
 from repro.core.baselines import FixedScheduler
 from repro.serving.bcedge import run_episode
@@ -112,6 +112,88 @@ def test_engine_rejects_enc_dec():
                               n_enc_layers=1)
     with pytest.raises(NotImplementedError):
         ContinuousBatchingEngine(enc, max_slots=2, max_seq=32)
+
+
+# ------------------------------------------------------------ donation
+def _drain(engines):
+    """Step ``engines`` in alternation until all are idle: each step
+    donates its engine's cache while the other's stays live."""
+    out = [{} for _ in engines]
+    for _ in range(200):
+        busy = False
+        for eng, got in zip(engines, out):
+            if eng.waiting or eng.active_slots:
+                busy = True
+                for r in eng.step():
+                    got[r.request_id] = r.tokens
+        if not busy:
+            return out
+    raise AssertionError("engines did not drain")
+
+
+@pytest.mark.parametrize("kind,spec_k", [("global", 0), ("global", 2),
+                                         ("rglru", 0)],
+                         ids=["fused", "speculative", "staging"])
+def test_shared_engines_donating_in_alternation_stay_identical(kind,
+                                                               spec_k):
+    """Engines built with ``share_from`` share the donating step jits but
+    own their caches: stepped in alternation (fused prefill, speculative
+    verify, or a hybrid stack's staging prefill and per-slot state), each
+    serves exactly what the same requests get served alone."""
+    cfg = KIND_CFGS[kind]
+    kw = dict(max_slots=2, max_seq=64, kv_layout="paged", block_size=8,
+              spec_k=spec_k)
+    rng = np.random.default_rng(7)
+    prompts = [[rng.integers(1, 97, n).astype(np.int32) for n in ns]
+               for ns in ((5, 13, 9), (11, 4))]
+    want = [[make_cont_engine(cfg, **kw).run([p], max_new_tokens=7)[0]
+             .tokens for p in ps] for ps in prompts]
+    donor = make_cont_engine(cfg, **kw)
+    engines = [donor, make_cont_engine(cfg, share_from=donor, **kw)]
+    assert engines[1]._decode is donor._decode
+    ids = [[eng.submit(p, max_new_tokens=7) for p in ps]
+           for eng, ps in zip(engines, prompts)]
+    for got, rids, ws in zip(_drain(engines), ids, want):
+        for rid, w in zip(rids, ws):
+            np.testing.assert_array_equal(got[rid], w)
+
+
+def test_swap_resume_and_prefix_hit_after_donated_steps():
+    """Host-tier swap out and back in, and a prefix-cache hit, on a pool
+    that many donated steps have updated in place: the swapped blocks
+    and the shared prefix blocks read back what was written."""
+    kw = dict(max_slots=2, max_seq=64, kv_layout="paged", block_size=8,
+              kv_blocks=24, kv_host_blocks=16, prefix_cache=True)
+    rng = np.random.default_rng(8)
+    first, other = (rng.integers(1, 97, n).astype(np.int32)
+                    for n in (21, 6))
+    sibling = np.concatenate([first[:16], rng.integers(1, 97, 5)
+                              .astype(np.int32)])
+    want = {name: make_cont_engine(TINY, **kw).run(
+        [p], max_new_tokens=10)[0].tokens
+        for name, p in (("first", first), ("other", other),
+                        ("sibling", sibling))}
+    eng = make_cont_engine(TINY, **kw)
+    rid = {"first": eng.submit(first, max_new_tokens=10),
+           "other": eng.submit(other, max_new_tokens=10)}
+    got = {}
+    for _ in range(5):
+        for r in eng.step():
+            got[r.request_id] = r.tokens
+    slot = next(i for i in eng.decoding_slots
+                if eng.slots[i].request_id == rid["first"])
+    snap = eng.preempt(slot, requeue=False, mode="swap")
+    assert snap.swapped
+    for _ in range(3):  # donated steps while the blocks sit on the host
+        for r in eng.step():
+            got[r.request_id] = r.tokens
+    rid["first"] = eng.submit_resume(snap)
+    got.update(_drain([eng])[0])
+    rid["sibling"] = eng.submit(sibling, max_new_tokens=10)
+    got.update(_drain([eng])[0])
+    assert eng.n_swap_resumes == 1 and eng.n_prefix_hits >= 1
+    for name, w in want.items():
+        np.testing.assert_array_equal(got[rid[name]], w, err_msg=name)
 
 
 # ------------------------------------------------------------ workload

@@ -10,7 +10,11 @@ head_dim 128, block size 16), in float32 and bfloat16:
   kernels must read the pool in place (no relayout copy of it, which
   would cost a whole-pool HBM round trip per call);
 * the jitted paged ``decode_step`` of full-width qwen3-0.6b, from
-  ``jax.eval_shape`` shapes.
+  ``jax.eval_shape`` shapes;
+* the engine's donated ``decode_step`` and ``prefill_chunk`` at the
+  chat cell's shapes, which must update the pool in place: the whole
+  pool aliased to the output, no pool-sized temporary, and no copy or
+  slice of the pool or of one layer of it.
 
 The topology is described inside a module fixture, never at import: a
 test worker that loads the TPU library holds it until it exits, so only
@@ -128,3 +132,49 @@ def test_qwen3_paged_decode_step_compiles_for_v5e(one_chip):
     mem = compiled.memory_analysis()
     # 0.6 B float32 parameters: ~2.4 GB, inside one chip's 16 GB
     assert 2.0e9 < mem.argument_size_in_bytes < 16e9
+
+
+@pytest.mark.parametrize("step", ["decode_step", "prefill_chunk"])
+def test_qwen3_paged_step_updates_pool_in_place(one_chip, step):
+    """Full-width qwen3-0.6b at the chat cell's shapes (16 slots, 768
+    positions, 769 blocks of 16, float32), jitted as the engine jits it
+    (``jit_cache_step``, the cache donated): the carried, donated pool
+    is written in place, so the executable aliases both whole pools and
+    holds no temporary as large as one."""
+    from repro.config import get_config
+    from repro.models import build_model
+    from repro.serving.engine import jit_cache_step
+
+    cfg = get_config("qwen3-0.6b")
+    model = build_model(cfg, remat=False)
+    slots, max_seq, bs = 16, 768, 16
+    per_slot = max_seq // bs
+    n_blocks = slots * per_slot + 1
+    units = cfg.n_layers
+
+    def place(tree):
+        return jax.tree.map(
+            lambda x: _sds(x.shape, x.dtype, one_chip), tree)
+
+    params = place(model.abstract_params(jnp.float32))
+    cache = place(model.paged_cache_spec(slots, max_seq, n_blocks, bs,
+                                         jnp.float32))
+    rows, width = (slots, 1) if step == "decode_step" else (1, 128)
+    batch = {"tokens": _sds((rows, width), jnp.int32, one_chip),
+             "pos": _sds((rows,), jnp.int32, one_chip),
+             "block_tables": _sds((rows, per_slot), jnp.int32, one_chip)}
+    compiled = jit_cache_step(getattr(model, step)).lower(
+        params, cache, batch).compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = units * n_blocks * bs * cfg.n_kv_heads * cfg.head_dim * 4
+    assert mem.alias_size_in_bytes >= 2 * pool_bytes
+    # the rest of the temporary is the per-layer gathered table view,
+    # activations and logits; a pool-sized one means a copy came back
+    assert mem.temp_size_in_bytes < pool_bytes
+    shape = rf"f32\[(?:{units},|1,)?{n_blocks},{bs},{cfg.n_kv_heads}," \
+        rf"{cfg.head_dim}\]"
+    moved = re.compile(
+        rf"%\S*(?:copy|dynamic-slice|dynamic-update-slice)\S* = {shape}"
+        rf"|= {shape}\S* (?:copy|dynamic-slice|dynamic-update-slice)\(")
+    hlo = compiled.as_text()
+    assert not [line for line in hlo.splitlines() if moved.search(line)]
