@@ -30,6 +30,7 @@ class ModelConfig:
     # attention flavour
     rope: str = "rope"  # rope | rope2d | mrope | none
     qk_norm: bool = False
+    qkv_bias: bool = False  # bias on the q/k/v projections (chatglm3)
     sliding_window: Optional[int] = None  # tokens; None = full attention
     rope_theta: float = 10_000.0
 
@@ -56,6 +57,7 @@ class ModelConfig:
 
     # misc
     norm: str = "rmsnorm"  # rmsnorm | layernorm
+    norm_eps: float = 1e-6  # every norm of the trunk, q/k norms included
     activation: str = "silu"  # silu | gelu
     tie_embeddings: bool = False
     rwkv_head_size: int = 64
@@ -105,6 +107,8 @@ class ModelConfig:
         emb = self.vocab_size * d * (1 if self.tie_embeddings else 2)
         attn_p = (d * hd * self.n_heads + 2 * d * hd * self.n_kv_heads
                   + hd * self.n_heads * d)
+        if self.qkv_bias:
+            attn_p += hd * (self.n_heads + 2 * self.n_kv_heads)
         gated = self.activation in ("silu", "geglu")
         n_mats = 3 if gated else 2
 

@@ -42,6 +42,8 @@ def _base_spec(path: str, eff_ndim: int) -> Tuple:
         return (None, M)
     if name in ("wq", "wk", "wv"):
         return (None, M)
+    if name in ("bq", "bk", "bv"):                     # output dim of wq/wk/wv
+        return (M,)
     if name == "wo":
         return (M, None)
     if name in ("w_up", "w_gate") and eff_ndim == 3:   # MoE experts (E,d,f)

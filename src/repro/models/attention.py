@@ -42,6 +42,14 @@ def attn_init(rng, cfg: ModelConfig, dtype) -> Dict:
     if cfg.qk_norm:
         p["q_norm"] = norm_init(hd, "rmsnorm", dtype)
         p["k_norm"] = norm_init(hd, "rmsnorm", dtype)
+    if cfg.qkv_bias:
+        # keys of their own, so a model without the bias draws as before
+        kb = jax.random.split(jax.random.fold_in(rng, 1), 3)
+        for key, name, heads in zip(kb, ("bq", "bk", "bv"),
+                                    (cfg.n_heads, cfg.n_kv_heads,
+                                     cfg.n_kv_heads)):
+            p[name] = (jax.random.normal(key, (heads * hd,), jnp.float32)
+                       * d ** -0.5).astype(dtype)
     return p
 
 
@@ -50,12 +58,19 @@ def _project_qkv(p: Dict, x: jax.Array, cfg: ModelConfig,
                  mrope_positions=None) -> Tuple[jax.Array, jax.Array, jax.Array]:
     B, S, _ = x.shape
     hd = cfg.head_dim
-    q = (x @ p["wq"]).reshape(B, S, cfg.n_heads, hd)
-    k = (x @ p["wk"]).reshape(B, S, cfg.n_kv_heads, hd)
-    v = (x @ p["wv"]).reshape(B, S, cfg.n_kv_heads, hd)
+
+    def project(name: str, heads: int) -> jax.Array:
+        y = x @ p["w" + name]
+        if cfg.qkv_bias:
+            y = y + p["b" + name]
+        return y.reshape(B, S, heads, hd)
+
+    q = project("q", cfg.n_heads)
+    k = project("k", cfg.n_kv_heads)
+    v = project("v", cfg.n_kv_heads)
     if cfg.qk_norm:
-        q = apply_norm(p["q_norm"], q, "rmsnorm")
-        k = apply_norm(p["k_norm"], k, "rmsnorm")
+        q = apply_norm(p["q_norm"], q, "rmsnorm", cfg.norm_eps)
+        k = apply_norm(p["k_norm"], k, "rmsnorm", cfg.norm_eps)
     q = apply_rope(q, positions, cfg.rope, cfg.rope_theta, mrope_positions)
     k = apply_rope(k, positions, cfg.rope, cfg.rope_theta, mrope_positions)
     return q, k, v

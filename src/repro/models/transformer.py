@@ -133,12 +133,12 @@ def _layer_full(p: Dict, x: jax.Array, cfg: ModelConfig, kind: str,
         return x, aux, new_state
     if kind == "rglru":
         state = rg.rglru_state_init(cfg, x.shape[0], x.dtype)
-        h = apply_norm(p["rec"]["norm"], x, cfg.norm)
+        h = apply_norm(p["rec"]["norm"], x, cfg.norm, cfg.norm_eps)
         out, new_state = rg.rglru_seq(p["rec"], h, cfg, state)
         x = x + out
         cache_out = new_state
     else:
-        h = apply_norm(p["attn_norm"], x, cfg.norm)
+        h = apply_norm(p["attn_norm"], x, cfg.norm, cfg.norm_eps)
         out = attn.attention_full(
             p["attn"], h, cfg, ctx["positions"], window=window,
             impl=ctx["attn_impl"], chunk=ctx["chunk"],
@@ -147,12 +147,12 @@ def _layer_full(p: Dict, x: jax.Array, cfg: ModelConfig, kind: str,
         if ctx.get("return_cache"):
             cache_out = _prefill_kv(p["attn"], h, cfg, ctx, window)
         if cfg.enc_dec and "cross" in p:
-            h = apply_norm(p["cross_norm"], x, cfg.norm)
+            h = apply_norm(p["cross_norm"], x, cfg.norm, cfg.norm_eps)
             out, ck, cv = _cross_full(p["cross"], h, ctx["enc_out"], cfg)
             x = x + out
             if ctx.get("return_cache"):
                 cache_out = {**cache_out, "ck": ck, "cv": cv}
-    h = apply_norm(p["ffn_norm"], x, cfg.norm)
+    h = apply_norm(p["ffn_norm"], x, cfg.norm, cfg.norm_eps)
     y, ffn_aux = _ffn_apply(p["ffn"], h, cfg, kind)
     return x + y, aux + ffn_aux, cache_out
 
@@ -168,12 +168,12 @@ def _layer_decode(p: Dict, x: jax.Array, cfg: ModelConfig, kind: str,
         return rk.rwkv_block(p, x, cfg, cache, decode=True,
                              norm_kind=cfg.norm)
     if kind == "rglru":
-        h = apply_norm(p["rec"]["norm"], x, cfg.norm)
+        h = apply_norm(p["rec"]["norm"], x, cfg.norm, cfg.norm_eps)
         out, new_state = rg.rglru_decode(p["rec"], h, cfg, cache)
         x = x + out
         new_cache = new_state
     else:
-        h = apply_norm(p["attn_norm"], x, cfg.norm)
+        h = apply_norm(p["attn_norm"], x, cfg.norm, cfg.norm_eps)
         if _paged_here(cfg, kind, ctx):
             # paged layout covers linear KV layers only; ring buffers
             # (windowed) are already bounded by the window and stay dense
@@ -188,11 +188,11 @@ def _layer_decode(p: Dict, x: jax.Array, cfg: ModelConfig, kind: str,
         x = x + out
         new_cache = dict(kv)
         if cfg.enc_dec and "cross" in p:
-            h = apply_norm(p["cross_norm"], x, cfg.norm)
+            h = apply_norm(p["cross_norm"], x, cfg.norm, cfg.norm_eps)
             out = _cross_cached(p["cross"], h, cache["ck"], cache["cv"], cfg)
             x = x + out
             new_cache["ck"], new_cache["cv"] = cache["ck"], cache["cv"]
-    h = apply_norm(p["ffn_norm"], x, cfg.norm)
+    h = apply_norm(p["ffn_norm"], x, cfg.norm, cfg.norm_eps)
     y, _ = _ffn_apply(p["ffn"], h, cfg, kind)
     return x + y, new_cache
 
@@ -211,12 +211,12 @@ def _layer_chunk(p: Dict, x: jax.Array, cfg: ModelConfig, kind: str,
         return rk.rwkv_block(p, x, cfg, cache, decode=False,
                              norm_kind=cfg.norm)
     if kind == "rglru":
-        h = apply_norm(p["rec"]["norm"], x, cfg.norm)
+        h = apply_norm(p["rec"]["norm"], x, cfg.norm, cfg.norm_eps)
         out, new_state = rg.rglru_seq(p["rec"], h, cfg, cache)
         x = x + out
         new_cache = new_state
     else:
-        h = apply_norm(p["attn_norm"], x, cfg.norm)
+        h = apply_norm(p["attn_norm"], x, cfg.norm, cfg.norm_eps)
         if _paged_here(cfg, kind, ctx):
             # paged layout covers linear KV layers only (same gate as
             # _layer_decode): fused chunked prefill and speculative verify
@@ -230,7 +230,7 @@ def _layer_chunk(p: Dict, x: jax.Array, cfg: ModelConfig, kind: str,
                 ctx["pos"], cfg, window=window, impl=ctx["attn_impl"])
         x = x + out
         new_cache = dict(kv)
-    h = apply_norm(p["ffn_norm"], x, cfg.norm)
+    h = apply_norm(p["ffn_norm"], x, cfg.norm, cfg.norm_eps)
     y, _ = _ffn_apply(p["ffn"], h, cfg, kind)
     return x + y, new_cache
 
@@ -386,17 +386,18 @@ def _encoder_apply(params: Dict, embeds: jax.Array, cfg: ModelConfig,
            "return_cache": False}
 
     def body(x, layer_p):
-        h = apply_norm(layer_p["attn_norm"], x, cfg.norm)
+        h = apply_norm(layer_p["attn_norm"], x, cfg.norm, cfg.norm_eps)
         q, k, v = attn._project_qkv(layer_p["attn"], h, cfg, positions)
         mask = jnp.ones((B, T, T), bool)  # bidirectional
         out = attn._sdpa(q, k, v, mask, 1.0 / float(cfg.head_dim) ** 0.5)
         x = x + out.reshape(B, T, -1) @ layer_p["attn"]["wo"]
-        h = apply_norm(layer_p["ffn_norm"], x, cfg.norm)
+        h = apply_norm(layer_p["ffn_norm"], x, cfg.norm, cfg.norm_eps)
         y, _ = _ffn_apply(layer_p["ffn"], h, cfg)
         return x + y, None
 
     x, _ = jax.lax.scan(body, x, params["encoder"]["layers"])
-    return apply_norm(params["encoder"]["final_norm"], x, cfg.norm)
+    return apply_norm(params["encoder"]["final_norm"], x, cfg.norm,
+                      cfg.norm_eps)
 
 
 # =====================================================================
@@ -427,7 +428,7 @@ def _embed_inputs(params: Dict, batch: Dict, cfg: ModelConfig
 
 
 def _lm_logits(params: Dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
-    x = apply_norm(params["final_norm"], x, cfg.norm)
+    x = apply_norm(params["final_norm"], x, cfg.norm, cfg.norm_eps)
     head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
     return unembed(x, head, cfg.tie_embeddings, cfg.logit_softcap)
 

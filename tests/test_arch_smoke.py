@@ -157,6 +157,14 @@ def test_full_configs_match_assignment():
         assert cfg.d_ff == ff, arch
         assert cfg.vocab_size == V, arch
         assert cfg.n_experts == E, arch
+    # ChatGLM3 as published: QKV bias and its own RMSNorm epsilon; every
+    # other preset keeps neither
+    glm = get_config("chatglm3-6b")
+    assert glm.qkv_bias and glm.norm_eps == 1e-5 and glm.rope == "rope2d"
+    for arch in spec:
+        if arch != "chatglm3-6b":
+            assert not get_config(arch).qkv_bias, arch
+            assert get_config(arch).norm_eps == 1e-6, arch
 
 
 def test_param_count_estimates():

@@ -129,6 +129,27 @@ check("speculative", ref, got)
 
 
 @pytest.mark.slow
+def test_sharded_engine_token_identity_with_qkv_bias():
+    """A ChatGLM3-shaped model (QKV bias, eps 1e-5, partial rotary, 2 KV
+    heads) head-shards on a 2-way mesh, its biases split like the output
+    columns of wq/wk/wv, and stays token-identical to tp=1."""
+    out = _run_sub("""
+import dataclasses
+cfg = dataclasses.replace(TINY4, name="tiny4-glm", n_kv_heads=2,
+                          qkv_bias=True, norm_eps=1e-5, rope="rope2d")
+mesh = make_tp_mesh(2)
+_, ref = toks(cfg, None, kv_layout="paged", block_size=8)
+eng, got = toks(cfg, mesh, kv_layout="paged", block_size=8)
+attn = eng.params["units"][0]["attn"]
+for name in ("bq", "bk", "bv"):
+    assert tuple(attn[name].sharding.spec) == (None, "model"), name
+assert float(abs(attn["bq"]).max()) > 0.0
+check("glm_bias", ref, got)
+""")
+    assert "glm_bias OK" in out
+
+
+@pytest.mark.slow
 def test_pool_spawns_tp_instances():
     """ModelInstancePool carves TP instances from the FREE devices of
     the shared set: instances span disjoint meshes (tp=1 included),

@@ -84,7 +84,9 @@ for kwargs in (dict(name="tiny", family="dense",
                dict(name="tiny-rwkv", family="ssm",
                     block_pattern=("rwkv",), rwkv_head_size=16),
                dict(name="tiny-rglru", family="ssm",
-                    block_pattern=("rglru",))):
+                    block_pattern=("rglru",)),
+               dict(name="tiny-bias", family="dense",
+                    block_pattern=("attn",), qkv_bias=True)):
     cfg = ModelConfig(n_layers=2, d_model=32, n_heads=2,
                       n_kv_heads=2, d_ff=64, vocab_size=97, **kwargs)
     model = build_model(cfg, remat=False)
@@ -112,3 +114,21 @@ def test_sharding_specs_divide_mesh_all_families():
     for arch in FAMILY_ARCHS:
         assert f"{arch} OK" in out.stdout
     assert "DONE" in out.stdout
+
+
+def test_qkv_bias_shards_like_the_projection_columns():
+    """bq/bk/bv split on ``model`` as the output dim of wq/wk/wv does, so
+    each head shard adds its own heads' bias; stacked under a unit, the
+    leading layer dim stays whole."""
+    from repro.launch.sharding import _base_spec, param_pspec
+
+    class Leaf:
+        def __init__(self, shape):
+            self.shape, self.ndim = shape, len(shape)
+
+    for name in ("bq", "bk", "bv"):
+        assert _base_spec(f"units/0/attn/{name}", 1) == ("model",)
+        assert tuple(param_pspec(f"units/0/attn/{name}", Leaf((10, 4096)))) \
+            == (None, "model")
+        assert tuple(param_pspec(f"tail/0/attn/{name}", Leaf((256,)))) \
+            == ("model",)

@@ -14,7 +14,9 @@ head_dim 128, block size 16), in float32 and bfloat16:
 * the engine's donated ``decode_step`` and ``prefill_chunk`` at the
   chat cell's shapes, which must update the pool in place: the whole
   pool aliased to the output, no pool-sized temporary, and no copy or
-  slice of the pool or of one layer of it.
+  slice of the pool or of one layer of it;
+* the same two steps of ChatGLM3-6B cut to 10 layers, the benchmark's
+  largest model, which must fit one chip with both pools aliased.
 
 The topology is described inside a module fixture, never at import: a
 test worker that loads the TPU library holds it until it exits, so only
@@ -22,6 +24,7 @@ the worker given this file may do so. The persistent compilation cache
 is off around the compiles (an entry written for a described chip cannot
 be read back without one).
 """
+import dataclasses
 import os
 import re
 
@@ -134,30 +137,30 @@ def test_qwen3_paged_decode_step_compiles_for_v5e(one_chip):
     assert 2.0e9 < mem.argument_size_in_bytes < 16e9
 
 
-@pytest.mark.parametrize("step", ["decode_step", "prefill_chunk"])
-def test_qwen3_paged_step_updates_pool_in_place(one_chip, step):
-    """Full-width qwen3-0.6b at the chat cell's shapes (16 slots, 768
+#: the chat cell's serving shape: slots, positions per slot, block size
+CHAT_SLOTS, CHAT_SEQ, CHAT_BS = 16, 768, 16
+
+
+def _chat_step(one_chip, cfg, step):
+    """``step`` of ``cfg`` at the chat cell's shapes (16 slots, 768
     positions, 769 blocks of 16, float32), jitted as the engine jits it
-    (``jit_cache_step``, the cache donated): the carried, donated pool
-    is written in place, so the executable aliases both whole pools and
-    holds no temporary as large as one."""
-    from repro.config import get_config
+    (``jit_cache_step``, the cache donated) and compiled for the chip.
+    Returns the executable, the bytes of one stacked pool and the block
+    count."""
     from repro.models import build_model
     from repro.serving.engine import jit_cache_step
 
-    cfg = get_config("qwen3-0.6b")
     model = build_model(cfg, remat=False)
-    slots, max_seq, bs = 16, 768, 16
-    per_slot = max_seq // bs
+    slots, bs = CHAT_SLOTS, CHAT_BS
+    per_slot = CHAT_SEQ // bs
     n_blocks = slots * per_slot + 1
-    units = cfg.n_layers
 
     def place(tree):
         return jax.tree.map(
             lambda x: _sds(x.shape, x.dtype, one_chip), tree)
 
     params = place(model.abstract_params(jnp.float32))
-    cache = place(model.paged_cache_spec(slots, max_seq, n_blocks, bs,
+    cache = place(model.paged_cache_spec(slots, CHAT_SEQ, n_blocks, bs,
                                          jnp.float32))
     rows, width = (slots, 1) if step == "decode_step" else (1, 128)
     batch = {"tokens": _sds((rows, width), jnp.int32, one_chip),
@@ -165,12 +168,27 @@ def test_qwen3_paged_step_updates_pool_in_place(one_chip, step):
              "block_tables": _sds((rows, per_slot), jnp.int32, one_chip)}
     compiled = jit_cache_step(getattr(model, step)).lower(
         params, cache, batch).compile()
+    pool_bytes = cfg.n_layers * n_blocks * bs * cfg.n_kv_heads \
+        * cfg.head_dim * 4
+    return compiled, pool_bytes, n_blocks
+
+
+@pytest.mark.parametrize("step", ["decode_step", "prefill_chunk"])
+def test_qwen3_paged_step_updates_pool_in_place(one_chip, step):
+    """Full-width qwen3-0.6b at the chat cell's shapes, jitted as the
+    engine jits it: the carried, donated pool is written in place, so
+    the executable aliases both whole pools and holds no temporary as
+    large as one."""
+    from repro.config import get_config
+
+    cfg = get_config("qwen3-0.6b")
+    compiled, pool_bytes, n_blocks = _chat_step(one_chip, cfg, step)
     mem = compiled.memory_analysis()
-    pool_bytes = units * n_blocks * bs * cfg.n_kv_heads * cfg.head_dim * 4
     assert mem.alias_size_in_bytes >= 2 * pool_bytes
     # the rest of the temporary is the per-layer gathered table view,
     # activations and logits; a pool-sized one means a copy came back
     assert mem.temp_size_in_bytes < pool_bytes
+    units, bs = cfg.n_layers, CHAT_BS
     shape = rf"f32\[(?:{units},|1,)?{n_blocks},{bs},{cfg.n_kv_heads}," \
         rf"{cfg.head_dim}\]"
     moved = re.compile(
@@ -178,3 +196,26 @@ def test_qwen3_paged_step_updates_pool_in_place(one_chip, step):
         rf"|= {shape}\S* (?:copy|dynamic-slice|dynamic-update-slice)\(")
     hlo = compiled.as_text()
     assert not [line for line in hlo.splitlines() if moved.search(line)]
+
+
+#: one v5e chip's HBM as the runtime offers it to a program
+V5E_HBM_BYTES = 15.75 * 2 ** 30
+
+
+@pytest.mark.parametrize("step", ["decode_step", "prefill_chunk"])
+def test_chatglm3_paged_step_fits_one_chip(one_chip, step):
+    """ChatGLM3-6B as published (QKV bias, eps 1e-5) cut to the
+    benchmark's 10 layers, at the chat cell's shapes: its 10.3 GB of
+    float32 weights, the pool and the step's temporaries fit one chip,
+    and the donated pools are written in place. This guards the depth
+    cut."""
+    from repro.config import get_config
+
+    cfg = dataclasses.replace(get_config("chatglm3-6b"), n_layers=10)
+    assert cfg.qkv_bias and cfg.norm_eps == 1e-5
+    compiled, pool_bytes, _ = _chat_step(one_chip, cfg, step)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * pool_bytes
+    assert 10.0e9 < mem.argument_size_in_bytes < 10.6e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        <= V5E_HBM_BYTES
