@@ -51,9 +51,10 @@ PROMPT_LENS = (5, 12, 23, 31)
 MAX_NEW = 16
 
 #: Tolerance of the served path against the reference, as a fraction of
-#: the reference's largest |logit|. The server computes in float32 at
-#: the backend's default matmul precision, which on a TPU rounds every
-#: matmul input to bfloat16 (relative error 2^-9); 28 layers of that
+#: the reference's largest |logit|. The server keeps float32
+#: activations, and on a TPU every product takes bfloat16 inputs (the
+#: weights stored so, the activations rounded: relative error 2^-9,
+#: as the default-precision float32 product had); 28 layers of that
 #: leave the logits a few parts in a thousand off. A served greedy token
 #: must score within this margin of the reference's best token: a
 #: near-tie may flip, anything else is a bug.
@@ -205,13 +206,15 @@ def serve_and_check(cfg, seed: int = 0) -> dict:
 # ------------------------------------------------------- reference phase
 def check_against_reference(cfg, engine, prompts, served) -> None:
     """Teacher-forced reference over prompt + served tokens (float32,
-    highest precision, plain attention) on the served weights. The
+    highest precision, plain attention) on the served weights' values,
+    widened to float32 whatever type the engine stores them in. The
     engine serves each prompt left-padded with token 0 to its length
     bucket and attends the pad rows, so the reference reads that same
     padded prompt."""
     from repro.models import build_model
 
-    ref = build_model(cfg, remat=False, attn_impl="naive")
+    ref = build_model(cfg, remat=False, attn_impl="naive",
+                      compute_dtype=jnp.float32)
     prompts = [engine._padded_seq(p) for p in prompts]
     lens = [len(p) + len(s) for p, s in zip(prompts, served)]
     S = max(lens)
@@ -381,7 +384,8 @@ def sharded_serving(cfg, tp: int = 4) -> None:
 
 def tp_matches_single_chip(cfg, tp: int = 4) -> None:
     """First-token logits and greedy tokens of a depth-cut cfg at degree
-    tp vs degree 1, both at the highest matmul precision."""
+    tp vs degree 1, both with float32 weights at the highest matmul
+    precision, so the two differ only in the order of their sums."""
     from repro.launch.mesh import make_tp_mesh
     from repro.serving.engine import ContinuousBatchingEngine
 
@@ -390,7 +394,8 @@ def tp_matches_single_chip(cfg, tp: int = 4) -> None:
     logits, toks = [], []
     for mesh in (None, make_tp_mesh(tp)):
         eng = ContinuousBatchingEngine(cfg, max_slots=1, max_seq=128,
-                                       seed=0, mesh=mesh)
+                                       seed=0, mesh=mesh,
+                                       weight_dtype=jnp.float32)
         with jax.default_matmul_precision("highest"):
             lg, _ = jax.jit(eng.model.prefill)(eng.params,
                                                {"tokens": prompt[None]})

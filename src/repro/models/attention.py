@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.config.base import ModelConfig
-from repro.models.layers import dense_init, norm_init, apply_norm
+from repro.models.layers import apply_norm, dense, dense_init, norm_init
 from repro.models.rope import apply_rope
 
 NEG_INF = -2.0e38
@@ -60,7 +60,7 @@ def _project_qkv(p: Dict, x: jax.Array, cfg: ModelConfig,
     hd = cfg.head_dim
 
     def project(name: str, heads: int) -> jax.Array:
-        y = x @ p["w" + name]
+        y = dense(x, p["w" + name])
         if cfg.qkv_bias:
             y = y + p["b" + name]
         return y.reshape(B, S, heads, hd)
@@ -146,7 +146,7 @@ def attention_full(p: Dict, x: jax.Array, cfg: ModelConfig,
         out = jnp.moveaxis(out, 0, 1).reshape(B, S, cfg.n_heads, cfg.head_dim)
     else:
         raise ValueError(f"unknown attention impl {impl!r}")
-    return out.reshape(B, S, -1) @ p["wo"]
+    return dense(out.reshape(B, S, -1), p["wo"])
 
 
 # ---------------------------------------------------------------- decode
@@ -241,7 +241,7 @@ def attention_decode_paged(p: Dict, x: jax.Array, cache: Dict,
         v = _gather_paged(cache["v"], tables, layer)
         valid = jnp.arange(nb * bs, dtype=jnp.int32)[None, :] <= pos[:, None]
         out = _sdpa(q, k, v, valid[:, None, :], scale)
-    return out.reshape(B, 1, -1) @ p["wo"], cache
+    return dense(out.reshape(B, 1, -1), p["wo"]), cache
 
 
 def _write_paged_chunk(pool: jax.Array, new: jax.Array, tables: jax.Array,
@@ -310,7 +310,7 @@ def attention_chunk_paged(p: Dict, x: jax.Array, cache: Dict,
         mask = jnp.arange(nb * bs, dtype=jnp.int32)[None, None, :] \
             <= q_pos[:, :, None]
         out = _sdpa(q, k, v, mask, scale)
-    return out.reshape(B, T, -1) @ p["wo"], cache
+    return dense(out.reshape(B, T, -1), p["wo"]), cache
 
 
 def _write_chunk_linear(cache: jax.Array, new: jax.Array,
@@ -380,7 +380,7 @@ def attention_prefill_chunk(p: Dict, x: jax.Array, cache: Dict,
     write = _write_chunk_ring if window is not None else _write_chunk_linear
     cache = {"k": write(cache["k"], k_new, pos),
              "v": write(cache["v"], v_new, pos)}
-    return out.reshape(B, T, -1) @ p["wo"], cache
+    return dense(out.reshape(B, T, -1), p["wo"]), cache
 
 
 def attention_decode(p: Dict, x: jax.Array, cache: Dict, pos: jax.Array,
@@ -410,4 +410,4 @@ def attention_decode(p: Dict, x: jax.Array, cache: Dict, pos: jax.Array,
     else:
         mask = valid[:, None, :]  # (B,1,C)
         out = _sdpa(q, cache["k"], cache["v"], mask, scale)
-    return out.reshape(B, 1, -1) @ p["wo"], cache
+    return dense(out.reshape(B, 1, -1), p["wo"]), cache

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.config.base import ModelConfig
-from repro.models.layers import dense_init, mlp_init, apply_mlp
+from repro.models.layers import apply_mlp, dense, dense_init, mlp_init
 
 
 def moe_init(rng, cfg: ModelConfig, dtype) -> Dict:
@@ -51,7 +51,7 @@ def moe_apply(p: Dict, x: jax.Array, cfg: ModelConfig
     E, k = cfg.n_experts, cfg.top_k
     N = B * S
     xf = x.reshape(N, d)
-    logits = (xf @ p["router"]).astype(jnp.float32)  # (N,E)
+    logits = dense(xf, p["router"]).astype(jnp.float32)  # (N,E)
     probs = jax.nn.softmax(logits, axis=-1)
     gate_vals, gate_idx = jax.lax.top_k(probs, k)  # (N,k)
     if k > 1:
@@ -82,13 +82,13 @@ def moe_apply(p: Dict, x: jax.Array, cfg: ModelConfig
 
     buf = constrain(buf, "model", None, None)
     two_d = cap <= 64
-    h = jnp.einsum("ecd,edf->ecf", buf, p["w_gate"])
-    u = jnp.einsum("ecd,edf->ecf", buf, p["w_up"])
+    h = dense(buf, p["w_gate"], "ecd,edf->ecf")
+    u = dense(buf, p["w_up"], "ecd,edf->ecf")
     if two_d:
         h = constrain(h, "model", None, "data")
         u = constrain(u, "model", None, "data")
     h = jax.nn.silu(h) * u
-    y_e = jnp.einsum("ecf,efd->ecd", h, p["w_down"])  # (E,Cap,d)
+    y_e = dense(h, p["w_down"], "ecf,efd->ecd")  # (E,Cap,d)
     y_e = constrain(y_e, "model", None, None)
 
     # gather back and weight by gates

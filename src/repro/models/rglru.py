@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.config.base import ModelConfig
-from repro.models.layers import dense_init, norm_init
+from repro.models.layers import dense, dense_init, norm_init
 
 CONV_W = 4
 DECAY_C = 8.0
@@ -64,8 +64,8 @@ def _conv1d_causal(u: jax.Array, conv_w: jax.Array, conv_b: jax.Array,
 def _gates(p: Dict, u: jax.Array):
     """Returns (a, gated_in) in u's dtype (bf16-safe; the scan carry stays
     f32). Gate math runs in f32 internally."""
-    r = jax.nn.sigmoid((u @ p["W_a"] + p["b_a"]).astype(jnp.float32))
-    i = jax.nn.sigmoid((u @ p["W_i"] + p["b_i"]).astype(jnp.float32))
+    r = jax.nn.sigmoid((dense(u, p["W_a"]) + p["b_a"]).astype(jnp.float32))
+    i = jax.nn.sigmoid((dense(u, p["W_i"]) + p["b_i"]).astype(jnp.float32))
     log_a = -DECAY_C * jax.nn.softplus(p["lam"].astype(jnp.float32)) * r
     a = jnp.exp(log_a)
     gated_in = jnp.sqrt(jnp.maximum(1.0 - jnp.square(a), 1e-12)) * (
@@ -79,7 +79,7 @@ def rglru_seq(p: Dict, x: jax.Array, cfg: ModelConfig, state: Dict
     from repro.models.shard_hooks import constrain
 
     bspec = ("pod", "data")
-    u = x @ p["W_x"]
+    u = dense(x, p["W_x"])
     u, new_conv = _conv1d_causal(u, p["conv_w"], p["conv_b"], state["conv"])
     # §Perf: replicate u's width once (ONE bf16 all-gather) so the W_a/W_i
     # gate projections are local column-parallel matmuls — GSPMD otherwise
@@ -101,21 +101,21 @@ def rglru_seq(p: Dict, x: jax.Array, cfg: ModelConfig, state: Dict
     h0 = constrain(state["h"].astype(jnp.float32), bspec, "model")
     new_h, hs = jax.lax.scan(step, h0, jnp.arange(x.shape[1]))
     hs = jnp.moveaxis(hs, 0, 1).astype(x.dtype)  # (B,S,w)
-    gate = jax.nn.gelu(x @ p["W_gate"])
-    out = (gate * hs) @ p["W_o"]
+    gate = jax.nn.gelu(dense(x, p["W_gate"]))
+    out = dense(gate * hs, p["W_o"])
     return out, {"h": new_h.astype(state["h"].dtype), "conv": new_conv}
 
 
 def rglru_decode(p: Dict, x: jax.Array, cfg: ModelConfig, state: Dict
                  ) -> Tuple[jax.Array, Dict]:
     """x (B,1,d) normed input; single recurrent step."""
-    u = x @ p["W_x"]  # (B,1,w)
+    u = dense(x, p["W_x"])  # (B,1,w)
     full = jnp.concatenate([state["conv"], u], axis=1)  # (B,CONV_W,w)
-    u1 = jnp.einsum("bcw,cw->bw", full, p["conv_w"][::-1]) + p["conv_b"]
+    u1 = dense(full, p["conv_w"][::-1], "bcw,cw->bw") + p["conv_b"]
     a, gated_in = _gates(p, u1)
     h = a * state["h"].astype(jnp.float32) + gated_in
-    gate = jax.nn.gelu(x[:, 0, :] @ p["W_gate"])
-    out = (gate * h.astype(x.dtype)) @ p["W_o"]
+    gate = jax.nn.gelu(dense(x[:, 0, :], p["W_gate"]))
+    out = dense(gate * h.astype(x.dtype), p["W_o"])
     return out[:, None, :], {"h": h.astype(state["h"].dtype),
                              "conv": full[:, 1:, :]}
 

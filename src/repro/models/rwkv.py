@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.config.base import ModelConfig
-from repro.models.layers import dense_init, norm_init, apply_norm
+from repro.models.layers import apply_norm, dense, dense_init, norm_init
 
 LORA_DIM = 32
 MIX_NAMES = ("r", "k", "v", "w", "g")
@@ -61,19 +61,19 @@ def _token_shift(x: jax.Array, last: jax.Array) -> jax.Array:
 def _ddlerp(tm: Dict, x, xx, nm: str) -> jax.Array:
     """RWKV-6 data-dependent lerp between x and shifted x."""
     base = x + xx * tm["mu_x"]
-    lora = jnp.tanh(base @ tm[f"A_{nm}"]) @ tm[f"B_{nm}"]
+    lora = dense(jnp.tanh(dense(base, tm[f"A_{nm}"])), tm[f"B_{nm}"])
     return x + xx * (tm[f"mu_{nm}"] + lora)
 
 
 def _rkvwg(tm: Dict, x: jax.Array, shifted: jax.Array, H: int, hd: int):
     xx = shifted - x
-    r = (_ddlerp(tm, x, xx, "r") @ tm["W_r"])
-    k = (_ddlerp(tm, x, xx, "k") @ tm["W_k"])
-    v = (_ddlerp(tm, x, xx, "v") @ tm["W_v"])
-    g = jax.nn.silu(_ddlerp(tm, x, xx, "g") @ tm["W_g"])
+    r = dense(_ddlerp(tm, x, xx, "r"), tm["W_r"])
+    k = dense(_ddlerp(tm, x, xx, "k"), tm["W_k"])
+    v = dense(_ddlerp(tm, x, xx, "v"), tm["W_v"])
+    g = jax.nn.silu(dense(_ddlerp(tm, x, xx, "g"), tm["W_g"]))
     w_in = _ddlerp(tm, x, xx, "w")
-    log_w = tm["w_base"].astype(jnp.float32) + (
-        jnp.tanh(w_in @ tm["A_w"]) @ tm["B_w"]).astype(jnp.float32)
+    lora_w = dense(jnp.tanh(dense(w_in, tm["A_w"])), tm["B_w"])
+    log_w = tm["w_base"].astype(jnp.float32) + lora_w.astype(jnp.float32)
     w = jnp.exp(-jnp.exp(log_w))  # (…, d) in (0,1)
     shp = x.shape[:-1] + (H, hd)
     return (r.reshape(shp), k.reshape(shp), v.reshape(shp),
@@ -117,7 +117,7 @@ def time_mix_seq(tm: Dict, x: jax.Array, cfg: ModelConfig,
                                    jnp.arange(S))
     out = jnp.moveaxis(outs, 0, 1)  # (B,S,H,hd)
     out = apply_norm(tm["ln_x"], out.astype(x.dtype), "rmsnorm")
-    out = (out.reshape(B, S, d) * g) @ tm["W_o"]
+    out = dense(out.reshape(B, S, d) * g, tm["W_o"])
     return out, new_state.astype(state.dtype), x[:, -1, :]
 
 
@@ -136,7 +136,7 @@ def time_mix_decode(tm: Dict, x: jax.Array, cfg: ModelConfig,
                                 u))
     out = apply_norm(tm["ln_x"], out[:, :, None, :].swapaxes(1, 2
                      ).astype(x.dtype), "rmsnorm")  # (B,1,H,hd)
-    out = (out.reshape(B, 1, d) * g[:, None, :]) @ tm["W_o"]
+    out = dense(out.reshape(B, 1, d) * g[:, None, :], tm["W_o"])
     return out, new_state.astype(state.dtype), xt
 
 
@@ -147,8 +147,8 @@ def channel_mix(cm: Dict, x: jax.Array, shift: jax.Array
     xx = shifted - x
     xk = x + xx * cm["mu_k"]
     xr = x + xx * cm["mu_r"]
-    k = jnp.square(jax.nn.relu(xk @ cm["W_k"]))
-    out = jax.nn.sigmoid(xr @ cm["W_r"]) * (k @ cm["W_v"])
+    k = jnp.square(jax.nn.relu(dense(xk, cm["W_k"])))
+    out = jax.nn.sigmoid(dense(xr, cm["W_r"])) * dense(k, cm["W_v"])
     return out, x[:, -1, :]
 
 

@@ -24,7 +24,7 @@ from repro.config.base import InputShape, ModelConfig
 from repro.models import attention as attn
 from repro.models import rglru as rg
 from repro.models import rwkv as rk
-from repro.models.layers import (apply_embed, apply_mlp, apply_norm,
+from repro.models.layers import (apply_embed, apply_mlp, apply_norm, dense,
                                  dense_init, embed_init, mlp_init, norm_init,
                                  unembed)
 from repro.models.moe import moe_apply, moe_init
@@ -264,17 +264,17 @@ def _prefill_kv(p, h, cfg: ModelConfig, ctx, window) -> Dict:
 # ---- cross attention -------------------------------------------------
 def _cross_kv(p, enc_out, cfg: ModelConfig):
     B, T, _ = enc_out.shape
-    k = (enc_out @ p["wk"]).reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
-    v = (enc_out @ p["wv"]).reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
+    k = dense(enc_out, p["wk"]).reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
+    v = dense(enc_out, p["wv"]).reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
     return k, v
 
 
 def _cross_core(p, x, k, v, cfg: ModelConfig):
     B, S, _ = x.shape
-    q = (x @ p["wq"]).reshape(B, S, cfg.n_heads, cfg.head_dim)
+    q = dense(x, p["wq"]).reshape(B, S, cfg.n_heads, cfg.head_dim)
     mask = jnp.ones((B, S, k.shape[1]), bool)
     out = attn._sdpa(q, k, v, mask, 1.0 / float(cfg.head_dim) ** 0.5)
-    return out.reshape(B, S, -1) @ p["wo"]
+    return dense(out.reshape(B, S, -1), p["wo"])
 
 
 def _cross_full(p, x, enc_out, cfg: ModelConfig):
@@ -379,7 +379,7 @@ def _trunk_cached(params: Dict, x: jax.Array, cfg: ModelConfig,
 
 def _encoder_apply(params: Dict, embeds: jax.Array, cfg: ModelConfig,
                    proj: jax.Array) -> jax.Array:
-    x = embeds @ proj
+    x = dense(embeds, proj)
     B, T, _ = x.shape
     positions = default_positions(B, T)
     ctx = {"positions": positions, "attn_impl": "auto", "chunk": 512,
@@ -390,7 +390,7 @@ def _encoder_apply(params: Dict, embeds: jax.Array, cfg: ModelConfig,
         q, k, v = attn._project_qkv(layer_p["attn"], h, cfg, positions)
         mask = jnp.ones((B, T, T), bool)  # bidirectional
         out = attn._sdpa(q, k, v, mask, 1.0 / float(cfg.head_dim) ** 0.5)
-        x = x + out.reshape(B, T, -1) @ layer_p["attn"]["wo"]
+        x = x + dense(out.reshape(B, T, -1), layer_p["attn"]["wo"])
         h = apply_norm(layer_p["ffn_norm"], x, cfg.norm, cfg.norm_eps)
         y, _ = _ffn_apply(layer_p["ffn"], h, cfg)
         return x + y, None
@@ -403,14 +403,15 @@ def _encoder_apply(params: Dict, embeds: jax.Array, cfg: ModelConfig,
 # =====================================================================
 # embedding / positions / loss
 # =====================================================================
-def _embed_inputs(params: Dict, batch: Dict, cfg: ModelConfig
+def _embed_inputs(params: Dict, batch: Dict, cfg: ModelConfig, dtype=None
                   ) -> Tuple[jax.Array, jax.Array, Optional[Tuple]]:
-    """Returns (x (B,S,d), positions (B,S), mrope_positions or None)."""
+    """Returns (x (B,S,d), positions (B,S), mrope_positions or None);
+    ``dtype`` as in :func:`apply_embed`."""
     tokens = batch["tokens"]
     B = tokens.shape[0]
-    x_tok = apply_embed(params["embed"], tokens)
+    x_tok = apply_embed(params["embed"], tokens, dtype)
     if cfg.frontend is not None and not cfg.enc_dec:
-        fe = batch["frontend_embeds"] @ params["frontend_proj"]
+        fe = dense(batch["frontend_embeds"], params["frontend_proj"])
         x = jnp.concatenate([fe.astype(x_tok.dtype), x_tok], axis=1)
         F = fe.shape[1]
         S = x.shape[1]
@@ -639,6 +640,10 @@ class Model:
     #: bf16 compute against f32 master weights — §Perf: halves every
     #: activation collective and activation buffer). None = no cast.
     compute_dtype: Any = None
+    #: the residual stream's dtype: embedding rows are cast to it, so
+    #: weights stored narrower (the serving engine's bf16 weights under
+    #: f32 activations) never narrow the stream. None = the table's own.
+    activation_dtype: Any = None
 
     def _cast(self, params):
         if self.compute_dtype is None:
@@ -654,10 +659,16 @@ class Model:
     def abstract_params(self, dtype=jnp.float32):
         return abstract_params(self.cfg, dtype)
 
+    def embed(self, params, tokens) -> jax.Array:
+        """The residual stream's first rows: embedding rows of
+        ``tokens`` in ``activation_dtype``."""
+        return apply_embed(params["embed"], tokens, self.activation_dtype)
+
     def _trunk(self, params, batch, *, return_cache: bool, remat: bool):
         """Embed ``batch`` and run the whole trunk: (x, aux, cache)."""
         cfg = self.cfg
-        x, positions, mrope = _embed_inputs(params, batch, cfg)
+        x, positions, mrope = _embed_inputs(params, batch, cfg,
+                                            self.activation_dtype)
         ctx = {"positions": positions, "mrope_positions": mrope,
                "attn_impl": self.attn_impl, "chunk": self.chunk,
                "return_cache": return_cache}
@@ -715,7 +726,7 @@ class Model:
             raise NotImplementedError(
                 "prefill_chunk supports plain token prompts only")
         params = self._cast(params)
-        x = apply_embed(params["embed"], batch["tokens"])
+        x = self.embed(params, batch["tokens"])
         ctx = {"pos": batch["pos"], "attn_impl": self.attn_impl,
                "block_tables": batch.get("block_tables")}
         x, new_cache = _trunk_cached(params, x, cfg, cache, ctx,
@@ -745,7 +756,7 @@ class Model:
             raise NotImplementedError(
                 "verify_step supports plain token prompts only")
         params = self._cast(params)
-        x = apply_embed(params["embed"], batch["tokens"])
+        x = self.embed(params, batch["tokens"])
         ctx = {"pos": batch["pos"], "attn_impl": self.attn_impl,
                "block_tables": batch.get("block_tables")}
         x, new_cache = _trunk_cached(params, x, cfg, cache, ctx,
@@ -759,7 +770,7 @@ class Model:
         "block_tables": (B, nb) int32; returns (logits, cache)."""
         cfg = self.cfg
         params = self._cast(params)
-        x = apply_embed(params["embed"], batch["tokens"])
+        x = self.embed(params, batch["tokens"])
         ctx = {"pos": batch["pos"], "attn_impl": self.attn_impl,
                "block_tables": batch.get("block_tables")}
         x, new_cache = _trunk_cached(params, x, cfg, cache, ctx,

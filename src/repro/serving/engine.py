@@ -97,6 +97,15 @@ def sample_tokens(logits, greedy: bool = True, seed: int = 0) -> np.ndarray:
         jax.random.categorical(key, jnp.asarray(logits)).astype(jnp.int32))
 
 
+def default_weight_dtype(dtype=jnp.float32):
+    """The type the continuous engine stores its weights in when the
+    caller names none: bfloat16 on the TPU, where every product rounds
+    its operands to bfloat16 anyway, so float32 storage buys no
+    precision and costs a cast of every weight in every step; elsewhere
+    the activations' ``dtype`` (the CPU's products are exact float32)."""
+    return jnp.bfloat16 if jax.default_backend() == "tpu" else dtype
+
+
 def jit_cache_step(step: Callable, **shardings) -> Callable:
     """Jit a model step ``step(params, cache, batch) -> (out, cache)``
     with the cache DONATED: the step updates it in place
@@ -809,7 +818,7 @@ class ContinuousBatchingEngine:
                  prefix_cache: bool = False,
                  spec_k: int = 0, spec_ngram: int = 2,
                  proposer=None, prefill_mode: str = "auto",
-                 mesh=None):
+                 mesh=None, weight_dtype=None):
         if cfg.enc_dec:
             # cross-attention K/V is unmasked (_cross_core attends every
             # encoder row), so grafting a shorter prefilled ck/cv into the
@@ -825,7 +834,12 @@ class ContinuousBatchingEngine:
         self.n_slots = max(1, max_slots)
         self.cache_len = max_seq
         self.kv_layout = kv_layout
+        #: the KV pool's, staging caches' and activations' type
         self.dtype = dtype
+        #: the parameter tree's type, every leaf (None: by backend)
+        self.weight_dtype = jnp.dtype(
+            default_weight_dtype(dtype) if weight_dtype is None
+            else weight_dtype)
         #: per-iteration cap on prefill-chunk + resident-decode tokens
         #: (docs/ARCHITECTURE.md §5; None = uncapped, prompts prefill in
         #: one pass of bucket-sized chunks). Mutable between steps — the
@@ -936,6 +950,11 @@ class ContinuousBatchingEngine:
                 raise ValueError(
                     "share_from donor spans a different mesh; instances "
                     "share weights/jit only on the same devices")
+            if (share_from.weight_dtype, jnp.dtype(share_from.dtype)) \
+                    != (self.weight_dtype, jnp.dtype(dtype)):
+                raise ValueError(
+                    "share_from donor stores other weight or activation "
+                    "types; instances share weights/jit only in the same")
             self.model = share_from.model
             self.params = share_from.params
             self._prefill = share_from._prefill
@@ -945,17 +964,25 @@ class ContinuousBatchingEngine:
             if self._verify is None and supports_speculation(cfg):
                 self._verify = jit_cache_step(self.model.verify_step)
         else:
-            self.model = build_model(cfg, remat=False)
+            self.model = build_model(cfg, remat=False,
+                                     activation_dtype=dtype)
             key = jax.random.PRNGKey(seed)
+            wdt = self.weight_dtype
+            init = functools.partial(self.model.init, dtype=wdt)
             if mesh is None:
-                self.params = self.model.init(key, dtype)
+                # a narrower tree is drawn under jit, which writes each
+                # weight once in its stored type: drawn eagerly, every
+                # stacked weight passed through two float32 copies beside
+                # the tree, and that set the device's peak. A float32
+                # tree keeps its eager draw (the same values as before).
+                self.params = jax.jit(init)(key) if wdt.itemsize < 4 \
+                    else init(key)
             else:
                 # generated in place, shard by shard (out_shardings): a
                 # model no single device can hold never exists whole
                 self.params = jax.jit(
-                    functools.partial(self.model.init, dtype=dtype),
-                    out_shardings=engine_param_shardings(
-                        mesh, self.model.abstract_params(dtype)))(key)
+                    init, out_shardings=engine_param_shardings(
+                        mesh, self.model.abstract_params(wdt)))(key)
             self._prefill = jax.jit(self.model.prefill)
             if mesh is None:
                 self._prefill_chunk = jit_cache_step(
@@ -2480,6 +2507,9 @@ class ContinuousBatchingEngine:
         alloc = float(self.kv_allocated_tokens)
         return {
             "n_iters": float(self.n_iters),
+            # the served weights' bytes: halved when stored in bfloat16
+            "param_bytes": float(sum(
+                a.nbytes for a in jax.tree.leaves(self.params))),
             "n_compiled_steps": float(self.n_compiled_steps),
             "n_decode_rows": float(self.n_decode_rows),
             "n_prefill_chunk_tokens": float(self.n_prefill_chunk_tokens),

@@ -16,7 +16,11 @@ head_dim 128, block size 16), in float32 and bfloat16:
   pool aliased to the output, no pool-sized temporary, and no copy or
   slice of the pool or of one layer of it;
 * the same two steps of ChatGLM3-6B cut to 10 layers, the benchmark's
-  largest model, which must fit one chip with both pools aliased.
+  largest model, which must fit one chip with both pools aliased;
+* both steps of both models with the weights stored in bfloat16 under
+  the float32 pool, as the engine serves them on the chip: no
+  instruction outside a fusion writes a weight-shaped array through a
+  convert or in a type other than bfloat16.
 
 The topology is described inside a module fixture, never at import: a
 test worker that loads the TPU library holds it until it exits, so only
@@ -141,16 +145,17 @@ def test_qwen3_paged_decode_step_compiles_for_v5e(one_chip):
 CHAT_SLOTS, CHAT_SEQ, CHAT_BS = 16, 768, 16
 
 
-def _chat_step(one_chip, cfg, step):
+def _chat_step(one_chip, cfg, step, weight_dtype=jnp.float32):
     """``step`` of ``cfg`` at the chat cell's shapes (16 slots, 768
-    positions, 769 blocks of 16, float32), jitted as the engine jits it
+    positions, 769 blocks of 16, a float32 pool and activations, weights
+    in ``weight_dtype``), jitted as the engine jits it
     (``jit_cache_step``, the cache donated) and compiled for the chip.
     Returns the executable, the bytes of one stacked pool and the block
     count."""
     from repro.models import build_model
     from repro.serving.engine import jit_cache_step
 
-    model = build_model(cfg, remat=False)
+    model = build_model(cfg, remat=False, activation_dtype=jnp.float32)
     slots, bs = CHAT_SLOTS, CHAT_BS
     per_slot = CHAT_SEQ // bs
     n_blocks = slots * per_slot + 1
@@ -159,7 +164,7 @@ def _chat_step(one_chip, cfg, step):
         return jax.tree.map(
             lambda x: _sds(x.shape, x.dtype, one_chip), tree)
 
-    params = place(model.abstract_params(jnp.float32))
+    params = place(model.abstract_params(weight_dtype))
     cache = place(model.paged_cache_spec(slots, CHAT_SEQ, n_blocks, bs,
                                          jnp.float32))
     rows, width = (slots, 1) if step == "decode_step" else (1, 128)
@@ -219,3 +224,67 @@ def test_chatglm3_paged_step_fits_one_chip(one_chip, step):
     assert 10.0e9 < mem.argument_size_in_bytes < 10.6e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
         <= V5E_HBM_BYTES
+
+
+def _chatglm3_cut():
+    from repro.config import get_config
+
+    return dataclasses.replace(get_config("chatglm3-6b"), n_layers=10)
+
+
+@pytest.mark.parametrize("step", ["decode_step", "prefill_chunk"])
+def test_chatglm3_bf16_paged_step_fits_one_chip(one_chip, step):
+    """The same cut with its weights stored in bfloat16, as the engine
+    serves them on the chip: 5.2 GB of weights beside the float32 pool,
+    both pools still aliased in place."""
+    compiled, pool_bytes, _ = _chat_step(one_chip, _chatglm3_cut(), step,
+                                         jnp.bfloat16)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * pool_bytes
+    assert 5.0e9 < mem.argument_size_in_bytes < 5.6e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        <= V5E_HBM_BYTES
+
+
+def _unfused_lines(hlo):
+    """The executable's lines outside fusion bodies: what it writes to
+    memory (a convert inside a fusion reads its operand as stored)."""
+    bodies = set(re.findall(r"calls=(%[\w.\-]+)", hlo))
+    inside = False
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?(%[\w.\-]+) .*\{$", line)
+        if head:
+            inside = head.group(1) in bodies
+        elif not inside:
+            yield line
+
+
+@pytest.mark.parametrize("step", ["decode_step", "prefill_chunk"])
+@pytest.mark.parametrize("name", ["qwen3-0.6b", "chatglm3-6b"])
+def test_bf16_weights_are_never_widened(one_chip, name, step):
+    """With bfloat16 weights no step converts a weight: no array of a
+    matrix weight's shape, stacked over the layers or one layer of it,
+    nor of the embedding or head, is written by a convert or in another
+    type than the stored bfloat16."""
+    from repro.config import get_config
+    from repro.models import build_model
+
+    cfg = _chatglm3_cut() if name == "chatglm3-6b" else get_config(name)
+    compiled, _, _ = _chat_step(one_chip, cfg, step, jnp.bfloat16)
+    tree = build_model(cfg).abstract_params(jnp.bfloat16)
+    shapes = set()
+    for leaf in jax.tree.leaves(tree):
+        if leaf.ndim == 3:                      # stacked over the layers
+            shapes |= {leaf.shape, leaf.shape[1:]}
+        elif leaf.ndim == 2 and cfg.vocab_size in leaf.shape:
+            shapes.add(leaf.shape)              # embedding, head
+    assert (cfg.n_layers, cfg.d_model, cfg.d_ff) in shapes
+    dims = "|".join(",".join(map(str, s)) for s in sorted(shapes))
+    written = re.compile(rf"= (\w+)\[(?:1,)?(?:{dims})\]")
+    bad = []
+    for line in _unfused_lines(compiled.as_text()):
+        m = written.search(line)
+        if m and "parameter(" not in line \
+                and (m.group(1) != "bf16" or "convert" in line):
+            bad.append(line.strip()[:160])
+    assert not bad
