@@ -121,7 +121,7 @@ async def _episode_async(backpressure: bool, smoke: bool,
                          seed: int) -> dict:
     pool = ModelInstancePool({CFG.name: CFG}, max_instances=1,
                              max_slots=MAX_SLOTS, max_seq=MAX_SEQ,
-                             kv_layout="paged", block_size=8, seed=seed)
+                             block_size=8, seed=seed)
     pool.scale_to(CFG.name, 1)
     pool.warmup(seed=seed)
     trace = _trace(smoke)

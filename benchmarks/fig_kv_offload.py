@@ -92,7 +92,7 @@ def _run_trial(mode: str, ref: np.ndarray, hog_prompt: np.ndarray,
     """One hog/urgent contention run under one preempt mode."""
     pool = ModelInstancePool({TINY.name: TINY}, max_instances=1,
                              max_slots=2, max_seq=CACHE_LEN, seed=0,
-                             kv_layout="paged", block_size=BLOCK_SIZE,
+                             block_size=BLOCK_SIZE,
                              kv_block_budget=KV_BLOCK_BUDGET,
                              kv_host_blocks=KV_HOST_BLOCKS,
                              token_budget=TOKEN_BUDGET,
@@ -202,7 +202,7 @@ def main(fast: bool = FAST) -> dict:
         np.int32)
     # uninterrupted reference completion for the token-identity check
     ref = ContinuousBatchingEngine(
-        TINY, max_slots=2, max_seq=CACHE_LEN, seed=0, kv_layout="paged",
+        TINY, max_slots=2, max_seq=CACHE_LEN, seed=0,
         block_size=BLOCK_SIZE).run(
             [hog_prompt], max_new_tokens=HOG_TOKENS)[0].tokens
 

@@ -66,7 +66,7 @@ def _workload(seed: int = 0):
 def _run(prefix_cache: bool, prompts, share_from=None):
     eng = ContinuousBatchingEngine(
         TINY, max_slots=MAX_SLOTS, max_seq=MAX_SEQ, seed=0,
-        share_from=share_from, kv_layout="paged", block_size=BLOCK_SIZE,
+        share_from=share_from, block_size=BLOCK_SIZE,
         kv_blocks=BUDGET_BLOCKS, prefix_cache=prefix_cache)
     # seed request: with the cache on it publishes the prefix blocks;
     # the baseline pays the same warmup, so the comparison stays fair

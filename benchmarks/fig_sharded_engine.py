@@ -76,7 +76,7 @@ RNG = np.random.default_rng(3)
 def make_pool(tp):
     pool = ModelInstancePool({"tiny-tp": TINY}, max_instances=2,
                              max_slots=12, max_seq=128,
-                             kv_layout="paged", block_size=8,
+                             block_size=8,
                              kv_block_budget=P["kv_budget"],
                              tp_degree=tp, n_devices=2, seed=0)
     assert pool.scale_to("tiny-tp", 1) == 1

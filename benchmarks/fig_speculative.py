@@ -87,8 +87,7 @@ def _workload(base: ContinuousBatchingEngine, seed: int = 1):
 def _run(spec_k: int, prompts, share_from):
     eng = ContinuousBatchingEngine(
         TINY, max_slots=MAX_SLOTS, max_seq=MAX_SEQ, seed=0,
-        share_from=share_from, kv_layout="paged", block_size=BLOCK_SIZE,
-        spec_k=spec_k)
+        share_from=share_from, block_size=BLOCK_SIZE, spec_k=spec_k)
     # warm the verify/decode compile for the measured shapes
     eng.run(prompts[:2], max_new_tokens=4)
     for p in prompts:
@@ -155,7 +154,7 @@ def main(fast: bool = FAST) -> dict:
         N_CANDIDATES, SCREEN_TOKENS = 8, 24
     base_eng = ContinuousBatchingEngine(
         TINY, max_slots=MAX_SLOTS, max_seq=MAX_SEQ, seed=0,
-        kv_layout="paged", block_size=BLOCK_SIZE)
+        block_size=BLOCK_SIZE)
     try:
         prompts = _workload(base_eng)
     except AssertionError:

@@ -28,11 +28,9 @@ MODULES = [
     "fig16_overhead",
     "fig_continuous_vs_round",
     "fig_multimodel_concurrency",
-    "fig_paged_kv",
     "fig_preemption_chunked",
     "fig_prefix_cache",
     "fig_speculative",
-    "fig_fused_kernels",
     "fig_sharded_engine",
     "fig_async_serving",
     "fig_kv_offload",

@@ -106,7 +106,7 @@ def start_server(cfg, seed: int = 0):
         try:
             serve_http(models=[cfg.name], port=0, slo_ms=600_000.0,
                        max_instances=1, max_slots=len(PROMPT_LENS),
-                       seed=seed, kv_layout="paged", ready=ready,
+                       seed=seed, ready=ready,
                        configs={cfg.name: cfg})
         except BaseException as e:  # noqa: BLE001 - re-raised by caller
             errors.append(e)
@@ -335,7 +335,7 @@ def pool_placement(cfg, n_devices: int = 4) -> None:
     from repro.serving.runtime import ModelInstancePool
 
     pool = ModelInstancePool({cfg.name: cfg}, max_instances=n_devices,
-                             max_slots=2, max_seq=128, kv_layout="paged",
+                             max_slots=2, max_seq=128,
                              tp_degree=2, n_devices=n_devices)
     for tp, m_c in ((2, n_devices // 2), (1, n_devices)):
         pool.set_tp_degree(cfg.name, tp)

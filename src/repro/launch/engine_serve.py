@@ -152,19 +152,19 @@ def _shared_prefix_prompts(rng, vocab: int, shared_prefix_tokens: int,
 
 def serve_continuous(arch: str = "qwen3-0.6b", duration_s: float = 20.0,
                      rps: float = 12.0, slo_ms: float = 1500.0,
-                     max_slots: int = 4, kv_layout: str = "dense",
+                     max_slots: int = 4,
                      kv_block_budget: Optional[int] = None,
                      token_budget: Optional[int] = None,
                      prefix_cache: bool = False,
                      shared_prefix_tokens: int = 0,
                      spec_k: int = 0, reduced: bool = False) -> None:
     """Continuous mode: arrivals are submitted into the slot engine as
-    they land and join the running batch at iteration boundaries. With
-    ``kv_layout="paged"``, ``kv_block_budget`` caps the engine's block
-    pool (default: the dense-equivalent worst case). ``token_budget``
+    they land and join the running batch at iteration boundaries.
+    ``kv_block_budget`` caps the engine's block pool (default: one
+    full-length row of blocks per slot). ``token_budget``
     caps per-iteration prefill+decode tokens (chunked prefill,
     docs/ARCHITECTURE.md §5). ``prefix_cache`` shares full immutable
-    prompt blocks across same-prefix sequences (paged only);
+    prompt blocks across same-prefix sequences;
     ``shared_prefix_tokens`` makes the generated workload templated so
     the cache has something to hit. ``spec_k`` enables self-speculative
     decoding: up to k n-gram drafts per slot verified in one forward
@@ -175,14 +175,13 @@ def serve_continuous(arch: str = "qwen3-0.6b", duration_s: float = 20.0,
         print(f"{cfg.name}: cache not rewindable "
               f"(recurrent/windowed layers); serving with spec_k=0")
         spec_k = 0
-    print(f"[continuous] {max_slots} slots, {kv_layout} KV, "
+    print(f"[continuous] {max_slots} slots, "
           f"token budget {token_budget or 'uncapped'}, "
           f"prefix cache {'on' if prefix_cache else 'off'}, "
           f"spec_k {spec_k or 'off'}...")
     engine = ContinuousBatchingEngine(cfg, max_slots=max_slots,
                                       max_seq=_serve_max_seq(
                                           shared_prefix_tokens),
-                                      kv_layout=kv_layout,
                                       kv_blocks=kv_block_budget,
                                       token_budget=token_budget,
                                       prefix_cache=prefix_cache,
@@ -225,7 +224,6 @@ def serve_pool(models: Sequence[str] = ("qwen3-0.6b", "recurrentgemma-2b"),
                slo_ms: float = 2000.0, max_instances: int = 4,
                max_slots: int = 4, max_new_tokens: int = 4,
                control_ms: float = 500.0, seed: int = 0,
-               kv_layout: str = "dense",
                kv_block_budget: Optional[int] = None,
                token_budget: Optional[int] = None,
                preemption: bool = False,
@@ -235,24 +233,23 @@ def serve_pool(models: Sequence[str] = ("qwen3-0.6b", "recurrentgemma-2b"),
                shared_prefix_tokens: int = 0,
                spec_k: int = 0, reduced: bool = False
                ) -> Dict[str, Dict[str, float]]:
-    """Multi-model pool serve (docs/RUNTIME.md): Poisson arrivals per
-    model are routed by deadline into a ``ModelInstancePool`` of live
-    engine instances while the ``PoolScheduler`` re-decides (b, m_c) per
-    model once per Eq.-1 slot (clamped to [control_ms, 2000] ms).
-    ``kv_layout="paged"`` serves every instance from the block-pool KV
-    layout under a shared ``kv_block_budget`` (docs/RUNTIME.md §7).
-    ``token_budget`` adds the per-iteration token cap as a third
-    scheduler axis and ``preemption`` enables SLO-aware eviction
-    (docs/RUNTIME.md §8). ``kv_host_blocks`` gives every paged instance
-    a host-memory KV tier so eviction can swap instead of recompute;
-    ``preempt_mode`` picks recompute/swap/auto (costed, per victim). ``prefix_cache`` shares full immutable prompt
-    blocks across same-prefix sequences on pageable models, with router
-    prefix affinity (docs/RUNTIME.md §7); pair it with
+    """Multi-model pool serve (docs/RUNTIME.md): Poisson arrivals per model
+    are routed by deadline into a ``ModelInstancePool`` of live engine
+    instances while the ``PoolScheduler`` re-decides (b, m_c) per model once
+    per Eq.-1 slot (clamped to [control_ms, 2000] ms). ``kv_block_budget``
+    caps the blocks every instance's pool shares (docs/RUNTIME.md §7).
+    ``token_budget`` adds the per-iteration token cap as a third scheduler
+    axis and ``preemption`` enables SLO-aware eviction (docs/RUNTIME.md §8).
+    ``kv_host_blocks`` gives every instance a host-memory KV tier so
+    eviction can swap instead of recompute; ``preempt_mode`` picks
+    recompute/swap/auto (costed, per victim). ``prefix_cache`` shares full
+    immutable prompt blocks across same-prefix sequences on pageable models,
+    with router prefix affinity (docs/RUNTIME.md §7); pair it with
     ``shared_prefix_tokens`` so the generated workload is templated.
-    ``spec_k`` caps self-speculative decoding and adds the proposal
-    depth as the FOURTH scheduler axis (k ∈ {0, k/2, k}; rewind-capable
-    models only, docs/ARCHITECTURE.md §speculation).
-    Returns the pool's per-model report."""
+    ``spec_k`` caps self-speculative decoding and adds the proposal depth as
+    the FOURTH scheduler axis (k ∈ {0, k/2, k}; rewind-capable models only,
+    docs/ARCHITECTURE.md §speculation). Returns the pool's per-model
+    report."""
     cfgs = {m: load_config(m, reduced) for m in models}
     pool = ModelInstancePool(cfgs, max_instances=max_instances,
                              max_slots=max_slots,
@@ -260,7 +257,6 @@ def serve_pool(models: Sequence[str] = ("qwen3-0.6b", "recurrentgemma-2b"),
                              seed=seed,
                              strict_admission=True,
                              predictor=NNInterferencePredictor(seed=seed),
-                             kv_layout=kv_layout,
                              kv_block_budget=kv_block_budget,
                              preemption=preemption,
                              kv_host_blocks=max(0, kv_host_blocks),
@@ -337,7 +333,6 @@ def serve_http(models: Sequence[str] = ("qwen3-0.6b",),
                host: str = "127.0.0.1", port: int = 8808,
                slo_ms: float = 2000.0, max_instances: int = 4,
                max_slots: int = 4, seed: int = 0,
-               kv_layout: str = "paged",
                kv_block_budget: Optional[int] = None,
                backpressure: bool = True, max_queue_depth: int = 8,
                control_ms: float = 500.0,
@@ -363,7 +358,6 @@ def serve_http(models: Sequence[str] = ("qwen3-0.6b",),
     cfgs = configs or {m: load_config(m, reduced) for m in models}
     pool = ModelInstancePool(cfgs, max_instances=max_instances,
                              max_slots=max_slots, max_seq=128, seed=seed,
-                             kv_layout=kv_layout,
                              kv_block_budget=kv_block_budget)
     per_model_mc = max(1, max_instances // max(1, len(cfgs)))
     scfg = ServingConfig(
@@ -408,7 +402,7 @@ def serve_http(models: Sequence[str] = ("qwen3-0.6b",),
 def main(exec_mode: str = "round", arch: str = "qwen3-0.6b",
          duration_s: float = 20.0, rps: float = 12.0,
          slo_ms: float = 1500.0, models: Optional[Sequence[str]] = None,
-         max_instances: int = 4, kv_layout: str = "dense",
+         max_instances: int = 4,
          kv_block_budget: Optional[int] = None,
          token_budget: Optional[int] = None,
          preemption: bool = False, kv_host_blocks: int = 0,
@@ -421,7 +415,6 @@ def main(exec_mode: str = "round", arch: str = "qwen3-0.6b",
         serve_http(models or [arch], port=serve_http_port, slo_ms=slo_ms,
                    reduced=reduced,
                    max_instances=max_instances,
-                   kv_layout=kv_layout if kv_layout else "paged",
                    kv_block_budget=kv_block_budget,
                    backpressure=backpressure,
                    max_queue_depth=max_queue_depth)
@@ -430,7 +423,7 @@ def main(exec_mode: str = "round", arch: str = "qwen3-0.6b",
             print("multi-model pool serving is continuous-only; "
                   "running with --exec-mode continuous")
         serve_pool(models, duration_s, rps, slo_ms,
-                   max_instances=max_instances, kv_layout=kv_layout,
+                   max_instances=max_instances,
                    kv_block_budget=kv_block_budget,
                    token_budget=token_budget, preemption=preemption,
                    kv_host_blocks=kv_host_blocks,
@@ -440,16 +433,12 @@ def main(exec_mode: str = "round", arch: str = "qwen3-0.6b",
                    spec_k=spec_k, reduced=reduced)
     elif exec_mode == "continuous":
         serve_continuous(arch, duration_s, rps, slo_ms,
-                         kv_layout=kv_layout,
                          kv_block_budget=kv_block_budget,
                          token_budget=token_budget,
                          prefix_cache=prefix_cache,
                          shared_prefix_tokens=int(shared_prefix_tokens),
                          spec_k=spec_k, reduced=reduced)
     else:
-        if kv_layout != "dense":
-            print("round mode always uses the dense per-round cache; "
-                  "--kv-layout applies to continuous/pool serving")
         if token_budget or preemption or prefix_cache or spec_k \
                 or kv_host_blocks:
             print("chunked prefill / preemption / prefix caching / "

@@ -53,15 +53,10 @@ def main() -> None:
     ap.add_argument("--max-instances", type=int, default=4,
                     help="pool-wide live engine instance budget shared "
                          "by all --models")
-    ap.add_argument("--kv-layout", default="dense",
-                    choices=["dense", "paged"],
-                    help="continuous-engine KV cache layout: dense "
-                         "per-slot slabs or the vLLM-style block pool "
-                         "(docs/ARCHITECTURE.md §5)")
     ap.add_argument("--kv-block-budget", type=int, default=None,
                     help="total KV blocks shared by all pool instances "
-                         "(paged only; default: unlimited, each "
-                         "instance gets its dense-equivalent grant)")
+                         "(default: unlimited, each instance gets one "
+                         "full-length row of blocks per slot)")
     ap.add_argument("--token-budget", type=int, default=None,
                     help="per-iteration cap on prefill-chunk + decode "
                          "tokens (chunked prefill, docs/ARCHITECTURE.md "
@@ -73,7 +68,7 @@ def main() -> None:
                          "resident when an urgent request cannot be "
                          "admitted (docs/RUNTIME.md §8)")
     ap.add_argument("--kv-host-blocks", type=int, default=0,
-                    help="host-memory KV block tier per paged engine "
+                    help="host-memory KV block tier per engine "
                          "instance: preempted sequences can swap their "
                          "blocks to host instead of recomputing, and "
                          "the prefix cache spills cold blocks there "
@@ -92,12 +87,11 @@ def main() -> None:
                          "(geometric; 0 = single-shot, no prefill "
                          "modeling)")
     ap.add_argument("--prefix-cache", action="store_true",
-                    help="vLLM-style prefix caching: paged engines "
-                         "share full immutable prompt blocks at "
-                         "refcount+1 (copy-on-write tails, LRU reuse of "
-                         "evicted blocks; docs/ARCHITECTURE.md §5); the "
-                         "simulator skips already-paid shared prefixes. "
-                         "Engine/pool modes require --kv-layout paged")
+                    help="vLLM-style prefix caching: engines share "
+                         "full immutable prompt blocks at refcount+1 "
+                         "(copy-on-write tails, LRU reuse of evicted "
+                         "blocks; docs/ARCHITECTURE.md §5); the "
+                         "simulator skips already-paid shared prefixes")
     ap.add_argument("--shared-prefix-tokens", type=float, default=0.0,
                     help="templated workload: every prompt starts with "
                          "one of a small population of shared prefixes "
@@ -129,15 +123,9 @@ def main() -> None:
     if args.models and not args.engine:
         ap.error("--models requires --engine (the simulator is already "
                  "multi-tenant over the paper's Table-IV models)")
-    if args.prefix_cache and args.engine and args.kv_layout != "paged":
-        ap.error("--prefix-cache on the engine needs --kv-layout paged "
-                 "(sharing is block-granular)")
     if args.serve_http and not args.engine:
         ap.error("--serve-http requires --engine (the HTTP front-end "
                  "streams real engine tokens)")
-    if args.kv_host_blocks and args.kv_layout != "paged":
-        ap.error("--kv-host-blocks needs --kv-layout paged (the host "
-                 "tier holds KV blocks)")
     if args.preempt_mode == "swap" and args.kv_host_blocks <= 0:
         ap.error("--preempt-mode swap needs --kv-host-blocks > 0 "
                  "(there is nowhere to swap to)")
@@ -149,7 +137,6 @@ def main() -> None:
         models = [m for m in (args.models or "").split(",") if m] or None
         engine_serve.main(exec_mode=args.exec_mode, arch=args.arch,
                           models=models, max_instances=args.max_instances,
-                          kv_layout=args.kv_layout,
                           kv_block_budget=args.kv_block_budget,
                           token_budget=args.token_budget,
                           preemption=args.preemption,

@@ -215,9 +215,9 @@ def engine_param_shardings(mesh, params_abstract) -> Any:
 
 
 def engine_cache_pspec(path: str, leaf, mesh) -> P:
-    """PartitionSpec for one serving-engine cache leaf — dense slot
-    slabs and paged block pools alike. Linear KV leaves, dense
-    ``(B, S, n_kv, hd)`` and paged ``(n_blocks, bs, n_kv, hd)``, shard
+    """PartitionSpec for one serving-engine cache leaf — block pools
+    and per-slot staging caches alike. Linear KV leaves, staging
+    ``(B, S, n_kv, hd)`` and pooled ``(n_blocks, bs, n_kv, hd)``, shard
     the HEAD axis over ``model``, matching the column-sharded
     wq/wk/wv: scores and the weighted-value contraction stay local per
     head shard and the row-sharded wo psums once per step. (The

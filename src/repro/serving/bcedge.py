@@ -189,9 +189,9 @@ def run_episode(env: EdgeServingEnv, agent,
 #: state vector fed to the per-model pool agents (docs/RUNTIME.md):
 #: [log1p(queue), oldest slack s, own m_c share, total live share,
 #:  log1p(predicted iter ms), log1p(Eq.-1 slot ms),
-#:  KV budget headroom frac (1.0 for dense/unlimited pools),
+#:  KV budget headroom frac (1.0 for unlimited budgets),
 #:  log1p(prefill backlog tokens), log1p(preemptions since last decision),
-#:  prefix-cache hit rate (0.0 for dense / cache-off pools),
+#:  prefix-cache hit rate (0.0 for cache-off pools),
 #:  speculative acceptance rate (0.0 for spec-off pools),
 #:  shared-device-set utilization (0.0 for unbudgeted pools),
 #:  host-tier occupancy frac (swapped + spilled blocks over the host
@@ -312,8 +312,8 @@ class PoolScheduler:
         block budget: the proposed allocation's predicted resident
         tokens — b slots × m_c instances at the MEASURED tokens/sequence
         (``latency_model.fit_occupancy``) — plus what the other tenants
-        measurably occupy must fit the budget. Dense pools / unlimited
-        budgets / uncalibrated occupancy default to feasible (the
+        measurably occupy must fit the budget. Unlimited budgets /
+        uncalibrated occupancy default to feasible (the
         analytic curve never blocked the real runtime either).
 
         This is the *demand* side of Eq. 4; the *allocation* side

@@ -61,7 +61,7 @@ def supports_prefix_cache(cfg: ModelConfig) -> bool:
     """Prefix caching shares physical KV *blocks*, so it needs every
     layer's decode state to live in the block pool: linear-attention KV
     only (``paged_layer_kind``). Recurrent states and windowed ring
-    buffers are per-slot dense — a shared-prefix hit would also need the
+    buffers are per-slot leaves — a shared-prefix hit would also need the
     recurrent state at the block boundary, which the cache does not
     hold — and frontend/enc-dec models bypass the chunked path
     entirely."""
@@ -72,10 +72,10 @@ def supports_prefix_cache(cfg: ModelConfig) -> bool:
 def supports_speculation(cfg: ModelConfig) -> bool:
     """Speculative decoding needs every layer's decode state to be
     REWINDABLE: rejected linear-attention KV rows are masked by ``pos``
-    (dense) or freed back to the allocator at block granularity (paged),
-    but a recurrent state advances irreversibly per token and a windowed
-    ring buffer aliases rejected writes over live positions — neither
-    can be rolled back. That is the same layer predicate prefix caching
+    and their trailing blocks freed back to the allocator, but a
+    recurrent state advances irreversibly per token and a windowed ring
+    buffer aliases rejected writes over live positions — neither can be
+    rolled back. That is the same layer predicate prefix caching
     needs (all decode state in plain linear KV), so the gates coincide;
     frontend/enc-dec models additionally bypass the chunked forward the
     verify pass is built on."""
@@ -618,8 +618,8 @@ class _Slot:
     tokens: List[int] = dataclasses.field(default_factory=list)
     submit_s: float = 0.0
     admit_s: float = 0.0
-    # paged layout only: physical blocks owned, and how many of the
-    # admission reservation remain unallocated (alloc-on-decode-boundary)
+    # physical blocks owned, and how many of the admission reservation
+    # remain unallocated (alloc-on-decode-boundary)
     blocks: List[int] = dataclasses.field(default_factory=list)
     n_outstanding: int = 0
     #: leading blocks of ``blocks`` mapped from the prefix cache at
@@ -788,37 +788,36 @@ class ContinuousBatchingEngine:
     SLICE/Orca-style execution mode the simulator's
     ``exec_mode="continuous"`` models analytically).
 
-    A fixed number of KV-cache slots is allocated once at
-    ``(n_slots, cache_len)``; every ``step()`` runs ONE jit-compiled
-    decode iteration over all slots (a single compiled shape for the
-    engine's lifetime). At iteration boundaries finished sequences are
-    evicted — their slot is freed immediately — and queued prompts are
-    prefilled (one compile per prompt-length bucket) and grafted into
-    free slots. Admission cost is one host-side cache scatter per
-    request, which is fine at the reduced-config scale this repo serves;
-    a production engine would fuse the graft into the prefill kernel.
+    A fixed number of slots, each at most ``cache_len`` positions long;
+    every ``step()`` runs ONE jit-compiled decode iteration over all
+    slots (a single compiled shape for the engine's lifetime). At
+    iteration boundaries finished sequences are evicted — their slot is
+    freed immediately — and queued prompts are prefilled in
+    budget-bounded chunks into free slots.
 
-    ``kv_layout="paged"`` replaces the dense per-slot cache for linear
-    attention layers with a block pool + ``BlockAllocator``: a slot only
-    occupies the blocks its sequence actually needs (prompt bucket +
-    requested decode tokens) instead of a full ``cache_len`` row, so the
-    same token budget holds materially more concurrent sequences.
-    Admission is gated on free blocks, blocks are physically allocated
-    when decode crosses a block boundary, and eviction returns them to
-    the free list. Greedy outputs are token-identical to the dense
-    layout (asserted in tests/test_paged_kv.py).
+    Linear-attention KV lives in one block pool shared by every slot,
+    managed by a ``BlockAllocator``: a slot occupies only the blocks its
+    sequence needs (prompt bucket + requested decode tokens), addressed
+    through its row of ``block_tables``. Admission is gated on free
+    slots and unreserved blocks, blocks are physically allocated when
+    decode crosses a block boundary, and eviction returns them to the
+    free list. Windowed ring buffers and recurrent states stay per-slot
+    leaves inside the same cache pytree (``paged_layer_kind``). Stacks
+    whose every layer is paged prefill straight into the pool (fused
+    prefill); hybrid stacks chunk into a per-slot staging cache that is
+    grafted on completion. Greedy outputs are token-identical to the
+    round ``InferenceEngine`` (asserted in tests/test_paged_kv.py).
     """
 
     def __init__(self, cfg: ModelConfig, max_slots: int = 4,
                  max_seq: int = 256, dtype=jnp.float32, seed: int = 0,
                  share_from: "ContinuousBatchingEngine" = None,
-                 kv_layout: str = "dense", block_size: int = 16,
+                 block_size: int = 16,
                  kv_blocks: int = None, kv_host_blocks: int = 0,
                  token_budget: Optional[int] = None,
                  prefix_cache: bool = False,
                  spec_k: int = 0, spec_ngram: int = 2,
-                 proposer=None, prefill_mode: str = "auto",
-                 mesh=None, weight_dtype=None):
+                 proposer=None, mesh=None, weight_dtype=None):
         if cfg.enc_dec:
             # cross-attention K/V is unmasked (_cross_core attends every
             # encoder row), so grafting a shorter prefilled ck/cv into the
@@ -826,14 +825,11 @@ class ContinuousBatchingEngine:
             raise NotImplementedError(
                 "continuous batching does not support encoder-decoder "
                 "architectures yet; use InferenceEngine")
-        if kv_layout not in ("dense", "paged"):
-            raise ValueError(f"unknown kv_layout {kv_layout!r}")
         if token_budget is not None and token_budget < 1:
             raise ValueError(f"token_budget must be >= 1, got {token_budget}")
         self.cfg = cfg
         self.n_slots = max(1, max_slots)
         self.cache_len = max_seq
-        self.kv_layout = kv_layout
         #: the KV pool's, staging caches' and activations' type
         self.dtype = dtype
         #: the parameter tree's type, every leaf (None: by backend)
@@ -849,42 +845,23 @@ class ContinuousBatchingEngine:
         #: keep the single-shot prefill admission path (and therefore
         #: do not support preemption-resume)
         self.chunked = cfg.frontend is None and not cfg.enc_dec
-        if prefix_cache:
-            if kv_layout != "paged":
-                raise ValueError(
-                    "prefix_cache needs kv_layout='paged' (sharing is "
-                    "block-granular)")
-            if not supports_prefix_cache(cfg):
-                raise ValueError(
-                    f"{cfg.name}: prefix_cache needs every layer's decode "
-                    "state in the block pool (linear attention only); "
-                    "recurrent/windowed/frontend layers keep per-slot "
-                    "dense state the cache cannot share")
-        self.prefix_cache = prefix_cache
-        #: chunked-prefill execution mode. "fused" runs every prefill
-        #: chunk DIRECTLY against the paged pool through the slot's
-        #: block table (repro.models.attention.attention_chunk_paged):
-        #: no per-slot staging cache, no prefix gather, no completion
-        #: graft scatter. "auto" picks fused whenever the layout
-        #: supports it: paged + chunked + every layer's decode state in
-        #: the block pool (the same gate as the prefix cache — a dense
-        #: per-slot leaf cannot take a batch-1 chunk against the shared
-        #: pool pytree). Layouts that fail the gate (dense, hybrid
-        #: stacks) keep the staging-cache round trip: chunk into a
-        #: per-slot staging cache, scatter-graft on completion. The
-        #: legacy "staging" override for paged all-linear stacks is
-        #: gone — fused is the only paged prefill path.
-        if prefill_mode not in ("auto", "fused"):
-            raise ValueError(f"unknown prefill_mode {prefill_mode!r}")
-        fused_ok = (kv_layout == "paged" and self.chunked
-                    and supports_prefix_cache(cfg))
-        if prefill_mode == "fused" and not fused_ok:
+        if prefix_cache and not supports_prefix_cache(cfg):
             raise ValueError(
-                "prefill_mode='fused' needs kv_layout='paged', the "
-                "chunked-prefill path, and every layer's decode state "
-                "in the block pool")
-        self.fused_prefill = fused_ok if prefill_mode == "auto" \
-            else prefill_mode == "fused"
+                f"{cfg.name}: prefix_cache needs every layer's decode "
+                "state in the block pool (linear attention only); "
+                "recurrent/windowed/frontend layers keep per-slot "
+                "state the cache cannot share")
+        self.prefix_cache = prefix_cache
+        #: fused prefill runs every chunk DIRECTLY against the block
+        #: pool through the slot's block table
+        #: (repro.models.attention.attention_chunk_paged): no per-slot
+        #: staging cache, no prefix gather, no completion graft. It needs
+        #: every layer's decode state in the block pool (the prefix
+        #: cache's gate — a per-slot leaf cannot take a batch-1 chunk
+        #: against the shared pool pytree); hybrid stacks keep the
+        #: staging-cache round trip: chunk into a per-slot staging
+        #: cache, graft on completion.
+        self.fused_prefill = self.chunked and supports_prefix_cache(cfg)
         if spec_k < 0:
             raise ValueError(f"spec_k must be >= 0, got {spec_k}")
         if spec_k > 0 and not supports_speculation(cfg):
@@ -894,10 +871,9 @@ class ContinuousBatchingEngine:
                 "recurrent states advance irreversibly and windowed ring "
                 "buffers alias rejected writes over live positions")
         #: max speculation depth this engine compiled for (fixed: the
-        #: dense scratch margin and the verify block-table padding depend
-        #: on it); ``spec_k`` below is the CURRENT depth, mutable between
-        #: steps — the PoolScheduler's fourth action axis — and clamped
-        #: to ``spec_max`` at use
+        #: verify block-table padding depends on it); ``spec_k`` below is
+        #: the CURRENT depth, mutable between steps — the PoolScheduler's
+        #: fourth action axis — and clamped to ``spec_max`` at use
         self.spec_max = spec_k
         self.spec_k = spec_k
         self.proposer = proposer if proposer is not None \
@@ -917,7 +893,7 @@ class ContinuousBatchingEngine:
         #: tensor parallelism (docs/ARCHITECTURE.md §11): a 1D
         #: ``("model",)`` mesh (launch/mesh.make_tp_mesh) this instance
         #: spans. Params are placed under the launch TP rules, the KV
-        #: cache — dense slabs and the paged block pool alike — is
+        #: cache — the block pool and the per-slot leaves alike — is
         #: HEAD-sharded, and the step functions are jitted with
         #: NamedSharding in/out specs. Block tables, the allocator and
         #: every slot/queue structure stay host-side (replicated): the
@@ -999,48 +975,30 @@ class ContinuousBatchingEngine:
         if kv_host_blocks < 0:
             raise ValueError(
                 f"kv_host_blocks must be >= 0, got {kv_host_blocks}")
-        if kv_host_blocks > 0:
-            if kv_layout != "paged":
-                raise ValueError(
-                    "kv_host_blocks needs kv_layout='paged' (the host "
-                    "tier swaps block-granular KV)")
-            if mesh is not None and mesh.size > 1:
-                raise ValueError(
-                    "the host KV tier is single-device for now: swap-in "
-                    "writes outside jit would drop the pool's sharding")
+        if kv_host_blocks > 0 and mesh is not None and mesh.size > 1:
+            raise ValueError(
+                "the host KV tier is single-device for now: swap-in "
+                "writes outside jit would drop the pool's sharding")
         self.kv_host_blocks = kv_host_blocks
         #: host-tier stack gate: swapping a sequence (or spilling a
         #: prefix block) moves ONLY block-pool state, so every layer's
         #: decode state must live there — the same all-linear predicate
         #: prefix caching needs. Hybrid stacks keep recompute-on-resume.
         self.swap_ok = kv_host_blocks > 0 and supports_prefix_cache(cfg)
-        if kv_layout == "paged":
-            self.block_size = block_size
-            self.blocks_per_slot = -(-self.cache_len // block_size)
-            if kv_blocks is None:
-                # dense-equivalent worst case: admission can never refuse
-                # a request the dense layout would have taken
-                kv_blocks = self.n_slots * self.blocks_per_slot
-            self.allocator = BlockAllocator(kv_blocks, block_size,
-                                            host_blocks=kv_host_blocks)
-            # pool array includes the null block 0 (id range 0..kv_blocks)
-            init_cache = functools.partial(
-                self.model.init_paged_cache, self.n_slots, self.cache_len,
-                kv_blocks + 1, block_size, dtype)
-            self.block_tables = np.zeros(
-                (self.n_slots, self.blocks_per_slot), np.int32)
-        else:
-            self.block_size = 0
-            self.allocator = None
-            self.block_tables = None
-            # speculative verify writes up to spec_max rows past a slot's
-            # frontier before acceptance is known; dynamic_update_slice
-            # CLAMPS out-of-bounds starts (it would silently overwrite
-            # valid earlier rows), so the physical slab carries a scratch
-            # margin. cache_len stays the LOGICAL capacity everywhere.
-            init_cache = functools.partial(
-                self.model.init_cache, self.n_slots,
-                self.cache_len + self.spec_max, dtype)
+        self.block_size = block_size
+        self.blocks_per_slot = -(-self.cache_len // block_size)
+        if kv_blocks is None:
+            # one full-length row per slot: block availability never
+            # refuses a request a free slot could hold
+            kv_blocks = self.n_slots * self.blocks_per_slot
+        self.allocator = BlockAllocator(kv_blocks, block_size,
+                                        host_blocks=kv_host_blocks)
+        # pool array includes the null block 0 (id range 0..kv_blocks)
+        init_cache = functools.partial(
+            self.model.init_paged_cache, self.n_slots, self.cache_len,
+            kv_blocks + 1, block_size, dtype)
+        self.block_tables = np.zeros(
+            (self.n_slots, self.blocks_per_slot), np.int32)
         if mesh is None:
             self.cache = init_cache()
         else:
@@ -1050,8 +1008,8 @@ class ContinuousBatchingEngine:
             # specs. The batch dict — tokens, pos, block tables — is
             # replicated: every shard sees the same schedule. The same
             # cache shardings tree serves the per-slot staging caches
-            # non-fused layouts chunk into (same pytree structure, and
-            # specs never shard the batch/length dims that differ).
+            # hybrid stacks chunk into (same pytree structure, and specs
+            # never shard the batch/length dims that differ).
             cshard = engine_cache_shardings(mesh,
                                             jax.eval_shape(init_cache))
             self.cache = jax.jit(init_cache, out_shardings=cshard)()
@@ -1186,13 +1144,13 @@ class ContinuousBatchingEngine:
     def _seq_tokens(self, prompt_len: int, max_new: int) -> int:
         """Cache positions a sequence occupies: frontend + bucketed
         prompt + decode tokens (left-pad rows included — they are
-        attended, so the paged layout must hold them too)."""
+        attended, so the block pool must hold them too)."""
         return self._frontend_tokens() \
             + _bucket(prompt_len, buckets=SEQ_BUCKETS) + max_new
 
     def request_blocks(self, prompt_len: int, max_new: int) -> int:
         """Worst-case blocks a request of this shape reserves at
-        admission (paged layout)."""
+        admission."""
         room = self.cache_len - self._seq_tokens(prompt_len, 0)
         return self.allocator.blocks_for(
             self._seq_tokens(prompt_len, min(max_new, room)))
@@ -1212,9 +1170,9 @@ class ContinuousBatchingEngine:
                    pending_blocks: int = 0,
                    resume: Optional[PreemptedRequest] = None,
                    prompt: Optional[np.ndarray] = None) -> bool:
-        """Could a request of this shape be admitted right now? Dense:
-        a free slot. Paged: a free slot AND enough unreserved blocks
-        (the real memory constraint, docs/ARCHITECTURE.md §5).
+        """Could a request of this shape be admitted right now? A free
+        slot AND enough unreserved blocks (the real memory constraint,
+        docs/ARCHITECTURE.md §5).
         ``pending_blocks`` debits blocks a caller has already promised
         to earlier requests it routed this pass but that the engine has
         not reserved yet (reservation happens inside ``admit``). With
@@ -1226,8 +1184,6 @@ class ContinuousBatchingEngine:
         buys."""
         if not self.free_slots:
             return False
-        if self.kv_layout != "paged":
-            return True
         if resume is not None:
             need = self.resume_blocks(resume)
             # swap resumes never map shared prefix blocks (their KV
@@ -1247,13 +1203,12 @@ class ContinuousBatchingEngine:
         """Queue a prompt; it joins a slot at the next iteration boundary.
 
         Raises only when the prompt can never fit a sequence's
-        ``cache_len`` budget. Transient pressure (no free slot, or — in
-        the paged layout — no free blocks) just keeps it queued; the
-        paged admission gate is the allocator's free-block count, not
-        dense ``cache_len`` headroom. A ``max_new_tokens`` that exceeds
-        the remaining cache room is clamped, and the clamp is RECORDED:
-        the finished ``ContinuousResult`` carries ``truncated=True`` so
-        callers never mistake a shortened completion for a full one."""
+        ``cache_len`` budget or the whole block pool. Transient pressure
+        (no free slot, or no free blocks) just keeps it queued. A
+        ``max_new_tokens`` that exceeds the remaining cache room is
+        clamped, and the clamp is RECORDED: the finished
+        ``ContinuousResult`` carries ``truncated=True`` so callers never
+        mistake a shortened completion for a full one."""
         S = _bucket(len(prompt), buckets=SEQ_BUCKETS)
         F = self._frontend_tokens()
         room = self.cache_len - (F + S)
@@ -1261,16 +1216,14 @@ class ContinuousBatchingEngine:
             raise ValueError(
                 f"prompt bucket {S} (+{F} frontend) does not fit cache_len "
                 f"{self.cache_len}")
-        if self.kv_layout == "paged":
-            # a reservation that exceeds the whole pool could never be
-            # admitted — queuing it would livelock the FIFO head forever
-            # (same boundary rule as the over-length bucket check above)
-            need = self.allocator.blocks_for(
-                F + S + min(max_new_tokens, room))
-            if need > self.allocator.n_blocks:
-                raise ValueError(
-                    f"request needs {need} blocks, pool has only "
-                    f"{self.allocator.n_blocks}")
+        # a reservation that exceeds the whole pool could never be
+        # admitted — queuing it would livelock the FIFO head forever
+        # (same boundary rule as the over-length bucket check above)
+        need = self.allocator.blocks_for(F + S + min(max_new_tokens, room))
+        if need > self.allocator.n_blocks:
+            raise ValueError(
+                f"request needs {need} blocks, pool has only "
+                f"{self.allocator.n_blocks}")
         rid = self._next_id
         self._next_id += 1
         granted = min(max_new_tokens, room)
@@ -1537,16 +1490,16 @@ class ContinuousBatchingEngine:
         """Allocator revival hook: promote one spilled prefix block."""
         self._swap_in_blocks([hid], [bid])
 
-    def _graft(self, one_cache, slot: int, block_ids=None,
+    def _graft(self, one_cache, slot: int, block_ids: List[int],
                skip_blocks: int = 0) -> None:
         """Scatter a freshly-prefilled single-sequence cache into the
-        persistent cache. Dense layers (and windowed/recurrent state in
-        both layouts) write batch row ``slot``, zero-padding each leaf up
-        to the slot cache's length axes (same semantics as ``pad_cache``:
-        prefill wrote [0, S), decode writes from S on). Paged linear-KV
-        layers instead ``scatter_blocks`` the prefilled rows into the
-        physical blocks ``block_ids`` the allocator handed this slot —
-        grafting is block-granular, no ``cache_len`` copy. The first
+        persistent cache. Per-slot leaves (windowed ring buffers,
+        recurrent state) write batch row ``slot``, zero-padding each leaf
+        up to the slot cache's length axes (same semantics as
+        ``pad_cache``: prefill wrote [0, S), decode writes from S on).
+        Paged linear-KV layers ``scatter_blocks`` the prefilled rows into
+        the physical blocks ``block_ids`` the allocator handed this slot
+        — grafting is block-granular, no ``cache_len`` copy. The first
         ``skip_blocks`` ids are prefix-cache blocks mapped SHARED: they
         already hold the right content (possibly for other sequences
         too), so the scatter starts past them — writes only ever target
@@ -1577,13 +1530,12 @@ class ContinuousBatchingEngine:
                                  else one_c[key][0, start:cap], ids)
                     for key in ("k", "v")}
 
-        paged = self.kv_layout == "paged"
         _, tail_kinds = _split_layers(self.cfg)
         new: Dict = {}
         if "units" in self.cache:
             new["units"] = tuple(
                 graft_paged(fc, oc, stacked=True)
-                if paged and paged_layer_kind(self.cfg, kind)
+                if paged_layer_kind(self.cfg, kind)
                 else graft_layer(fc, oc, batch_axis=1)
                 for kind, fc, oc in zip(self.cfg.block_pattern,
                                         self.cache["units"],
@@ -1591,7 +1543,7 @@ class ContinuousBatchingEngine:
         if "tail" in self.cache:
             new["tail"] = tuple(
                 graft_paged(fc, oc, stacked=False)
-                if paged and paged_layer_kind(self.cfg, kind)
+                if paged_layer_kind(self.cfg, kind)
                 else graft_layer(fc, oc, batch_axis=0)
                 for kind, fc, oc in zip(tail_kinds, self.cache["tail"],
                                         one_cache["tail"]))
@@ -1607,8 +1559,8 @@ class ContinuousBatchingEngine:
         never blocks resident decodes for a whole prompt. Frontend
         models keep the single-shot inline prefill.
 
-        Paged layout: FIFO admission is additionally gated on the
-        allocator — the head request's worst-case block count
+        FIFO admission is gated on a free slot and on the allocator —
+        the head request's worst-case block count
         (prompt bucket + requested decode tokens) must be reservable, or
         it (and everything behind it) stays queued until evictions free
         blocks."""
@@ -1634,51 +1586,47 @@ class ContinuousBatchingEngine:
                 if self.chunked:
                     seq = np.zeros((S,), np.int32)
                     seq[S - len(w.prompt):] = w.prompt
-            reserved = 0
             shared_ids: List[int] = []
             pos0 = 0
             cow_key: Optional[str] = None
-            if self.kv_layout == "paged":
-                need_tokens = len(seq) + w.max_new if seq is not None \
-                    else self._seq_tokens(len(w.prompt), w.max_new)
-                need = self.allocator.blocks_for(need_tokens)
-                if self.prefix_cache and seq is not None:
-                    # map the longest cached block-aligned prefix at
-                    # refcount+1 and reserve only the remainder — the
-                    # admission-capacity gain sharing buys. acquire can
-                    # refuse an LRU revival (every remaining block
-                    # promised): the chain simply stops there.
-                    self.n_prefix_lookups += 1
-                    hit_keys, pos0, cow_key = self._prefix_lookup(seq)
-                    for k in hit_keys:
-                        bid = self.allocator.acquire(k)
-                        if bid is None:
-                            break
-                        shared_ids.append(bid)
-                    if len(shared_ids) < len(hit_keys):
-                        pos0 = len(shared_ids) * self.block_size
-                        cow_key = None
-                reserved = need - len(shared_ids)
-                if not self.allocator.reserve(reserved):
-                    if shared_ids:
-                        self.allocator.free(shared_ids)
-                    break  # FIFO: head of queue blocks on memory
+            need_tokens = len(seq) + w.max_new if seq is not None \
+                else self._seq_tokens(len(w.prompt), w.max_new)
+            need = self.allocator.blocks_for(need_tokens)
+            if self.prefix_cache and seq is not None:
+                # map the longest cached block-aligned prefix at
+                # refcount+1 and reserve only the remainder — the
+                # admission-capacity gain sharing buys. acquire can
+                # refuse an LRU revival (every remaining block
+                # promised): the chain simply stops there.
+                self.n_prefix_lookups += 1
+                hit_keys, pos0, cow_key = self._prefix_lookup(seq)
+                for k in hit_keys:
+                    bid = self.allocator.acquire(k)
+                    if bid is None:
+                        break
+                    shared_ids.append(bid)
+                if len(shared_ids) < len(hit_keys):
+                    pos0 = len(shared_ids) * self.block_size
+                    cow_key = None
+            reserved = need - len(shared_ids)
+            if not self.allocator.reserve(reserved):
+                if shared_ids:
+                    self.allocator.free(shared_ids)
+                break  # FIFO: head of queue blocks on memory
             self.waiting.pop(0)
             slot = free.pop(0)
             if self.chunked:
-                n0 = 0
-                ids: List[int] = list(shared_ids)
-                if self.kv_layout == "paged":
-                    # physically allocate the uncached prefill prefix
-                    # now; the decode tail of the reservation is claimed
-                    # lazily at block boundaries in step(). block_tables
-                    # stays on the null block until the prefill lands
-                    # (mid-prefill dummy decode writes must keep sinking
-                    # into the null block) — fused chunks carry their own
-                    # table row built from ``ids``.
-                    n0 = self.allocator.blocks_for(len(seq))
-                    ids += [self.allocator.alloc_reserved()
-                            for _ in range(n0 - len(shared_ids))]
+                # physically allocate the uncached prefill prefix now;
+                # the decode tail of the reservation is claimed lazily at
+                # block boundaries in step(). block_tables stays on the
+                # null block until the prefill lands (mid-prefill dummy
+                # decode writes must keep sinking into the null block) —
+                # fused chunks carry their own table row built from
+                # ``ids``.
+                n0 = self.allocator.blocks_for(len(seq))
+                ids = list(shared_ids) + [
+                    self.allocator.alloc_reserved()
+                    for _ in range(n0 - len(shared_ids))]
                 staging = None
                 if self.fused_prefill:
                     # fused path: chunks attend the shared prefix blocks
@@ -1695,8 +1643,8 @@ class ContinuousBatchingEngine:
                             self._copy_pool_block(ids[-1], tmp)
                             self.allocator.free([tmp])
                 else:
-                    # non-fused (dense / hybrid) layouts never see prefix
-                    # hits — the prefix cache requires the same layer gate
+                    # hybrid stacks never see prefix hits — the prefix
+                    # cache requires the same layer gate
                     # as fused prefill — so the staging cache starts empty
                     assert pos0 == 0 and not shared_ids
                     staging = self.model.init_cache(1, self.cache_len,
@@ -1785,22 +1733,15 @@ class ContinuousBatchingEngine:
         F = 0
         if self.cfg.frontend is not None and not self.cfg.enc_dec:
             F = batch["frontend_embeds"].shape[1]
-        if self.kv_layout == "paged":
-            n0 = self.allocator.blocks_for(F + S)
-            ids = [self.allocator.alloc_reserved() for _ in range(n0)]
-            self.block_tables[slot, :n0] = ids
-            self._graft(one_cache, slot, block_ids=ids)
-            self.slots[slot] = _Slot(
-                request_id=w.request_id, remaining=w.max_new,
-                submit_s=w.submit_s, admit_s=self._now(), blocks=ids,
-                n_outstanding=reserved - n0,
-                requested_new=w.requested_new, truncated=w.truncated)
-        else:
-            self._graft(one_cache, slot)
-            self.slots[slot] = _Slot(
-                request_id=w.request_id, remaining=w.max_new,
-                submit_s=w.submit_s, admit_s=self._now(),
-                requested_new=w.requested_new, truncated=w.truncated)
+        n0 = self.allocator.blocks_for(F + S)
+        ids = [self.allocator.alloc_reserved() for _ in range(n0)]
+        self.block_tables[slot, :n0] = ids
+        self._graft(one_cache, slot, block_ids=ids)
+        self.slots[slot] = _Slot(
+            request_id=w.request_id, remaining=w.max_new,
+            submit_s=w.submit_s, admit_s=self._now(), blocks=ids,
+            n_outstanding=reserved - n0,
+            requested_new=w.requested_new, truncated=w.truncated)
         self.pos[slot] = F + S
         self.pending_tok[slot] = int(sample_tokens(logits[0, -1, :]))
         if self.on_state is not None:
@@ -1813,9 +1754,9 @@ class ContinuousBatchingEngine:
         """Advance in-slot chunked prefills by at most ``budget_left``
         tokens (power-of-two chunk pieces so the compile cache stays
         bounded at one shape per piece size). Returns tokens processed.
-        A slot whose last chunk lands is grafted (non-fused layouts) or
-        just published (fused mode) and joins the decode batch of this
-        same iteration.
+        A slot whose last chunk lands is grafted (hybrid stacks) or just
+        published (fused mode) and joins the decode batch of this same
+        iteration.
 
         Fused mode runs each chunk directly against the paged pool: the
         batch carries the slot's block-table row (built from its
@@ -1860,25 +1801,22 @@ class ContinuousBatchingEngine:
 
     def _finish_prefill(self, slot: int, logits) -> None:
         """Last chunk landed: point the block table at the allocated
-        prefix blocks and hand the slot to the decode loop. Non-fused
-        layouts graft the staging cache into the slot first (skipping
+        prefix blocks and hand the slot to the decode loop. Hybrid
+        stacks graft the staging cache into the slot first (skipping
         the shared prefix blocks, which are immutable); in fused mode
         the chunks already wrote the pool through the table, so there is
         nothing to scatter. With the prefix cache on, the now-complete
         full prompt blocks are published under their chain keys so later
         same-prefix admissions can share them."""
         s = self.slots[slot]
-        if self.kv_layout == "paged":
-            self.block_tables[slot, :len(s.blocks)] = s.blocks
-            if not self.fused_prefill:
-                self._graft(s.staging, slot, block_ids=s.blocks,
-                            skip_blocks=s.n_shared)
-            if self.prefix_cache:
-                for i, key in enumerate(self._chain_keys(s.seq_tokens)):
-                    if i >= s.n_shared:
-                        self.allocator.register(key, s.blocks[i])
-        else:
-            self._graft(s.staging, slot)
+        self.block_tables[slot, :len(s.blocks)] = s.blocks
+        if not self.fused_prefill:
+            self._graft(s.staging, slot, block_ids=s.blocks,
+                        skip_blocks=s.n_shared)
+        if self.prefix_cache:
+            for i, key in enumerate(self._chain_keys(s.seq_tokens)):
+                if i >= s.n_shared:
+                    self.allocator.register(key, s.blocks[i])
         s.staging = None
         self.pos[slot] = s.prefill_pos
         with span("repro.engine.prefill_readback"):
@@ -1900,12 +1838,8 @@ class ContinuousBatchingEngine:
         for i, s in enumerate(self.slots):
             if not s.active or s.prefilling:
                 continue
-            freeable = s.n_outstanding
-            if self.kv_layout == "paged":
-                freeable += sum(1 for b in s.blocks
-                                if self.allocator.refcount(b) == 1)
-            else:
-                freeable += len(s.blocks)
+            freeable = s.n_outstanding + sum(
+                1 for b in s.blocks if self.allocator.refcount(b) == 1)
             out.append((i, s.request_id, freeable))
         return out
 
@@ -1981,10 +1915,9 @@ class ContinuousBatchingEngine:
                 submit_s=s.submit_s, requested_new=s.requested_new,
                 truncated=s.truncated, n_preempted=s.n_preempted + 1,
                 first_token_s=s.first_token_s, n_pad=s.n_pad)
-        if self.kv_layout == "paged":
-            self.allocator.free(s.blocks)
-            self.allocator.unreserve(s.n_outstanding)
-            self.block_tables[slot, :] = 0
+        self.allocator.free(s.blocks)
+        self.allocator.unreserve(s.n_outstanding)
+        self.block_tables[slot, :] = 0
         self.pos[slot] = 0
         self.slots[slot] = _Slot()
         self.n_preempted += 1
@@ -2070,12 +2003,11 @@ class ContinuousBatchingEngine:
                 n_spec_proposed=s.n_spec_proposed,
                 n_spec_accepted=s.n_spec_accepted,
                 first_token_s=s.first_token_s, cancelled=True)
-            if self.kv_layout == "paged":
-                # same free path as eviction: refcounted frees park
-                # still-registered prefix blocks in the LRU pool
-                self.allocator.free(s.blocks)
-                self.allocator.unreserve(s.n_outstanding)
-                self.block_tables[i, :] = 0
+            # same free path as eviction: refcounted frees park
+            # still-registered prefix blocks in the LRU pool
+            self.allocator.free(s.blocks)
+            self.allocator.unreserve(s.n_outstanding)
+            self.block_tables[i, :] = 0
             self.pos[i] = 0
             self.slots[i] = _Slot()
             self.n_cancelled += 1
@@ -2124,19 +2056,18 @@ class ContinuousBatchingEngine:
         with span("repro.engine.decode_batch"):
             batch = {"tokens": jnp.asarray(self.pending_tok[:, None]),
                      "pos": jnp.asarray(self.pos)}
-            if self.kv_layout == "paged":
-                # alloc-on-decode-boundary: the write at ``pos`` needs its
-                # block mapped before the decode runs; the admission
-                # reservation guarantees the free list cannot be empty
-                bs = self.block_size
-                for i in active:
-                    s = self.slots[i]
-                    while self.pos[i] >= len(s.blocks) * bs:
-                        bid = self.allocator.alloc_reserved()
-                        s.n_outstanding -= 1
-                        self.block_tables[i, len(s.blocks)] = bid
-                        s.blocks.append(bid)
-                batch["block_tables"] = jnp.asarray(self.block_tables)
+            # alloc-on-decode-boundary: the write at ``pos`` needs its
+            # block mapped before the decode runs; the admission
+            # reservation guarantees the free list cannot be empty
+            bs = self.block_size
+            for i in active:
+                s = self.slots[i]
+                while self.pos[i] >= len(s.blocks) * bs:
+                    bid = self.allocator.alloc_reserved()
+                    s.n_outstanding -= 1
+                    self.block_tables[i, len(s.blocks)] = bid
+                    s.blocks.append(bid)
+            batch["block_tables"] = jnp.asarray(self.block_tables)
         if not self._decode_warm:
             self._decode_warm = True
             self._note_compile()
@@ -2174,13 +2105,12 @@ class ContinuousBatchingEngine:
                     n_iters=len(emitted), truncated=s.truncated,
                     n_preempted=s.n_preempted,
                     first_token_s=s.first_token_s))
-                if self.kv_layout == "paged":
-                    # free-on-evict: blocks return to the pool, the
-                    # unconsumed tail of the reservation is cancelled
-                    self.allocator.free(s.blocks)
-                    self.allocator.unreserve(s.n_outstanding)
-                    self.block_tables[i, :] = 0
-                    self.pos[i] = 0
+                # free-on-evict: blocks return to the pool, the
+                # unconsumed tail of the reservation is cancelled
+                self.allocator.free(s.blocks)
+                self.allocator.unreserve(s.n_outstanding)
+                self.block_tables[i, :] = 0
+                self.pos[i] = 0
                 self.slots[i] = _Slot()
                 self.n_evicted += 1
             else:
@@ -2206,9 +2136,9 @@ class ContinuousBatchingEngine:
         to ``k`` draft tokens per slot from its own context, score the
         pending token + drafts in ONE ``(n_slots, 1+k)`` verify forward,
         accept the longest draft prefix matching the verify argmax, and
-        roll the KV state back over the rejected tail — dense rows are
-        masked by ``pos`` (never attended before being overwritten);
-        paged blocks are freed back to the allocator at block
+        roll the KV state back over the rejected tail: rejected rows are
+        masked by ``pos`` (never attended before being overwritten) and
+        trailing blocks are freed back to the allocator at block
         granularity. Greedy output is token-identical to k=0 because
         acceptance IS greedy equality: every emitted token equals the
         argmax a sequential decode would have produced (asserted in
@@ -2244,8 +2174,8 @@ class ContinuousBatchingEngine:
             s = self.slots[i]
             # participation cap: never draft past the request's remaining
             # tokens or the logical cache capacity (rows j > k_i of the
-            # fixed-width forward land in the null block / scratch margin
-            # and their logits are ignored)
+            # fixed-width forward land in the null block and their logits
+            # are ignored)
             ki = max(0, min(k, s.remaining - 1,
                             self.cache_len - 1 - int(self.pos[i])))
             k_eff[i] = ki
@@ -2258,28 +2188,26 @@ class ContinuousBatchingEngine:
                     else np.asarray(s.tokens + [self.pending_tok[i]],
                                     np.int32)
                 toks[i, 1:1 + ki] = self.proposer.propose(context, ki)
+        # pre-allocate blocks covering each slot's deepest draft row (the
+        # admission reservation covers them: pos + k_i is within the
+        # granted footprint), then hand the forward a block table padded
+        # with null columns so rows past cache_len can never clip into a
+        # live block (JAX clamps out-of-bounds gathers)
+        bs = self.block_size
+        for i in active:
+            s = self.slots[i]
+            top = int(self.pos[i]) + k_eff[i]
+            while top >= len(s.blocks) * bs:
+                bid = self.allocator.alloc_reserved()
+                s.n_outstanding -= 1
+                self.block_tables[i, len(s.blocks)] = bid
+                s.blocks.append(bid)
+        pad = -(-self.spec_max // bs)
+        vt = np.zeros((self.n_slots, self.blocks_per_slot + pad), np.int32)
+        vt[:, :self.blocks_per_slot] = self.block_tables
         batch = {"tokens": jnp.asarray(toks),
-                 "pos": jnp.asarray(self.pos)}
-        if self.kv_layout == "paged":
-            # pre-allocate blocks covering each slot's deepest draft row
-            # (the admission reservation covers them: pos + k_i is within
-            # the granted footprint), then hand the forward a block table
-            # padded with null columns so rows past cache_len can never
-            # clip into a live block (JAX clamps out-of-bounds gathers)
-            bs = self.block_size
-            for i in active:
-                s = self.slots[i]
-                top = int(self.pos[i]) + k_eff[i]
-                while top >= len(s.blocks) * bs:
-                    bid = self.allocator.alloc_reserved()
-                    s.n_outstanding -= 1
-                    self.block_tables[i, len(s.blocks)] = bid
-                    s.blocks.append(bid)
-            pad = -(-self.spec_max // bs)
-            vt = np.zeros((self.n_slots, self.blocks_per_slot + pad),
-                          np.int32)
-            vt[:, :self.blocks_per_slot] = self.block_tables
-            batch["block_tables"] = jnp.asarray(vt)
+                 "pos": jnp.asarray(self.pos),
+                 "block_tables": jnp.asarray(vt)}
         return toks, k_eff, batch
 
     def _accept(self, active: List[int], toks: np.ndarray,
@@ -2307,8 +2235,7 @@ class ContinuousBatchingEngine:
             s.remaining -= a + 1
             self._note_tokens(s, a + 1)
             new_pos = int(self.pos[i]) + a + 1
-            if self.kv_layout == "paged":
-                self._trim_blocks(i, new_pos)
+            self._trim_blocks(i, new_pos)
             if new_pos >= self.cache_len and s.remaining > 0:
                 s.truncated = True
                 s.remaining = 0
@@ -2325,11 +2252,10 @@ class ContinuousBatchingEngine:
                     n_spec_proposed=s.n_spec_proposed,
                     n_spec_accepted=s.n_spec_accepted,
                     first_token_s=s.first_token_s))
-                if self.kv_layout == "paged":
-                    self.allocator.free(s.blocks)
-                    self.allocator.unreserve(s.n_outstanding)
-                    self.block_tables[i, :] = 0
-                    self.pos[i] = 0
+                self.allocator.free(s.blocks)
+                self.allocator.unreserve(s.n_outstanding)
+                self.block_tables[i, :] = 0
+                self.pos[i] = 0
                 self.slots[i] = _Slot()
                 self.n_evicted += 1
             else:
@@ -2367,8 +2293,8 @@ class ContinuousBatchingEngine:
     def rollback(self, slot: int, n: int) -> None:
         """Undo the last ``n`` emitted tokens of the sequence in
         ``slot``: the committed context shrinks by ``n``, the pending
-        token becomes what it was before those emissions, and (paged)
-        the trailing KV blocks past the new frontier are freed back to
+        token becomes what it was before those emissions, and the
+        trailing KV blocks past the new frontier are freed back to
         the allocator with the reservation restored — the primitive the
         speculative path's rejection handling is built on, exposed for
         the property tests (tests/test_speculative.py). Re-decoding from
@@ -2391,8 +2317,7 @@ class ContinuousBatchingEngine:
         s.n_emitted -= n
         s.remaining += n
         self.pos[slot] = new_pos
-        if self.kv_layout == "paged":
-            self._trim_blocks(slot, new_pos)
+        self._trim_blocks(slot, new_pos)
 
     @property
     def spec_accept_rate(self) -> float:
@@ -2426,23 +2351,19 @@ class ContinuousBatchingEngine:
 
     @property
     def kv_allocated_tokens(self) -> int:
-        """Cache positions *committed*: the whole slab for the dense
-        layout, LIVE blocks × block_size for the paged one — a block
-        shared by N sequences is counted ONCE, and evicted-but-cached
-        LRU blocks are reclaimable so they do not count."""
-        if self.kv_layout == "paged":
-            return self.allocator.n_live * self.block_size
-        return self.n_slots * self.cache_len
+        """Cache positions *committed*: LIVE blocks × block_size — a
+        block shared by N sequences is counted ONCE, and
+        evicted-but-cached LRU blocks are reclaimable so they do not
+        count."""
+        return self.allocator.n_live * self.block_size
 
     @property
     def kv_unique_used_tokens(self) -> int:
         """Distinct physical cache positions live sequences occupy:
-        per-block coverage with shared blocks counted once (the paged
+        per-block coverage with shared blocks counted once (the physical
         counterpart of ``kv_used_tokens``, which stays per-sequence
         logical — under sharing the logical sum can exceed the physical
         footprint, which is the whole point)."""
-        if self.kv_layout != "paged":
-            return self.kv_used_tokens
         bs = self.block_size
         cov: Dict[int, int] = {}
         for i, s in enumerate(self.slots):
@@ -2450,8 +2371,8 @@ class ContinuousBatchingEngine:
                 continue
             if s.prefilling:
                 # fused chunks write the pool directly, so every
-                # prefilled token occupies its block; non-fused hybrid
-                # layouts hold nothing in the pool until the graft (the
+                # prefilled token occupies its block; hybrid stacks hold
+                # nothing in the pool until the graft (the
                 # chunked prefix lives in the staging cache)
                 c = s.prefill_pos if self.fused_prefill \
                     else min(s.prefill_pos, s.n_shared * bs)
@@ -2489,15 +2410,12 @@ class ContinuousBatchingEngine:
 
     @property
     def kv_free_tokens(self) -> int:
-        """Admission headroom in tokens: unreserved blocks (paged) or
-        free slots × cache_len (dense)."""
-        if self.kv_layout == "paged":
-            return self.allocator.n_available * self.block_size
-        return len(self.free_slots) * self.cache_len
+        """Admission headroom in tokens: unreserved blocks."""
+        return self.allocator.n_available * self.block_size
 
     def stats(self) -> Dict[str, float]:
         """Counters + KV occupancy metrics, so benchmarks can report
-        dense-vs-paged waste without poking engine internals.
+        pool waste without poking engine internals.
         ``kv_waste_frac`` counts shared blocks ONCE (unique physical
         coverage over live allocation); ``kv_used_tokens`` stays
         per-sequence logical, so used/allocated can exceed 1 under
@@ -2522,11 +2440,9 @@ class ContinuousBatchingEngine:
             "kv_allocated_tokens": alloc,
             "kv_waste_frac": 1.0 - uniq / alloc if alloc else 0.0,
             "kv_reserved_tokens": float(
-                self.allocator.n_reserved * self.block_size
-                if self.kv_layout == "paged" else 0),
+                self.allocator.n_reserved * self.block_size),
             "kv_cached_tokens": float(
-                self.allocator.n_cached * self.block_size
-                if self.kv_layout == "paged" else 0),
+                self.allocator.n_cached * self.block_size),
             "kv_shared_frac": self.kv_shared_frac,
             "prefix_hit_rate": self.prefix_hit_rate,
             "n_prefix_hits": float(self.n_prefix_hits),
@@ -2535,23 +2451,13 @@ class ContinuousBatchingEngine:
             "n_cancelled": float(self.n_cancelled),
             # host KV tier (docs/ARCHITECTURE.md §5)
             "kv_host_blocks": float(self.kv_host_blocks),
-            "kv_host_free": float(
-                self.allocator.n_host_free
-                if self.kv_layout == "paged" else 0),
-            "kv_host_live": float(
-                self.allocator.n_host_live
-                if self.kv_layout == "paged" else 0),
-            "kv_host_cached": float(
-                self.allocator.n_host_cached
-                if self.kv_layout == "paged" else 0),
+            "kv_host_free": float(self.allocator.n_host_free),
+            "kv_host_live": float(self.allocator.n_host_live),
+            "kv_host_cached": float(self.allocator.n_host_cached),
             "n_swap_preempts": float(self.n_swap_preempts),
             "n_swap_resumes": float(self.n_swap_resumes),
-            "n_spilled": float(
-                self.allocator.n_spilled
-                if self.kv_layout == "paged" else 0),
-            "n_unspilled": float(
-                self.allocator.n_unspilled
-                if self.kv_layout == "paged" else 0),
+            "n_spilled": float(self.allocator.n_spilled),
+            "n_unspilled": float(self.allocator.n_unspilled),
             "prefill_backlog_tokens": float(self.prefill_backlog_tokens),
             "token_budget": float(self.token_budget or 0),
             "spec_k": float(min(max(0, self.spec_k), self.spec_max)),

@@ -24,17 +24,17 @@ concurrent engine instances:
 
 Instances of the same model share weights and jit caches
 (``ContinuousBatchingEngine(share_from=...)``) so ``spawn`` is cheap
-enough to be a per-decision action; each instance keeps its own KV slot
-cache, which is what actually bounds m_c on a real host.
+enough to be a per-decision action; each instance keeps its own KV
+block pool, which is what actually bounds m_c on a real host.
 
-Under ``kv_layout="paged"`` (docs/RUNTIME.md §7) every engine uses the
-block-pool KV layout and the pool shares ONE ``kv_block_budget`` across
-instances: ``spawn``/``scale_to`` are constrained by actual free blocks,
-the router's admission gate is the per-engine ``BlockAllocator``, and
-every pure-decode iteration records real occupancy samples that
-calibrate ``latency_model.fit_occupancy`` — the measured memory model
-the ``PoolScheduler``'s Eq.-4 guard checks proposed (b, m_c) actions
-against, in place of the analytic ``instance_memory_gb`` curve.
+The pool can share ONE ``kv_block_budget`` across instances
+(docs/RUNTIME.md §7): ``spawn``/``scale_to`` are constrained by actual
+free blocks, the router's admission gate is the per-engine
+``BlockAllocator``, and every pure-decode iteration records real
+occupancy samples that calibrate ``latency_model.fit_occupancy`` — the
+measured memory model the ``PoolScheduler``'s Eq.-4 guard checks
+proposed (b, m_c) actions against, in place of the analytic
+``instance_memory_gb`` curve.
 """
 from __future__ import annotations
 
@@ -208,13 +208,17 @@ class ModelInstancePool:
     (docs/RUNTIME.md). The unit of progress is ``step()``: route waiting
     requests, run one decode iteration on every busy instance, retire
     empty draining instances, and record the iteration's wall latency
-    against the overlap level for interference calibration."""
+    against the overlap level for interference calibration.
+
+    Every engine keeps its KV in a block pool. ``kv_layout`` accepts
+    only ``"paged"``: it is kept so that existing callers which name
+    the layout stay valid, and selects nothing."""
 
     def __init__(self, configs: Dict[str, ModelConfig],
                  max_instances: int = 8, max_slots: int = 4,
                  max_seq: int = 128, seed: int = 0,
                  strict_admission: bool = False,
-                 predictor=None, kv_layout: str = "dense",
+                 predictor=None, kv_layout: str = "paged",
                  block_size: int = 16,
                  kv_block_budget: Optional[int] = None,
                  blocks_per_instance: Optional[int] = None,
@@ -236,20 +240,23 @@ class ModelInstancePool:
         self.seed = seed
         self.strict_admission = strict_admission
         self.predictor = predictor
-        #: paged KV serving (docs/RUNTIME.md §7): every instance's engine
-        #: uses the block-pool layout and the pool shares ONE block
-        #: budget across all instances — memory becomes a managed
-        #: resource instead of the analytic latency_model curve
-        self.kv_layout = kv_layout
+        if kv_layout != "paged":
+            raise ValueError(
+                f"kv_layout {kv_layout!r}: the block pool ('paged') is "
+                "the only KV layout")
+        #: KV budget (docs/RUNTIME.md §7): with ``kv_block_budget`` set
+        #: the pool shares ONE block budget across all instances — memory
+        #: becomes a managed resource instead of the analytic
+        #: latency_model curve
         self.block_size = block_size
         self.kv_block_budget = kv_block_budget
         self.kv_blocks_free = kv_block_budget
-        #: vLLM-style prefix caching (docs/ARCHITECTURE.md §5): paged
-        #: engines share full immutable prompt blocks at refcount+1 and
+        #: vLLM-style prefix caching (docs/ARCHITECTURE.md §5): engines
+        #: share full immutable prompt blocks at refcount+1 and
         #: the router gains prefix affinity. Models whose layer stack
         #: cannot page every decode state (recurrent/windowed/frontend)
         #: silently serve without it — per-model capability, one flag.
-        self.prefix_cache = prefix_cache and kv_layout == "paged"
+        self.prefix_cache = prefix_cache
         #: speculative decoding (docs/ARCHITECTURE.md §speculation): the
         #: construction-time CAP on the proposal depth — engines are
         #: built with scratch capacity for ``spec_cap`` draft tokens and
@@ -274,10 +281,10 @@ class ModelInstancePool:
         #: from its FREE devices (``_carve_mesh``), so co-resident
         #: instances sit on disjoint devices, tp=1 included
         self.n_devices = n_devices
-        #: target grant for a paged instance; default = dense-equivalent
-        #: worst case. Sizing it from measured occupancy
-        #: (``occupancy_tokens_per_seq``) is how a paged pool fits more
-        #: instances into the same budget than dense slabs allow.
+        #: target grant for an instance; default = one full-length row
+        #: per slot (``_full_grant``). Sizing it from measured occupancy
+        #: (``occupancy_tokens_per_seq``) is how the pool fits more
+        #: instances into the same budget than full-length rows allow.
         self.blocks_per_instance = blocks_per_instance
         #: SLO-aware preemption policy (docs/RUNTIME.md §8): when the
         #: most urgent waiting request cannot be admitted anywhere and
@@ -304,10 +311,6 @@ class ModelInstancePool:
         if kv_host_blocks < 0:
             raise ValueError(
                 f"kv_host_blocks must be >= 0, got {kv_host_blocks}")
-        if kv_host_blocks > 0 and kv_layout != "paged":
-            raise ValueError(
-                "kv_host_blocks needs kv_layout='paged' (the host tier "
-                "swaps block-granular KV)")
         self.kv_host_blocks = kv_host_blocks
         self.preempt_mode = preempt_mode
         self.n_swap_preempted = 0
@@ -485,14 +488,14 @@ class ModelInstancePool:
             if inst.state == RUNNING and inst.tp_degree != tp:
                 inst.state = DRAINING
 
-    def _dense_equiv_blocks(self) -> int:
-        """Dense-equivalent worst-case grant: the whole
-        (max_slots, max_seq) slab expressed in blocks — what a dense
-        instance COMMITS by construction."""
+    def _full_grant(self) -> int:
+        """Worst-case grant: one full ``max_seq`` row of blocks per slot,
+        so block availability never refuses what a free slot could
+        hold (the engine's own ``kv_blocks`` default)."""
         return self.max_slots * (-(-self.max_seq // self.block_size))
 
     def _min_viable_blocks(self) -> int:
-        """Smallest grant a spawned paged instance can serve with: one
+        """Smallest grant a spawned instance can serve with: one
         slot's worst case, or the operator's explicit (right-sized)
         ``blocks_per_instance`` target if that is smaller — deliberate
         oversubscription against measured occupancy."""
@@ -503,17 +506,15 @@ class ModelInstancePool:
 
     def _spawn_grant(self) -> int:
         """Blocks the next spawn would charge against the budget."""
-        if self.kv_layout != "paged":
-            return self._dense_equiv_blocks()
-        return self.blocks_per_instance or self._dense_equiv_blocks()
+        return self.blocks_per_instance or self._full_grant()
 
     def can_spawn(self, model: Optional[str] = None) -> bool:
         """Instance budget, device budget AND block budget allow one
         more spawn — ``scale_to`` is constrained by actual free blocks,
-        not the analytic memory curve. A dense instance must fit its
-        whole slab; a paged one can start on a partial grant (min one
-        slot). ``model`` prices that model's TP degree against the
-        shared device set (degree 1 assumed when omitted)."""
+        not the analytic memory curve. An instance can start on a
+        partial grant (min one slot). ``model`` prices that model's TP
+        degree against the shared device set (degree 1 assumed when
+        omitted)."""
         if self.total_live() >= self.max_instances:
             return False
         tp = self.tp_degrees.get(model, 1) if model else 1
@@ -522,10 +523,7 @@ class ModelInstancePool:
             return False
         if self.kv_blocks_free is None:
             return True
-        if self.kv_layout == "paged":
-            return self.kv_blocks_free >= \
-                -(-self._min_viable_blocks() // tp)
-        return self.kv_blocks_free >= self._dense_equiv_blocks()
+        return self.kv_blocks_free >= -(-self._min_viable_blocks() // tp)
 
     def spawn(self, model: str) -> ModelInstance:
         """STARTING → RUNNING. Raises when the pool-wide instance
@@ -543,35 +541,25 @@ class ModelInstancePool:
                 f"{self.n_devices - self.devices_in_use()} free")
         grant = self._spawn_grant()
         charge = grant
-        kw = {}
         if self.kv_blocks_free is not None:
-            if self.kv_layout == "paged":
-                # head-sharding spreads every block over the instance's
-                # tp devices, so one budget (per-device) block buys tp
-                # pool blocks: the charge is ceil(grant / tp) and the
-                # engine keeps the full grant (docs/RUNTIME.md §10)
-                charge = min(-(-grant // tp), self.kv_blocks_free)
-                grant = charge * tp
-                if grant < self._min_viable_blocks():
-                    raise RuntimeError(
-                        f"KV block budget exhausted "
-                        f"({self.kv_blocks_free} free of "
-                        f"{self.kv_block_budget})")
-            elif self.kv_blocks_free < charge:
+            # head-sharding spreads every block over the instance's tp
+            # devices, so one budget (per-device) block buys tp pool
+            # blocks: the charge is ceil(grant / tp) and the engine keeps
+            # the full grant (docs/RUNTIME.md §10)
+            charge = min(-(-grant // tp), self.kv_blocks_free)
+            grant = charge * tp
+            if grant < self._min_viable_blocks():
                 raise RuntimeError(
-                    f"KV block budget exhausted: dense slab needs "
-                    f"{charge} blocks, {self.kv_blocks_free} free")
+                    f"KV block budget exhausted "
+                    f"({self.kv_blocks_free} free of "
+                    f"{self.kv_block_budget})")
             self.kv_blocks_free -= charge
-        elif self.kv_layout != "paged":
-            grant = charge = 0  # unlimited dense pool: nothing to account
-        if self.kv_layout == "paged":
-            kw = {"kv_layout": "paged", "block_size": self.block_size,
-                  "kv_blocks": grant,
-                  "prefix_cache": self.prefix_cache
-                  and supports_prefix_cache(self.configs[model]),
-                  # host tier is single-device: sharded instances keep
-                  # recompute-on-resume (the engine rejects the combo)
-                  "kv_host_blocks": self.kv_host_blocks if tp == 1 else 0}
+        kw = {"block_size": self.block_size, "kv_blocks": grant,
+              "prefix_cache": self.prefix_cache
+              and supports_prefix_cache(self.configs[model]),
+              # host tier is single-device: sharded instances keep
+              # recompute-on-resume (the engine rejects the combo)
+              "kv_host_blocks": self.kv_host_blocks if tp == 1 else 0}
         if self.spec_cap > 0 and supports_speculation(self.configs[model]):
             kw["spec_k"] = self.spec_cap
         mesh = self._carve_mesh(tp)
@@ -686,8 +674,7 @@ class ModelInstancePool:
                     retired_any = True
                     if self.kv_blocks_free is not None:
                         # the instance's KV block grant returns to the
-                        # shared budget (the paged analogue of dropping
-                        # the dense slot cache)
+                        # shared budget
                         self.kv_blocks_free += inst.kv_blocks
                     inst.kv_blocks = 0
                     self.retired.append(inst)
@@ -870,8 +857,6 @@ class ModelInstancePool:
         live instance AND the (unclamped, optimistic) grant a future
         spawn would take. Such a request can never leave the EDF queue,
         so the router rejects it up front."""
-        if self.kv_layout != "paged":
-            return False
         insts = self.running(model)
         if not insts:
             return False
@@ -915,8 +900,7 @@ class ModelInstancePool:
                 vslack_ms = (vreq.deadline_s - now) * 1000.0
                 if vslack_ms <= slack_ms + self.preempt_margin_ms:
                     continue  # hysteresis: victim must be clearly lazier
-                if self.kv_layout == "paged" and \
-                        eng.allocator.n_available + freeable \
+                if eng.allocator.n_available + freeable \
                         < self._request_blocks(eng, req):
                     continue  # eviction would not make req admissible
                 if best is None or vslack_ms > best[0]:
@@ -1078,9 +1062,9 @@ class ModelInstancePool:
                                 pending.get(i.instance_id, 0),
                                 resume=req.resume, prompt=req.prompt)]
 
-                # paged engines additionally gate on free KV blocks —
-                # a slot is only admissible when the request's worst-case
-                # block need is reservable (docs/RUNTIME.md §7)
+                # engines additionally gate on free KV blocks — a slot
+                # is only admissible when the request's worst-case block
+                # need is reservable (docs/RUNTIME.md §7)
                 cands = _cands() if open_insts else []
                 if not cands and self.preemption and \
                         self._try_preempt(model, req, now):
@@ -1114,10 +1098,9 @@ class ModelInstancePool:
                 else:
                     inst = max(cands, key=lambda i: cap - i.n_resident)
                 heapq.heappop(q)
-                if self.kv_layout == "paged":
-                    pending[inst.instance_id] = \
-                        pending.get(inst.instance_id, 0) \
-                        + self._request_blocks(inst.engine, req)
+                pending[inst.instance_id] = \
+                    pending.get(inst.instance_id, 0) \
+                    + self._request_blocks(inst.engine, req)
                 if req.resume is not None:
                     erid = inst.engine.submit_resume(req.resume)
                     req.resume = None
@@ -1412,8 +1395,6 @@ class ModelInstancePool:
         blocks charge the shared budget once."""
         logical = distinct = 0
         for i in self.live():
-            if i.engine.kv_layout != "paged":
-                continue
             lg, d = i.engine.kv_block_mapping()
             logical += lg
             distinct += d
@@ -1421,19 +1402,17 @@ class ModelInstancePool:
 
     def kv_occupancy(self) -> Dict[str, float]:
         """Real occupancy of the shared KV budget — what grounds the
-        ``PoolScheduler`` Eq.-4 guard when the pool is paged. Budget
+        ``PoolScheduler`` Eq.-4 guard. Budget
         fields are 0 for unlimited budgets. ``allocated_tokens`` counts
         a refcount-shared block ONCE (each engine reports distinct live
         blocks), so the gap to the logical ``used_tokens`` is exactly
         what prefix sharing saves."""
         budget_blocks = self.kv_block_budget or 0
         committed = sum(i.kv_blocks for i in self.live())
-        # host-tier occupancy across live paged engines (0 everywhere
-        # when no engine carries a host pool)
+        # host-tier occupancy across live engines (0 everywhere when no
+        # engine carries a host pool)
         host_blocks = host_free = host_live = host_cached = 0
         for i in self.live():
-            if i.engine.kv_layout != "paged":
-                continue
             a = i.engine.allocator
             host_blocks += a.n_host_blocks
             host_free += a.n_host_free
@@ -1536,6 +1515,5 @@ class ModelInstancePool:
             "tpot_ms_p99": float(np.percentile(self.tpot_samples, 99))
             if self.tpot_samples else 0.0,
         }
-        if self.kv_layout == "paged" or self.kv_block_budget:
-            out.update({f"kv_{k}": v for k, v in self.kv_occupancy().items()})
+        out.update({f"kv_{k}": v for k, v in self.kv_occupancy().items()})
         return out

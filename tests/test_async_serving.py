@@ -91,14 +91,13 @@ def _drain(eng, results):
 
 
 def _assert_no_leak(eng):
-    if eng.allocator is not None:
-        assert eng.allocator.n_live == 0
-        assert eng.allocator.n_reserved == 0
+    assert eng.allocator.n_live == 0
+    assert eng.allocator.n_reserved == 0
 
 
 def test_engine_cancel_queued_and_survivor_identity():
     eng = make_cont_engine(TINY, max_slots=1, max_seq=64,
-                           kv_layout="paged", block_size=8)
+                           block_size=8)
     p1, p2 = _prompt(8, 1), _prompt(8, 2)
     oracle = make_cont_engine(TINY, max_slots=1, max_seq=64,
                               share_from=eng).run(
@@ -119,7 +118,7 @@ def test_engine_cancel_queued_and_survivor_identity():
 
 def test_engine_cancel_mid_decode_frees_blocks_synchronously():
     eng = make_cont_engine(TINY, max_slots=2, max_seq=64,
-                           kv_layout="paged", block_size=8)
+                           block_size=8)
     p1, p2 = _prompt(8, 3), _prompt(8, 4)
     oracle = make_cont_engine(TINY, max_slots=1, max_seq=64,
                               share_from=eng).run(
@@ -142,7 +141,7 @@ def test_engine_cancel_mid_decode_frees_blocks_synchronously():
 def test_engine_cancel_mid_prefill_and_preempted():
     # token budget forces multi-chunk prefill AND enables preemption
     eng = make_cont_engine(TINY, max_slots=2, max_seq=64,
-                           kv_layout="paged", block_size=8,
+                           block_size=8,
                            token_budget=8)
     long_p, short_p = _prompt(30, 5), _prompt(6, 6)
     oracle = make_cont_engine(TINY, max_slots=1, max_seq=64,
@@ -189,7 +188,7 @@ def test_engine_cancel_unknown_or_finished_is_noop():
 # --------------------------------------------------------- pool cancel
 def test_pool_cancel_dequeues_immediately_no_head_starvation():
     pool = make_pool(TINY, max_instances=1, max_slots=1, max_seq=64,
-                     kv_layout="paged", block_size=8)
+                     block_size=8)
     pool.scale_to(TINY.name, 1)
     pool.warmup(seed=0)
     # r0 occupies the only slot for a while
@@ -325,7 +324,7 @@ def test_driver_cancel_and_stop_idempotent():
 # ---------------------------------------------------------------- http
 def _http_stack(backpressure=True, max_queue_depth=2, max_slots=2):
     pool = make_pool(TINY, max_instances=1, max_slots=max_slots,
-                     max_seq=64, kv_layout="paged", block_size=8)
+                     max_seq=64, block_size=8)
     pool.scale_to(TINY.name, 1)
     pool.warmup(seed=0)
     driver = ServingDriver(pool)

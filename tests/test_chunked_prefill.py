@@ -80,22 +80,18 @@ def test_budgeted_engine_matches_round_engine_greedy():
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("prefill_mode", ["fused"])
 @pytest.mark.parametrize("prefix_cache", [False, True])
-def test_paged_chunked_identity_staging_vs_fused(prefill_mode, prefix_cache):
+def test_paged_chunked_identity_fused(prefix_cache):
     """The fused path (chunks attend the block pool directly through
-    their table row) stays token-identical to the dense round engine on
-    paged chunked prefill, with and without prefix reuse. This
-    parametrization was the deletion gate for the legacy staging round
-    trip — "staging" was dropped and the code deleted (the staging
-    cache survives only for layouts fused prefill cannot serve: dense
-    and hybrid stacks, covered by the engine fuzz harness)."""
+    their table row) stays token-identical to the round engine on
+    chunked prefill, with and without prefix reuse. The staging cache
+    survives only for hybrid stacks, which fused prefill cannot serve
+    (covered by the engine fuzz harness)."""
     round_eng = InferenceEngine(TINY, max_seq=64)
     eng = ContinuousBatchingEngine(
-        TINY, max_slots=2, max_seq=64, kv_layout="paged", block_size=8,
-        token_budget=12, prefix_cache=prefix_cache,
-        prefill_mode=prefill_mode)
-    assert eng.fused_prefill == (prefill_mode == "fused")
+        TINY, max_slots=2, max_seq=64, block_size=8,
+        token_budget=12, prefix_cache=prefix_cache)
+    assert eng.fused_prefill
     rng = np.random.default_rng(6)
     shared = rng.integers(1, 97, 17).astype(np.int32)
     prompts = [np.concatenate([shared,

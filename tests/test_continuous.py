@@ -141,7 +141,7 @@ def test_shared_engines_donating_in_alternation_stay_identical(kind,
     verify, or a hybrid stack's staging prefill and per-slot state), each
     serves exactly what the same requests get served alone."""
     cfg = KIND_CFGS[kind]
-    kw = dict(max_slots=2, max_seq=64, kv_layout="paged", block_size=8,
+    kw = dict(max_slots=2, max_seq=64, block_size=8,
               spec_k=spec_k)
     rng = np.random.default_rng(7)
     prompts = [[rng.integers(1, 97, n).astype(np.int32) for n in ns]
@@ -162,7 +162,7 @@ def test_swap_resume_and_prefix_hit_after_donated_steps():
     """Host-tier swap out and back in, and a prefix-cache hit, on a pool
     that many donated steps have updated in place: the swapped blocks
     and the shared prefix blocks read back what was written."""
-    kw = dict(max_slots=2, max_seq=64, kv_layout="paged", block_size=8,
+    kw = dict(max_slots=2, max_seq=64, block_size=8,
               kv_blocks=24, kv_host_blocks=16, prefix_cache=True)
     rng = np.random.default_rng(8)
     first, other = (rng.integers(1, 97, n).astype(np.int32)

@@ -4,16 +4,16 @@
 Each seeded schedule interleaves submit / step / preempt-resume (both
 recompute and host-tier SWAP flavours on offload-capable variants) /
 CANCEL ops — plus live speculative-depth retuning on spec-capable
-variants — over a pool of mixed-length prompts with shared AND
-divergent prefixes, across nine engine variants (dense + paged
-layouts, prefix cache on/off, token budget on/off in BOTH layouts —
-the dense+budget variant runs the staging-cache chunked-prefill path,
-paged+budget the fused one — tight block budgets that force LRU
-reclaim, speculative k up to 4 with mid-flight k toggling, a
-kitchen-sink variant stacking prefix cache + tight blocks + budget +
-speculation + a host KV tier, and a dedicated offload variant whose
-tight device budget forces prefix-block spills to host alongside
-swap-mode preemption), and asserts:
+variants — over a pool of mixed-length prompts with shared AND divergent
+prefixes, across nine engine variants (block sizes 16 and 8, prefix
+cache on/off, token budget on/off — on the all-linear tiny model every
+variant prefills fused; the hybrid layer-family sweeps run the
+staging-cache path — tight block budgets that force LRU reclaim,
+speculative k up to 4 with mid-flight k toggling, a kitchen-sink variant
+stacking prefix cache + tight blocks + budget + speculation + a host KV
+tier, and a dedicated offload variant whose tight device budget forces
+prefix-block spills to host alongside swap-mode preemption), and
+asserts:
 
 * after EVERY operation — allocator conservation:
   ``n_free + n_cached + n_live == n_blocks`` (disjoint id sets),
@@ -25,7 +25,7 @@ swap-mode preemption), and asserts:
   (disjoint id sets) with the live host population EXACTLY the union
   of the waiting swap snapshots' block lists;
 * for EVERY finished request — greedy output token-identical to a
-  per-request uninterrupted oracle run (fresh single-slot dense engine,
+  per-request uninterrupted oracle run (fresh single-slot engine,
   shared weights), regardless of how the schedule batched, preempted,
   chunked or block-shared it;
 * for EVERY cancelled request — whatever it emitted before the cancel
@@ -95,77 +95,74 @@ def _prompt_pool(cfg):
 
 def _check_invariants(eng, ctx: str) -> None:
     al = eng.allocator
-    if al is not None:
-        free, lru = set(al._free), set(al._lru)
-        out = set(al._outstanding)
-        assert not (free & lru) and not (free & out) and not (lru & out), \
-            f"{ctx}: allocator id sets overlap"
-        assert len(free) + len(lru) + len(out) == al.n_blocks, \
-            f"{ctx}: conservation broken " \
-            f"({len(free)}+{len(lru)}+{len(out)} != {al.n_blocks})"
-        assert al.n_available >= 0, f"{ctx}: n_available < 0"
-        assert al.n_reserved <= al.n_free + al.n_cached, \
-            f"{ctx}: reservations exceed reclaimable blocks"
-        counts = {}
-        for s in eng.slots:
-            if not s.active:
-                continue
-            assert len(set(s.blocks)) == len(s.blocks), \
-                f"{ctx}: slot maps a block twice"
-            for b in s.blocks:
-                counts[b] = counts.get(b, 0) + 1
-        assert 0 not in counts, f"{ctx}: null block mapped"
-        for b, c in counts.items():
-            assert al.refcount(b) == c, \
-                f"{ctx}: block {b} mapped by {c} slots, refcount " \
-                f"{al.refcount(b)}"
-        assert set(counts) == out, \
-            f"{ctx}: live blocks != mapped blocks"
-        if al.n_host_blocks:
-            hfree, hlive = set(al._host_free), set(al._host_live)
-            hcache = set(al._host_cache.values())
-            assert not (hfree & hcache) and not (hfree & hlive) \
-                and not (hcache & hlive), \
-                f"{ctx}: host-tier id sets overlap"
-            assert len(hfree) + len(hcache) + len(hlive) \
-                == al.n_host_blocks, \
-                f"{ctx}: host conservation broken " \
-                f"({len(hfree)}+{len(hcache)}+{len(hlive)} " \
-                f"!= {al.n_host_blocks})"
-            swap_ids = [b for w in eng.waiting if w.swap is not None
-                        for b in w.swap.host_blocks]
-            assert len(swap_ids) == len(set(swap_ids)), \
-                f"{ctx}: host block shared by two swap snapshots"
-            assert set(swap_ids) == hlive, \
-                f"{ctx}: swap-pinned host blocks != waiting snapshots"
-    if eng.block_tables is not None:
-        for i, s in enumerate(eng.slots):
-            if s.active and not s.prefilling:
-                n = len(s.blocks)
-                np.testing.assert_array_equal(
-                    eng.block_tables[i, :n], s.blocks, err_msg=ctx)
-                assert not eng.block_tables[i, n:].any(), ctx
-            else:
-                # mid-prefill slots point at the null block until the
-                # prefill lands, in BOTH modes: non-fused chunks write a
-                # staging cache, and fused chunks carry their own table
-                # row — either way the decode batch's dummy writes for
-                # this row must keep sinking into the null block
-                assert not eng.block_tables[i].any(), ctx
+    free, lru = set(al._free), set(al._lru)
+    out = set(al._outstanding)
+    assert not (free & lru) and not (free & out) and not (lru & out), \
+        f"{ctx}: allocator id sets overlap"
+    assert len(free) + len(lru) + len(out) == al.n_blocks, \
+        f"{ctx}: conservation broken " \
+        f"({len(free)}+{len(lru)}+{len(out)} != {al.n_blocks})"
+    assert al.n_available >= 0, f"{ctx}: n_available < 0"
+    assert al.n_reserved <= al.n_free + al.n_cached, \
+        f"{ctx}: reservations exceed reclaimable blocks"
+    counts = {}
+    for s in eng.slots:
+        if not s.active:
+            continue
+        assert len(set(s.blocks)) == len(s.blocks), \
+            f"{ctx}: slot maps a block twice"
+        for b in s.blocks:
+            counts[b] = counts.get(b, 0) + 1
+    assert 0 not in counts, f"{ctx}: null block mapped"
+    for b, c in counts.items():
+        assert al.refcount(b) == c, \
+            f"{ctx}: block {b} mapped by {c} slots, refcount " \
+            f"{al.refcount(b)}"
+    assert set(counts) == out, \
+        f"{ctx}: live blocks != mapped blocks"
+    if al.n_host_blocks:
+        hfree, hlive = set(al._host_free), set(al._host_live)
+        hcache = set(al._host_cache.values())
+        assert not (hfree & hcache) and not (hfree & hlive) \
+            and not (hcache & hlive), \
+            f"{ctx}: host-tier id sets overlap"
+        assert len(hfree) + len(hcache) + len(hlive) \
+            == al.n_host_blocks, \
+            f"{ctx}: host conservation broken " \
+            f"({len(hfree)}+{len(hcache)}+{len(hlive)} " \
+            f"!= {al.n_host_blocks})"
+        swap_ids = [b for w in eng.waiting if w.swap is not None
+                    for b in w.swap.host_blocks]
+        assert len(swap_ids) == len(set(swap_ids)), \
+            f"{ctx}: host block shared by two swap snapshots"
+        assert set(swap_ids) == hlive, \
+            f"{ctx}: swap-pinned host blocks != waiting snapshots"
+    for i, s in enumerate(eng.slots):
+        if s.active and not s.prefilling:
+            n = len(s.blocks)
+            np.testing.assert_array_equal(
+                eng.block_tables[i, :n], s.blocks, err_msg=ctx)
+            assert not eng.block_tables[i, n:].any(), ctx
+        else:
+            # mid-prefill slots point at the null block until the
+            # prefill lands, in BOTH modes: staging chunks (hybrid
+            # stacks) write a staging cache, and fused chunks carry
+            # their own table row — either way the decode batch's dummy
+            # writes for this row must keep sinking into the null block
+            assert not eng.block_tables[i].any(), ctx
 
 
 N_VARIANTS = 9
 
 
 def _engine_variant(cfg, variant: int):
-    """Rotate the engine configurations the schedules exercise. Paged
-    variants (1-5, 7) resolve ``prefill_mode="auto"`` to the FUSED path
-    on these all-linear configs — so the prefix-cache (2, 3, 7) and
-    speculative (4, 5, 7) variants prove token-identity of fused
-    prefill under preempt/resume/rollback/cancel interleavings. The
-    dense token-budget variant (6) and the hybrid layer-family sweeps
-    below cover the staging-cache round trip (the non-fused path dense
-    and hybrid layouts keep)."""
+    """Rotate the engine configurations the schedules exercise. On the
+    all-linear configs every variant prefills FUSED — so the
+    prefix-cache (2, 3, 7) and speculative (4, 5, 7) variants prove
+    token-identity of fused prefill under preempt/resume/rollback/cancel
+    interleavings. Variants 0 and 6 run the default block size 16, the
+    rest 8. The hybrid layer-family sweeps below cover the staging-cache
+    round trip (the path hybrid stacks keep)."""
     if variant == 0:
         return ContinuousBatchingEngine(
             cfg, max_slots=3, max_seq=MAX_SEQ, seed=0,
@@ -173,13 +170,13 @@ def _engine_variant(cfg, variant: int):
     if variant == 1:
         return ContinuousBatchingEngine(
             cfg, max_slots=3, max_seq=MAX_SEQ, seed=0,
-            share_from=_template(cfg), kv_layout="paged", block_size=8)
+            share_from=_template(cfg), block_size=8)
     if variant == 2:
         kw = {"prefix_cache": True} \
             if cfg.name in ("tiny", "tiny-tail") else {}
         return ContinuousBatchingEngine(
             cfg, max_slots=3, max_seq=MAX_SEQ, seed=0,
-            share_from=_template(cfg), kv_layout="paged", block_size=8,
+            share_from=_template(cfg), block_size=8,
             token_budget=12, **kw)
     if variant == 3:
         # tight block budget + prefix cache: forces queueing on memory,
@@ -188,7 +185,7 @@ def _engine_variant(cfg, variant: int):
             else {}
         return ContinuousBatchingEngine(
             cfg, max_slots=4, max_seq=MAX_SEQ, seed=0,
-            share_from=_template(cfg), kv_layout="paged", block_size=8,
+            share_from=_template(cfg), block_size=8,
             kv_blocks=14, **kw)
     # speculative variants: propose/verify/rollback interleaved with
     # everything above. Only rewind-capable stacks can speculate — the
@@ -198,19 +195,18 @@ def _engine_variant(cfg, variant: int):
     if variant == 4:
         return ContinuousBatchingEngine(
             cfg, max_slots=3, max_seq=MAX_SEQ, seed=0,
-            share_from=_template(cfg), kv_layout="paged", block_size=8,
+            share_from=_template(cfg), block_size=8,
             prefix_cache=bool(spec), **spec)
     if variant == 5:
         # tight budget + speculation: block rollback under LRU reclaim
         # pressure and budget-degraded effective k
         return ContinuousBatchingEngine(
             cfg, max_slots=3, max_seq=MAX_SEQ, seed=0,
-            share_from=_template(cfg), kv_layout="paged", block_size=8,
+            share_from=_template(cfg), block_size=8,
             kv_blocks=16, token_budget=12, **spec)
     if variant == 6:
-        # dense + token budget: the STAGING-cache chunked-prefill path
-        # (fused prefill is paged-only), interleaved with preempt/
-        # resume/cancel at chunk boundaries
+        # default block size + token budget: chunk boundaries that are
+        # not block boundaries, interleaved with preempt/resume/cancel
         return ContinuousBatchingEngine(
             cfg, max_slots=3, max_seq=MAX_SEQ, seed=0,
             share_from=_template(cfg), token_budget=12)
@@ -222,7 +218,7 @@ def _engine_variant(cfg, variant: int):
             else {}
         return ContinuousBatchingEngine(
             cfg, max_slots=4, max_seq=MAX_SEQ, seed=0,
-            share_from=_template(cfg), kv_layout="paged", block_size=8,
+            share_from=_template(cfg), block_size=8,
             kv_blocks=18, token_budget=12, kv_host_blocks=10,
             **kw, **spec)
     # KV offload: a tight device budget under prefix caching forces LRU
@@ -232,7 +228,7 @@ def _engine_variant(cfg, variant: int):
         else {}
     return ContinuousBatchingEngine(
         cfg, max_slots=4, max_seq=MAX_SEQ, seed=0,
-        share_from=_template(cfg), kv_layout="paged", block_size=8,
+        share_from=_template(cfg), block_size=8,
         kv_blocks=16, kv_host_blocks=12, **kw)
 
 
@@ -320,19 +316,18 @@ def _run_schedule(cfg, seed: int) -> None:
             f"{ctx} rid={rid}: tokens diverge from oracle " \
             f"({got.tokens} vs {_oracle(cfg, p, mn)})"
     al = eng.allocator
-    if al is not None:
-        assert al.n_live == 0 and al.n_reserved == 0, \
-            f"{ctx}: leaked references after drain"
-        assert al.n_free + al.n_cached == al.n_blocks, ctx
-        assert al.n_host_live == 0, \
-            f"{ctx}: leaked host-tier blocks after drain"
-        assert al.n_host_free + al.n_host_cached == al.n_host_blocks, ctx
+    assert al.n_live == 0 and al.n_reserved == 0, \
+        f"{ctx}: leaked references after drain"
+    assert al.n_free + al.n_cached == al.n_blocks, ctx
+    assert al.n_host_live == 0, \
+        f"{ctx}: leaked host-tier blocks after drain"
+    assert al.n_host_free + al.n_host_cached == al.n_host_blocks, ctx
 
 
 def test_fuzz_smoke_schedules():
     """Tier-1 slice of the sweep: a handful of schedules covering every
     variant of the canonical tiny model once — including the
-    speculative (4, 5), dense-staging (6), kitchen-sink (7) and KV
+    speculative (4, 5), block-16 budget (6), kitchen-sink (7) and KV
     offload (8) variants."""
     for seed in range(N_VARIANTS):
         _run_schedule(TINY, seed)
@@ -350,8 +345,9 @@ def test_fuzz_full_sweep_tiny():
 @pytest.mark.parametrize("kind", ["tail", "rglru", "windowed"])
 def test_fuzz_layer_families(kind):
     """Shorter sweeps over the other layer families: the unrolled tail
-    (prefix-cacheable) plus recurrent and windowed stacks, whose hybrid
-    dense/paged cache surgery must hold under the same schedules."""
+    (prefix-cacheable) plus recurrent and windowed stacks, whose
+    per-slot leaves beside the block pool (staging chunks, graft) must
+    hold under the same schedules."""
     cfg = KIND_CFGS[kind]
     for seed in range(max(8, N_SCHEDULES // 10)):
         _run_schedule(cfg, seed)

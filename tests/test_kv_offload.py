@@ -19,7 +19,6 @@ def _prompt(rng, n):
 def _swap_engine(**kw):
     kw.setdefault("max_slots", 2)
     kw.setdefault("max_seq", 64)
-    kw.setdefault("kv_layout", "paged")
     kw.setdefault("block_size", 8)
     kw.setdefault("kv_blocks", 24)
     kw.setdefault("kv_host_blocks", 16)
@@ -30,13 +29,13 @@ def _swap_engine(**kw):
 # ---------------------------------------------------------------- engine
 def test_ctor_validation():
     with pytest.raises(ValueError):
-        make_cont_engine(TINY, kv_layout="paged", block_size=8,
-                         kv_host_blocks=-1)
-    with pytest.raises(ValueError):
-        make_cont_engine(TINY, kv_host_blocks=8)  # dense: no block tier
+        make_cont_engine(TINY, block_size=8, kv_host_blocks=-1)
+    # an all-linear stack swaps whole blocks through the tier
+    eng = make_cont_engine(TINY, block_size=8, kv_host_blocks=8)
+    assert eng.swap_ok and eng.host_pool is not None
     # non-pageable stacks build the tier but never swap (recompute-only)
-    eng = make_cont_engine(KIND_CFGS["rglru"], kv_layout="paged",
-                           block_size=8, kv_host_blocks=8)
+    eng = make_cont_engine(KIND_CFGS["rglru"], block_size=8,
+                           kv_host_blocks=8)
     assert not eng.swap_ok
 
 
@@ -197,7 +196,6 @@ def _calibrated_swap_pool(preempt_mode="auto", **kw):
     kw.setdefault("max_slots", 1)
     kw.setdefault("max_seq", 64)
     kw.setdefault("preemption", True)
-    kw.setdefault("kv_layout", "paged")
     kw.setdefault("block_size", 8)
     kw.setdefault("kv_block_budget", 64)
     kw.setdefault("prefix_cache", True)
@@ -261,7 +259,7 @@ def test_pool_swap_resume_token_identical(n_devices):
     hog, urgent = _preempt_once(pool, rng)
     got = {r.request_id: r.tokens for r in pool.run_until_drained()}
     assert pool.n_swap_preempted == 1
-    ref = make_cont_engine(TINY, max_slots=1, kv_layout="paged",
+    ref = make_cont_engine(TINY, max_slots=1,
                            block_size=8)
     for rid, p, n in ((hog, prompts[0], 24), (urgent, prompts[1], 2)):
         want = ref.run([p], max_new_tokens=n)[0].tokens
@@ -349,7 +347,7 @@ def test_headroom_prices_preempted_snapshots():
     contribute its remaining work to retry_after_s — context re-prefill
     + remaining decode for recompute snapshots, remaining decode only
     for swapped ones (their KV is already resident on the host)."""
-    pool = make_pool(TINY, kv_layout="paged", block_size=8)
+    pool = make_pool(TINY, block_size=8)
     pool.scale_to("tiny", 1)
     pool.token_cost = lambda tp_degree=None: (1.0, 2.0)  # calibrated
     base = pool.admission_headroom("tiny", 8, 4)

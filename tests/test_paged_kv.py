@@ -1,7 +1,7 @@
 """Paged KV-cache subsystem: block allocator invariants, pure
 gather/scatter block surgery, prefill-graft round trips across layer
-kinds, dense/paged token identity, and the pool's shared block budget
-(docs/ARCHITECTURE.md §5, docs/RUNTIME.md §7)."""
+kinds, token identity against the round engine, and the pool's shared
+block budget (docs/ARCHITECTURE.md §5, docs/RUNTIME.md §7)."""
 import os
 
 import numpy as np
@@ -222,37 +222,51 @@ def test_paged_layer_kind_predicate():
 # ------------------------------------------------- graft round trips
 @pytest.mark.slow
 @pytest.mark.parametrize("kind", sorted(KIND_CFGS))
-@pytest.mark.parametrize("layout", ["dense", "paged"])
-def test_graft_round_trip_matches_fresh_run(kind, layout):
+@pytest.mark.parametrize("pool", ["roomy", "tight"])
+def test_graft_round_trip_matches_fresh_run(kind, pool):
     """prefill -> graft -> decode through the slot engine must equal a
-    fresh single-sequence round-engine run, for every layer-kind family
-    and both cache layouts."""
+    fresh single-sequence round-engine run, for every layer-kind family.
+    ``roomy`` is the default pool (a full row of blocks per slot), so
+    both slots run at once; ``tight`` holds 5 blocks of 8, which fits
+    only one of the three requests (3, 3 and 5 blocks) at a time, so
+    block-gated queueing orders every admission."""
     cfg = KIND_CFGS[kind]
-    kw = {"kv_layout": "paged", "block_size": 8} if layout == "paged" else {}
-    eng = ContinuousBatchingEngine(cfg, max_slots=2, max_seq=64, **kw)
+    kw = {"kv_blocks": 5} if pool == "tight" else {}
+    eng = ContinuousBatchingEngine(cfg, max_slots=2, max_seq=64,
+                                   block_size=8, **kw)
     ref = InferenceEngine(cfg, max_seq=64)
     rng = np.random.default_rng(3)
     prompts = [rng.integers(1, 97, n).astype(np.int32) for n in (5, 11, 17)]
-    res = eng.run(prompts, max_new_tokens=4)
+    for p in prompts:
+        eng.submit(p, max_new_tokens=4)
+    res, most_active = [], 0
+    while eng.waiting or eng.active_slots:
+        res.extend(eng.step())
+        most_active = max(most_active, len(eng.active_slots))
+    assert most_active == (1 if pool == "tight" else 2)
+    res.sort(key=lambda r: r.request_id)
+    assert len(res) == len(prompts)
     for p, r in zip(prompts, res):
         want = ref.generate([p], max_new_tokens=4).tokens[0]
         assert np.array_equal(r.tokens, want), kind
+    assert eng.allocator.n_free == eng.allocator.n_blocks
 
 
-def test_paged_matches_dense_on_mixed_lengths():
-    """Acceptance: token-identical greedy outputs across layouts on a
-    mixed-length prompt set that churns slots and block boundaries."""
+def test_paged_matches_round_engine_on_mixed_lengths():
+    """Acceptance: greedy outputs token-identical to the round engine,
+    prompt by prompt, on a mixed-length prompt set that churns slots and
+    block boundaries."""
     rng = np.random.default_rng(4)
     prompts = [rng.integers(1, 97, n).astype(np.int32)
                for n in (3, 14, 9, 30, 6, 22, 12, 5)]
-    dense = ContinuousBatchingEngine(TINY, max_slots=3, max_seq=64)
+    ref = InferenceEngine(TINY, max_seq=64)
     paged = ContinuousBatchingEngine(TINY, max_slots=3, max_seq=64,
-                                     kv_layout="paged", block_size=8)
-    rd = dense.run(prompts, max_new_tokens=7)
+                                     block_size=8)
     rp = paged.run(prompts, max_new_tokens=7)
-    assert [r.request_id for r in rp] == [r.request_id for r in rd]
-    for a, b in zip(rd, rp):
-        assert np.array_equal(a.tokens, b.tokens)
+    assert [r.request_id for r in rp] == list(range(len(prompts)))
+    for p, r in zip(prompts, rp):
+        want = ref.generate([p], max_new_tokens=7).tokens[0]
+        assert np.array_equal(r.tokens, want)
     # eviction really returned every block
     al = paged.allocator
     assert al.n_free == al.n_blocks and al.n_reserved == 0
@@ -264,7 +278,7 @@ def test_block_gated_admission_queues_and_drains():
     rest, and serves everything as evictions free blocks."""
     # 6 blocks of 8 = 48 tokens; each request needs bucket16 + 4 = 3 blocks
     eng = ContinuousBatchingEngine(TINY, max_slots=4, max_seq=64,
-                                   kv_layout="paged", block_size=8,
+                                   block_size=8,
                                    kv_blocks=6)
     rng = np.random.default_rng(5)
     prompts = [rng.integers(1, 97, 10).astype(np.int32) for _ in range(5)]
@@ -285,7 +299,7 @@ def test_submit_rejects_request_larger_than_block_pool():
     pool could never be admitted — submit() must raise instead of
     livelocking the FIFO head forever."""
     eng = ContinuousBatchingEngine(TINY, max_slots=2, max_seq=128,
-                                   kv_layout="paged", block_size=16,
+                                   block_size=16,
                                    kv_blocks=4)
     with pytest.raises(ValueError):
         # bucket 64 + 16 new = 80 tokens = 5 blocks > 4 total
@@ -304,7 +318,7 @@ def test_pool_rejects_never_admissible_request():
     queue (and everything behind it) forever."""
     pool = ModelInstancePool({TINY.name: TINY}, max_instances=2,
                              max_slots=2, max_seq=128, seed=0,
-                             kv_layout="paged", block_size=16,
+                             block_size=16,
                              kv_block_budget=8, blocks_per_instance=4)
     pool.scale_to(TINY.name, 1)
     big = pool.submit(TINY.name, np.arange(1, 51, dtype=np.int32) % 97,
@@ -325,7 +339,7 @@ def test_route_does_not_oversubscribe_blocks_in_one_pass():
     of being stranded in one engine's internal FIFO."""
     pool = ModelInstancePool({TINY.name: TINY}, max_instances=1,
                              max_slots=4, max_seq=64, seed=0,
-                             kv_layout="paged", block_size=8,
+                             block_size=8,
                              kv_block_budget=6, blocks_per_instance=6)
     pool.scale_to(TINY.name, 1)
     rng = np.random.default_rng(8)
@@ -344,51 +358,54 @@ def test_route_does_not_oversubscribe_blocks_in_one_pass():
 
 def test_admissible_reflects_blocks_and_slots():
     eng = ContinuousBatchingEngine(TINY, max_slots=2, max_seq=64,
-                                   kv_layout="paged", block_size=8,
+                                   block_size=8,
                                    kv_blocks=3)
     assert eng.admissible(4, 4)         # 16+4 tokens -> 3 blocks, all free
     eng.submit(np.arange(1, 5, dtype=np.int32), max_new_tokens=4)
     eng.admit()
     assert not eng.admissible(4, 4)     # blocks exhausted, slot free
-    dense = ContinuousBatchingEngine(TINY, max_slots=1, max_seq=64)
-    assert dense.admissible(4, 4)
-    dense.submit(np.arange(1, 5, dtype=np.int32))
-    dense.admit()
-    assert not dense.admissible(4, 4)   # no free slot
+    one = ContinuousBatchingEngine(TINY, max_slots=1, max_seq=64,
+                                   block_size=8)
+    assert one.admissible(4, 4)
+    one.submit(np.arange(1, 5, dtype=np.int32))
+    one.admit()
+    assert one.allocator.n_available > 0
+    assert not one.admissible(4, 4)     # blocks free, no free slot
 
 
 def test_stats_report_kv_occupancy_metrics():
-    dense = ContinuousBatchingEngine(TINY, max_slots=2, max_seq=64)
     paged = ContinuousBatchingEngine(TINY, max_slots=2, max_seq=64,
-                                     kv_layout="paged", block_size=8)
+                                     block_size=8)
     rng = np.random.default_rng(6)
     p = rng.integers(1, 97, 6).astype(np.int32)
-    for eng in (dense, paged):
-        eng.submit(p, max_new_tokens=8)
-        eng.step()
-        s = eng.stats()
-        for key in ("kv_used_tokens", "kv_allocated_tokens",
-                    "kv_waste_frac", "kv_reserved_tokens", "queue_depth"):
-            assert key in s
-        assert s["kv_used_tokens"] > 0
-    # dense commits the whole slab; paged only the sequence's blocks
-    assert dense.stats()["kv_allocated_tokens"] == 2 * 64
-    assert paged.stats()["kv_allocated_tokens"] < 2 * 64
-    assert paged.stats()["kv_waste_frac"] \
-        < dense.stats()["kv_waste_frac"]
+    paged.submit(p, max_new_tokens=8)
+    paged.step()
+    s = paged.stats()
+    for key in ("kv_used_tokens", "kv_allocated_tokens",
+                "kv_waste_frac", "kv_reserved_tokens", "queue_depth"):
+        assert key in s
+    assert s["kv_used_tokens"] > 0
+    # only the sequence's blocks are committed, not a full row per slot:
+    # bucket 16 prefilled (2 blocks) plus the block decode wrote into;
+    # used = the decode frontier 17 plus the row the next step writes
+    assert s["kv_allocated_tokens"] == 3 * 8 < 2 * 64
+    assert s["kv_used_tokens"] == 18
+    assert s["kv_waste_frac"] == pytest.approx(1 - 18 / 24)
+    # the 16 + 8 token reservation is wholly allocated by now
+    assert s["kv_reserved_tokens"] == 0
 
 
 # ------------------------------------------------- pool shared budget
 @pytest.mark.slow
 def test_pool_shared_block_budget_clamps_scale_to():
-    """One shared budget: dense slabs fit once, right-sized paged grants
-    fit four times; retiring instances returns their grant."""
-    # budget = one dense slab (2 slots * 64 tokens = 16 blocks of 8)
+    """One shared budget: full-length grants fit once, right-sized
+    grants fit four times; retiring instances returns their grant."""
+    # budget = one full grant (2 slots * 64 tokens = 16 blocks of 8)
     common = dict(max_instances=4, max_slots=2, max_seq=64, seed=0,
                   kv_block_budget=16, block_size=8)
-    dense = ModelInstancePool({TINY.name: TINY}, **common)
-    assert dense.scale_to(TINY.name, 3) == 1  # slab-clamped
-    paged = ModelInstancePool({TINY.name: TINY}, kv_layout="paged",
+    full = ModelInstancePool({TINY.name: TINY}, **common)
+    assert full.scale_to(TINY.name, 3) == 1  # grant-clamped
+    paged = ModelInstancePool({TINY.name: TINY},
                               blocks_per_instance=4, **common)
     assert paged.scale_to(TINY.name, 4) == 4  # right-sized grants fit
     assert paged.kv_blocks_free == 0
@@ -408,7 +425,7 @@ def test_pool_paged_serves_and_calibrates_occupancy():
     reports real occupancy, and calibrates tokens-per-sequence."""
     pool = ModelInstancePool({TINY.name: TINY}, max_instances=2,
                              max_slots=2, max_seq=64, seed=0,
-                             kv_layout="paged", block_size=8,
+                             block_size=8,
                              kv_block_budget=32)
     pool.scale_to(TINY.name, 2)
     rng = np.random.default_rng(7)
@@ -438,7 +455,7 @@ def test_pool_guard_uses_free_blocks():
 
     pool = ModelInstancePool({TINY.name: TINY}, max_instances=4,
                              max_slots=4, max_seq=64, seed=0,
-                             kv_layout="paged", block_size=8,
+                             block_size=8,
                              kv_block_budget=12, blocks_per_instance=12)
     cfg = ServingConfig(batch_sizes=(1, 2, 4),
                         concurrency_levels=(1, 2, 3))

@@ -37,13 +37,14 @@ def test_double_free_raises():
 
 # ------------------------------------------------- engine mechanics
 @pytest.mark.slow
-@pytest.mark.parametrize("layout", ["dense", "paged"])
-def test_preempt_returns_blocks_and_resumes_identically(layout):
+@pytest.mark.parametrize("token_budget", [None, 8],
+                         ids=["uncapped", "budget8"])
+def test_preempt_returns_blocks_and_resumes_identically(token_budget):
     """The core invariants: (1) a preempted sequence's blocks are fully
     returned to the allocator, (2) after resume the greedy output is
-    token-identical to an uninterrupted run."""
-    kw = {} if layout == "dense" else \
-        {"kv_layout": "paged", "block_size": 16}
+    token-identical to an uninterrupted run. Under an 8-token budget the
+    resume re-prefills its 36-token context over several iterations."""
+    kw = {"block_size": 16, "token_budget": token_budget}
     rng = np.random.default_rng(0)
     prompt = _prompt(rng, 20)
 
@@ -54,28 +55,32 @@ def test_preempt_returns_blocks_and_resumes_identically(layout):
     eng = ContinuousBatchingEngine(TINY, max_slots=2, max_seq=128,
                                    seed=0, **kw)
     eng.submit(prompt, max_new_tokens=10)
-    for _ in range(4):  # emit a few tokens, then evict mid-sequence
+    # emit a few tokens, then evict mid-sequence
+    while not (eng.decoding_slots
+               and eng.slots[eng.decoding_slots[0]].n_emitted >= 4):
         eng.step()
     [slot] = eng.decoding_slots
-    if layout == "paged":
-        held = len(eng.slots[slot].blocks) + eng.slots[slot].n_outstanding
-        avail_before = eng.allocator.n_available
+    held = len(eng.slots[slot].blocks) + eng.slots[slot].n_outstanding
+    avail_before = eng.allocator.n_available
     eng.preempt(slot)  # requeues at the engine FIFO head
     assert eng.n_preempted == 1
-    if layout == "paged":
-        assert eng.allocator.n_available == avail_before + held
-        assert eng.allocator.n_free == eng.allocator.n_blocks
-        assert eng.allocator.n_reserved == 0
+    assert eng.allocator.n_available == avail_before + held
+    assert eng.allocator.n_free == eng.allocator.n_blocks
+    assert eng.allocator.n_reserved == 0
     done = []
+    n_prefill_iters = 0
     for _ in range(60):
         done.extend(eng.step())
+        n_prefill_iters += bool(eng.prefilling_slots)
         if done:
             break
     assert len(done) == 1
     assert done[0].n_preempted == 1
     assert np.array_equal(done[0].tokens, ref)
-    if layout == "paged":
-        assert eng.allocator.n_free == eng.allocator.n_blocks
+    assert eng.allocator.n_free == eng.allocator.n_blocks
+    # an uncapped resume re-prefills in one iteration; a capped one
+    # leaves its context part-prefilled after all but the last
+    assert n_prefill_iters == (0 if token_budget is None else 4)
 
 
 @pytest.mark.slow

@@ -17,7 +17,7 @@ def _mk(prefix_cache=True, **kw):
     kw.setdefault("max_slots", 4)
     kw.setdefault("max_seq", 128)
     kw.setdefault("seed", 0)
-    return ContinuousBatchingEngine(TINY, kv_layout="paged", block_size=8,
+    return ContinuousBatchingEngine(TINY, block_size=8,
                                     prefix_cache=prefix_cache, **kw)
 
 
@@ -32,14 +32,13 @@ def _family(rng, n_prompts, prefix_len=24, tail_len=4):
 
 # ------------------------------------------------------------ gating
 def test_prefix_cache_requires_paged_and_pageable_layers():
-    with pytest.raises(ValueError):
-        ContinuousBatchingEngine(TINY, max_slots=2, max_seq=64,
-                                 prefix_cache=True)  # dense layout
+    assert ContinuousBatchingEngine(TINY, max_slots=2, max_seq=64,
+                                    prefix_cache=True).prefix_cache
     for kind in ("windowed", "rglru", "rwkv", "swa"):
         assert not supports_prefix_cache(KIND_CFGS[kind])
         with pytest.raises(ValueError):
             ContinuousBatchingEngine(KIND_CFGS[kind], max_slots=2,
-                                     max_seq=64, kv_layout="paged",
+                                     max_seq=64,
                                      block_size=8, prefix_cache=True)
     assert supports_prefix_cache(TINY)
     assert supports_prefix_cache(KIND_CFGS["tail"])
@@ -201,7 +200,7 @@ def test_router_prefix_affinity_concentrates_same_prefix():
     rng = np.random.default_rng(6)
     prompts = _family(rng, 3, prefix_len=24, tail_len=4)
     pool = make_pool(TINY, max_instances=2, max_slots=4, max_seq=64,
-                     kv_layout="paged", block_size=8, kv_block_budget=64,
+                     block_size=8, kv_block_budget=64,
                      prefix_cache=True)
     pool.scale_to(TINY.name, 2)
     first = pool.submit(TINY.name, prompts[0], slo_ms=60_000.0,
@@ -224,7 +223,7 @@ def test_pool_prefix_cache_skips_unsupported_models():
     cfgs = {TINY.name: TINY,
             KIND_CFGS["rglru"].name: KIND_CFGS["rglru"]}
     pool = ModelInstancePool(cfgs, max_instances=2, max_slots=2,
-                             max_seq=64, seed=0, kv_layout="paged",
+                             max_seq=64, seed=0,
                              block_size=8, prefix_cache=True)
     pool.scale_to(TINY.name, 1)
     pool.scale_to(KIND_CFGS["rglru"].name, 1)

@@ -27,6 +27,15 @@ def _pool(**kw):
     return ModelInstancePool({"tiny-a": TINY_A}, **kw)
 
 
+def test_pool_kv_layout_keyword_accepts_only_the_block_pool():
+    """The pool's ``kv_layout`` keyword selects nothing: ``"paged"`` (the
+    default) builds the pool, any other value is refused up front."""
+    assert _pool(kv_layout="paged").stats()["kv_budget_tokens"] == 0.0
+    for bad in ("dense", "blocks", ""):
+        with pytest.raises(ValueError, match="only KV layout"):
+            _pool(kv_layout=bad)
+
+
 # ------------------------------------------------------------ router
 def test_router_admits_by_deadline():
     pool = _pool(max_slots=1)
@@ -240,7 +249,7 @@ def test_pool_scheduler_drives_real_scaling():
 
 # ------------------------------------------------ speculation (fourth axis)
 def test_set_spec_k_applies_to_live_engines_and_spawns():
-    pool = _pool(kv_layout="paged", block_size=8, spec_k=4)
+    pool = _pool(block_size=8, spec_k=4)
     pool.scale_to("tiny-a", 1)
     eng = pool.live("tiny-a")[0].engine
     assert eng.spec_max == 4 and eng.spec_k == 4
@@ -255,7 +264,7 @@ def test_set_spec_k_applies_to_live_engines_and_spawns():
 
 
 def test_spec_cap_zero_pool_is_inert():
-    pool = _pool()  # dense, spec off
+    pool = _pool()  # spec off
     pool.scale_to("tiny-a", 1)
     eng = pool.live("tiny-a")[0].engine
     assert eng.spec_max == 0
@@ -271,7 +280,7 @@ def test_pool_speculative_serving_matches_baseline():
     base = ContinuousBatchingEngine(TINY_A, max_slots=2, max_seq=64, seed=0)
     want = {tuple(p): base.run([p], max_new_tokens=4)[0].tokens
             for p in prompts}
-    pool = _pool(kv_layout="paged", block_size=8, spec_k=4)
+    pool = _pool(block_size=8, spec_k=4)
     pool.scale_to("tiny-a", 1)
     rids = {pool.submit("tiny-a", p, slo_ms=60_000.0, max_new_tokens=4):
             tuple(p) for p in prompts}

@@ -2,12 +2,12 @@
 
 The sharded engine spans a 1D ``("model",)`` mesh: params land under
 ``engine_param_shardings`` (column-sharded wq/wk/wv, row-sharded wo),
-the KV pool — dense slabs and paged blocks alike — head-shards over the
-model axis, and decode/prefill/verify are jitted with NamedSharding
-in/out specs. The acceptance bar is TOKEN IDENTITY: a sharded engine
-must produce bit-identical greedy outputs to the unsharded engine on
-the same weights, across layouts (dense + paged), prefix cache on/off,
-speculation, and TP degrees 2 and 4.
+the KV block pool head-shards over the model axis, and
+decode/prefill/verify are jitted with NamedSharding in/out specs. The
+acceptance bar is TOKEN IDENTITY: a sharded engine must produce
+bit-identical greedy outputs to the unsharded engine on the same
+weights, across block sizes, prefix cache on/off, speculation, and TP
+degrees 2 and 4.
 
 Multi-device tests run in a SUBPROCESS with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (the flag must
@@ -80,25 +80,24 @@ def _run_sub(code: str) -> str:
 @pytest.mark.parametrize("tp", [2, 4])
 def test_sharded_engine_token_identity(tp):
     """Sharded greedy outputs == unsharded, at TP degree 2 and 4, for
-    the paged layout (prefix cache on AND off, token budget on) and the
-    dense layout. tp=4 head-shards a 4-head variant; the 2-head config
+    block size 8 (prefix cache on AND off, token budget on) and the
+    default block size 16. tp=4 head-shards a 4-head variant; the 2-head config
     proves the divisibility filter (heads replicate, projections still
     shard) on the 2-way mesh."""
     cfg = "TINY2" if tp == 2 else "TINY4"
     out = _run_sub(f"""
 cfg = {cfg}
 mesh = make_tp_mesh({tp})
-_, ref = toks(cfg, None, kv_layout="paged", block_size=8)
-donor, got = toks(cfg, mesh, kv_layout="paged", block_size=8)
+_, ref = toks(cfg, None, block_size=8)
+donor, got = toks(cfg, mesh, block_size=8)
 check("paged", ref, got)
-_, got = toks(cfg, mesh, kv_layout="paged", block_size=8,
+_, got = toks(cfg, mesh, block_size=8,
               prefix_cache=True, token_budget=24)
 check("paged_prefix_budget", ref, got)
-_, dref = toks(cfg, None)
-_, dgot = toks(cfg, mesh)
-check("dense", dref, dgot)
-_, got = toks(cfg, mesh, share_from=donor, kv_layout="paged",
-              block_size=8)
+_, ref16 = toks(cfg, None)
+_, got16 = toks(cfg, mesh)
+check("block16", ref16, got16)
+_, got = toks(cfg, mesh, share_from=donor, block_size=8)
 check("share_from", ref, got)
 try:
     ContinuousBatchingEngine(
@@ -109,7 +108,7 @@ except ValueError as e:
     assert "must divide" in str(e), e
     print("divide OK")
 """)
-    for name in ("paged", "paged_prefix_budget", "dense", "share_from",
+    for name in ("paged", "paged_prefix_budget", "block16", "share_from",
                  "divide"):
         assert f"{name} OK" in out
 
@@ -120,8 +119,8 @@ def test_sharded_engine_speculative_identity():
     stays token-identical to the unsharded plain-decode engine."""
     out = _run_sub("""
 mesh = make_tp_mesh(2)
-_, ref = toks(TINY2, None, kv_layout="paged", block_size=8)
-_, got = toks(TINY2, mesh, kv_layout="paged", block_size=8,
+_, ref = toks(TINY2, None, block_size=8)
+_, got = toks(TINY2, mesh, block_size=8,
               prefix_cache=True, spec_k=3)
 check("speculative", ref, got)
 """)
@@ -138,8 +137,8 @@ import dataclasses
 cfg = dataclasses.replace(TINY4, name="tiny4-glm", n_kv_heads=2,
                           qkv_bias=True, norm_eps=1e-5, rope="rope2d")
 mesh = make_tp_mesh(2)
-_, ref = toks(cfg, None, kv_layout="paged", block_size=8)
-eng, got = toks(cfg, mesh, kv_layout="paged", block_size=8)
+_, ref = toks(cfg, None, block_size=8)
+eng, got = toks(cfg, mesh, block_size=8)
 attn = eng.params["units"][0]["attn"]
 for name in ("bq", "bk", "bv"):
     assert tuple(attn[name].sharding.spec) == (None, "model"), name
@@ -162,14 +161,14 @@ def test_pool_spawns_tp_instances():
 from repro.serving.runtime import ModelInstancePool
 
 pool = ModelInstancePool({"tiny": TINY2}, max_instances=4, max_slots=2,
-                         max_seq=128, kv_layout="paged", block_size=8,
+                         max_seq=128, block_size=8,
                          kv_block_budget=64, tp_degree=2, n_devices=8)
 assert pool.scale_to("tiny", 2) == 2
 insts = pool.running("tiny")
 assert all(i.tp_degree == 2 for i in insts)
 assert all(i.engine.tp_degree == 2 for i in insts)
 assert pool.devices_in_use() == 4
-# dense-equiv grant 2*16=32 blocks; at tp=2 the budget charge halves
+# full grant 2*16=32 blocks; at tp=2 the budget charge halves
 # while the engine keeps the full grant
 for i in insts:
     assert i.kv_blocks == 16 and i.engine.allocator.n_blocks == 32

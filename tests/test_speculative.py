@@ -112,13 +112,11 @@ def test_speculation_gated_to_rewindable_stacks():
 
 # ---- differential token identity ---------------------------------------
 @pytest.mark.parametrize("kw", [
-    {},                                                   # dense
-    {"kv_layout": "paged", "block_size": 8},              # paged
-    {"kv_layout": "paged", "block_size": 8,
-     "prefix_cache": True},                               # paged+prefix
-    {"kv_layout": "paged", "block_size": 8,
-     "kv_blocks": 20},                                    # tight budget
-], ids=["dense", "paged", "prefix", "tight"])
+    {},                                                   # block size 16
+    {"block_size": 8},                                    # block size 8
+    {"block_size": 8, "prefix_cache": True},              # paged+prefix
+    {"block_size": 8, "kv_blocks": 20},                   # tight budget
+], ids=["default", "paged", "prefix", "tight"])
 @pytest.mark.parametrize("k", [2, 4])
 def test_speculative_token_identity(donor, kw, k):
     prompts = _prompts(4)
@@ -132,9 +130,8 @@ def test_speculative_token_identity(donor, kw, k):
     assert eng.n_spec_proposed >= eng.n_spec_accepted >= 0
     assert 0.0 <= eng.spec_accept_rate <= 1.0
     al = eng.allocator
-    if al is not None:
-        assert al.n_live == 0 and al.n_reserved == 0
-        assert al.n_free + al.n_cached == al.n_blocks
+    assert al.n_live == 0 and al.n_reserved == 0
+    assert al.n_free + al.n_cached == al.n_blocks
 
 
 def test_spec_k_live_toggle_token_identity(donor):
@@ -142,7 +139,7 @@ def test_spec_k_live_toggle_token_identity(donor):
     the output."""
     prompts = _prompts(4, seed=3)
     base = _spec_engine(donor).run(prompts, max_new_tokens=12)
-    eng = _spec_engine(donor, spec_k=4, kv_layout="paged", block_size=8)
+    eng = _spec_engine(donor, spec_k=4, block_size=8)
     for p in prompts:
         eng.submit(p, max_new_tokens=12)
     out, i = {}, 0
@@ -207,7 +204,7 @@ def test_rollback_then_redecode_token_identical(donor, seed, n, steps):
     exactly the uninterrupted greedy output."""
     prompts = _prompts(3, seed=seed % 7)
     base = _spec_engine(donor).run(prompts, max_new_tokens=10)
-    eng = _spec_engine(donor, kv_layout="paged", block_size=8)
+    eng = _spec_engine(donor, block_size=8)
     for p in prompts:
         eng.submit(p, max_new_tokens=10)
     for _ in range(steps):
@@ -233,7 +230,7 @@ def test_rollback_conserves_allocator(donor, n):
     """Occupancy counters stay conserved through rollback: every freed
     block returns to the pool and the reservation is restored, so the
     slot can still decode to completion without a mid-sequence OOM."""
-    eng = _spec_engine(donor, kv_layout="paged", block_size=8)
+    eng = _spec_engine(donor, block_size=8)
     for p in _prompts(3, seed=11):
         eng.submit(p, max_new_tokens=10)
     slot = _decode_until(eng, min_tokens=3)
@@ -267,7 +264,7 @@ def test_rollback_never_frees_shared_prefix_blocks(donor, n):
                                rng.integers(1, TINY.vocab_size, 4)
                                .astype(np.int32)]) for _ in range(2)]
     base = _spec_engine(donor).run(prompts, max_new_tokens=10)
-    eng = _spec_engine(donor, kv_layout="paged", block_size=8,
+    eng = _spec_engine(donor, block_size=8,
                        prefix_cache=True)
     # publish the prefix blocks, then admit the sharing pair
     eng.run([prompts[0]], max_new_tokens=2)
@@ -318,7 +315,7 @@ def test_rollback_rejects_bad_calls(donor):
 def test_draft_proposer_engine_token_identity(donor):
     prompts = _prompts(3, seed=9)
     base = _spec_engine(donor).run(prompts, max_new_tokens=8)
-    eng = _spec_engine(donor, spec_k=3, kv_layout="paged", block_size=8,
+    eng = _spec_engine(donor, spec_k=3, block_size=8,
                        proposer=DraftModelProposer(TINY, seed=0))
     out = eng.run(prompts, max_new_tokens=8)
     for r0, r in zip(base, out):

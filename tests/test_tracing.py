@@ -89,7 +89,7 @@ def _inside(span, parents) -> bool:
 
 def test_driver_turns_record_every_program_span_nested(tmp_path):
     pool = make_pool(TINY, max_instances=1, max_slots=2, max_seq=64,
-                     kv_layout="paged", block_size=8)
+                     block_size=8)
     sched = PoolScheduler(pool, ServingConfig(batch_sizes=(2,),
                                               concurrency_levels=(1,)),
                           slo_ms={TINY.name: 5000.0}, seed=0)
@@ -121,7 +121,7 @@ def test_driver_turns_record_every_program_span_nested(tmp_path):
 
 
 def test_speculative_steps_record_the_engine_spans(tmp_path):
-    eng = make_cont_engine(TINY, max_slots=2, max_seq=64, kv_layout="paged",
+    eng = make_cont_engine(TINY, max_slots=2, max_seq=64,
                            block_size=8, spec_k=2)
     prompts = [np.tile(_prompt(4, i), 3) for i in range(2)]
     rows = []
@@ -139,11 +139,13 @@ def test_speculative_steps_record_the_engine_spans(tmp_path):
     assert eng.n_decode_rows == sum(rows)
 
 
-@pytest.mark.parametrize("layout", ["paged", "dense"])
-def test_prefill_padding_and_decode_rows_counted(layout):
-    eng = make_cont_engine(TINY, max_slots=2, max_seq=64, kv_layout=layout,
-                           block_size=8)
+@pytest.mark.parametrize("token_budget", [None, 8],
+                         ids=["uncapped", "budget8"])
+def test_prefill_padding_and_decode_rows_counted(token_budget):
+    eng = make_cont_engine(TINY, max_slots=2, max_seq=64, block_size=8,
+                           token_budget=token_budget)
     # both in the 16-token bucket: 11 + 4 rows of padding, prefilled once
+    # (in two 8-row pieces each under the 8-token budget)
     eng.run([_prompt(5, 1), _prompt(12, 2)], max_new_tokens=3)
     st = eng.stats()
     assert st["n_prefill_pad_rows"] == 11 + 4
@@ -154,7 +156,7 @@ def test_prefill_padding_and_decode_rows_counted(layout):
 
 
 def test_resumed_prefill_counts_its_padding_again():
-    eng = make_cont_engine(TINY, max_slots=1, max_seq=64, kv_layout="paged",
+    eng = make_cont_engine(TINY, max_slots=1, max_seq=64,
                            block_size=8)
     eng.submit(_prompt(5, 3), 6)
     while not eng.decoding_slots:
@@ -169,7 +171,7 @@ def test_resumed_prefill_counts_its_padding_again():
 
 
 def test_compiled_steps_count_steps_not_shapes():
-    eng = make_cont_engine(TINY, max_slots=2, max_seq=64, kv_layout="paged",
+    eng = make_cont_engine(TINY, max_slots=2, max_seq=64,
                            block_size=8)
     eng.submit(_prompt(5, 4), 4)
     eng.step()                # a new prefill piece and the first decode
@@ -180,7 +182,7 @@ def test_compiled_steps_count_steps_not_shapes():
 
 def test_pool_stats_sum_the_engine_counters():
     pool = make_pool(TINY, max_instances=2, max_slots=2, max_seq=64,
-                     kv_layout="paged", block_size=8)
+                     block_size=8)
     pool.scale_to(TINY.name, 2)
     for i in range(4):
         pool.submit(TINY.name, _prompt(5 + 3 * i, i), slo_ms=5000.0,

@@ -30,7 +30,7 @@ FAMILIES = ["global", "rglru"]
 
 
 def _engine(cfg, weight_dtype):
-    return make_cont_engine(cfg, kv_layout="paged", block_size=8,
+    return make_cont_engine(cfg, block_size=8,
                             weight_dtype=weight_dtype)
 
 
@@ -100,7 +100,7 @@ def test_param_bytes_halve_in_bf16(family):
 
 
 def _pool_engine():
-    pool = make_pool(kv_layout="paged", block_size=8)
+    pool = make_pool(block_size=8)
     pool.scale_to("tiny", 1)
     return pool.live("tiny")[0].engine
 

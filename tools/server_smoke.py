@@ -54,7 +54,7 @@ def _start_server() -> int:
     t = threading.Thread(
         target=serve_http,
         kwargs=dict(models=[TINY.name], port=0, slo_ms=2000.0,
-                    max_instances=1, max_slots=2, kv_layout="paged",
+                    max_instances=1, max_slots=2,
                     max_queue_depth=2, ready=ready,
                     configs={TINY.name: TINY}),
         daemon=True)
