@@ -77,16 +77,19 @@ def _project_qkv(p: Dict, x: jax.Array, cfg: ModelConfig,
 
 
 def _sdpa(q, k, v, mask, scale) -> jax.Array:
-    """q (B,Sq,H,hd), k/v (B,T,KV,hd), mask (B,Sq,T) bool -> (B,Sq,H,hd)."""
+    """q (B,Sq,H,hd), k/v (B,T,KV,hd) — or (T,KV,hd), one set of keys
+    that every row attends under its own mask — and mask (B,Sq,T) bool
+    -> (B,Sq,H,hd)."""
     B, Sq, H, hd = q.shape
-    KV = k.shape[2]
+    KV = k.shape[-2]
     G = H // KV
     qg = q.reshape(B, Sq, KV, G, hd)
-    scores = jnp.einsum("bskgh,btkh->bkgst", qg.astype(jnp.float32),
+    kv = "btkh" if k.ndim == 4 else "tkh"
+    scores = jnp.einsum(f"bskgh,{kv}->bkgst", qg.astype(jnp.float32),
                         k.astype(jnp.float32)) * scale
     scores = jnp.where(mask[:, None, None, :, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bkgst,btkh->bskgh", probs, v.astype(jnp.float32))
+    out = jnp.einsum(f"bkgst,{kv}->bskgh", probs, v.astype(jnp.float32))
     return out.reshape(B, Sq, H, hd).astype(q.dtype)
 
 
@@ -213,7 +216,8 @@ def _gather_paged(pool: jax.Array, tables: jax.Array,
 def attention_decode_paged(p: Dict, x: jax.Array, cache: Dict,
                            tables: jax.Array, pos: jax.Array,
                            cfg: ModelConfig, *, impl: str = "auto",
-                           layer: Optional[jax.Array] = None
+                           layer: Optional[jax.Array] = None,
+                           live: Optional[jax.Array] = None
                            ) -> Tuple[jax.Array, Dict]:
     """Paged-counterpart of :func:`attention_decode` for linear
     (non-windowed) layers: the new K/V is scattered through the block
@@ -221,7 +225,13 @@ def attention_decode_paged(p: Dict, x: jax.Array, cache: Dict,
     positions are exactly ``slots <= pos`` — the same set the dense
     layout attends — so greedy decode is token-identical across
     layouts. With ``layer``, ``cache`` holds the scan-stacked pools and
-    only that layer's blocks are written and read, in place."""
+    only that layer's blocks are written and read, in place.
+
+    With ``live`` (3, T) — the decoding rows' blocks, flat: physical id,
+    owning row (-1 pads), first logical position — the ``auto`` path
+    gathers those T blocks once instead of every row's whole table, and
+    row ``b`` attends the positions it owns up to ``pos[b]``: the same
+    set, read from the live KV only (docs/ARCHITECTURE.md §5)."""
     B = x.shape[0]
     nb = tables.shape[1]
     bs = cache["k"].shape[-3]
@@ -236,6 +246,15 @@ def attention_decode_paged(p: Dict, x: jax.Array, cache: Dict,
             (cache["k"][layer], cache["v"][layer])
         out = kops.paged_decode_attention(q, k_pool, v_pool,
                                           tables, pos + 1, scale)
+    elif live is not None:
+        blocks, owner, first = live
+        k = _gather_paged(cache["k"], blocks[None], layer)[0]
+        v = _gather_paged(cache["v"], blocks[None], layer)[0]
+        owner = jnp.repeat(owner, bs)
+        kpos = (first[:, None] + jnp.arange(bs, dtype=jnp.int32)).reshape(-1)
+        valid = (owner[None, :] == jnp.arange(B, dtype=jnp.int32)[:, None]) \
+            & (kpos[None, :] <= pos[:, None])
+        out = _sdpa(q, k, v, valid[:, None, :], scale)
     else:
         k = _gather_paged(cache["k"], tables, layer)
         v = _gather_paged(cache["v"], tables, layer)

@@ -180,7 +180,8 @@ def _layer_decode(p: Dict, x: jax.Array, cfg: ModelConfig, kind: str,
             out, kv = attn.attention_decode_paged(
                 p["attn"], h, {"k": cache["k"], "v": cache["v"]},
                 ctx["block_tables"], ctx["pos"], cfg,
-                impl=ctx["attn_impl"], layer=layer)
+                impl=ctx["attn_impl"], layer=layer,
+                live=ctx["live_blocks"])
         else:
             out, kv = attn.attention_decode(
                 p["attn"], h, {"k": cache["k"], "v": cache["v"]},
@@ -767,12 +768,18 @@ class Model:
     # ---- forward: decode -----------------------------------------------
     def decode_step(self, params, cache, batch):
         """batch = {"tokens": (B,1), "pos": (B,)} plus, for paged caches,
-        "block_tables": (B, nb) int32; returns (logits, cache)."""
+        "block_tables": (B, nb) int32 and optionally "live_blocks":
+        (3, T) int32, the decoding rows' blocks flat (block id, owning
+        row or -1, first position), which paged layers then attend in
+        place of the tables' full width
+        (:func:`repro.models.attention.attention_decode_paged`);
+        returns (logits, cache)."""
         cfg = self.cfg
         params = self._cast(params)
         x = self.embed(params, batch["tokens"])
         ctx = {"pos": batch["pos"], "attn_impl": self.attn_impl,
-               "block_tables": batch.get("block_tables")}
+               "block_tables": batch.get("block_tables"),
+               "live_blocks": batch.get("live_blocks")}
         x, new_cache = _trunk_cached(params, x, cfg, cache, ctx,
                                      _layer_decode)
         logits = _lm_logits(params, x, cfg)

@@ -56,6 +56,16 @@ def _bucket(n: int, buckets=(1, 2, 4, 8, 16, 32, 64, 128)) -> int:
 
 SEQ_BUCKETS = (16, 32, 64, 128, 256, 512, 640)
 
+#: widths, in blocks, that a decode step's flat list of the decoding
+#: slots' live blocks is padded to: one compiled decode shape each
+#: (docs/ARCHITECTURE.md §5). A step whose live blocks outgrow the
+#: ceiling gathers every slot's whole table row instead, which costs
+#: less once the flat form's scores (slots x positions) grow past the
+#: rows it saves reading. ``tools/time_decode_forms.py`` times both
+#: forms at each width.
+DECODE_KV_BUCKETS = (32, 64, 128, 256)
+DECODE_FLAT_MAX_BLOCKS = 256
+
 
 def supports_prefix_cache(cfg: ModelConfig) -> bool:
     """Prefix caching shares physical KV *blocks*, so it needs every
@@ -999,6 +1009,15 @@ class ContinuousBatchingEngine:
             kv_blocks + 1, block_size, dtype)
         self.block_tables = np.zeros(
             (self.n_slots, self.blocks_per_slot), np.int32)
+        #: blocks a decode step's table gather reads (every slot's row,
+        #: where some layer keeps its KV in the pool), and the flat
+        #: widths this engine compiles: those under the ceiling that
+        #: read fewer
+        paged = any(paged_layer_kind(cfg, k) for k in cfg.layer_kinds())
+        self.table_blocks = self.block_tables.size if paged else 0
+        self.decode_buckets = tuple(
+            b for b in DECODE_KV_BUCKETS
+            if b <= DECODE_FLAT_MAX_BLOCKS and b < self.table_blocks)
         if mesh is None:
             self.cache = init_cache()
         else:
@@ -1065,7 +1084,12 @@ class ContinuousBatchingEngine:
         #: sequences every decode (or verify) call carried
         self.n_compiled_steps = 0
         self.n_decode_rows = 0
-        self._decode_warm = False
+        #: decode steps that read the flat live blocks, and the blocks
+        #: every decode step gathered (per layer, of K and of V alike)
+        self.n_decode_flat_steps = 0
+        self.n_decode_kv_blocks = 0
+        #: decode forms dispatched so far: a flat width, or 0 for tables
+        self._decode_shapes: Set[int] = set()
         self.prefill_shapes: Set[Tuple[int, int]] = set()
         self._next_id = 0
         self._t0 = time.perf_counter()
@@ -2026,9 +2050,10 @@ class ContinuousBatchingEngine:
         the queued prompts are (docs/ARCHITECTURE.md §5). Returns the
         sequences that finished this iteration. Inactive slots decode a
         dummy token in place (their cache row is masked by ``pos`` and
-        overwritten at the next admission), keeping the compiled decode
-        shape fixed at (n_slots, 1). Each part runs under its profiler
-        span (``repro.engine.*``, ``tracing.py``).
+        overwritten at the next admission), keeping the decode batch at
+        (n_slots, 1); only the width of its flat live-block list varies,
+        over a fixed ladder (``_live_blocks``). Each part runs under its
+        profiler span (``repro.engine.*``, ``tracing.py``).
         """
         self.last_step_compiled = False
         with span("repro.engine.admit"):
@@ -2068,8 +2093,17 @@ class ContinuousBatchingEngine:
                     self.block_tables[i, len(s.blocks)] = bid
                     s.blocks.append(bid)
             batch["block_tables"] = jnp.asarray(self.block_tables)
-        if not self._decode_warm:
-            self._decode_warm = True
+            live = self._live_blocks(active)
+            if live is None:
+                width = 0
+                self.n_decode_kv_blocks += self.table_blocks
+            else:
+                width = live.shape[1]
+                batch["live_blocks"] = jnp.asarray(live)
+                self.n_decode_flat_steps += 1
+                self.n_decode_kv_blocks += width
+        if width not in self._decode_shapes:
+            self._decode_shapes.add(width)
             self._note_compile()
         with span("repro.engine.decode_dispatch"):
             logits, self.cache = self._decode(self.params, self.cache, batch)
@@ -2078,6 +2112,46 @@ class ContinuousBatchingEngine:
         self.n_iters += 1
         with span("repro.engine.retire"):
             return self._retire(active, nxt)
+
+    def _live_blocks(self, active: List[int]) -> Optional[np.ndarray]:
+        """The flat decode input (3, width): the ``active`` slots' blocks
+        through ``pos`` as columns of (block id, slot, first position),
+        padded with the null block (slot -1) to the smallest width of
+        ``decode_buckets`` that holds them. None when none does: the step
+        then reads every slot's table row."""
+        act = np.asarray(active)
+        n = self.pos[act] // self.block_size + 1
+        total = int(n.sum())
+        width = next((b for b in self.decode_buckets if total <= b), None)
+        if width is None:
+            return None
+        owner = np.repeat(act, n)
+        col = np.arange(total) - np.repeat(np.cumsum(n) - n, n)
+        live = np.zeros((3, width), np.int32)
+        live[1, total:] = -1
+        live[:, :total] = (self.block_tables[owner, col], owner,
+                           col * self.block_size)
+        return live
+
+    def warmup_decode(self) -> None:
+        """Compile every decode form this engine can take, the table
+        gather and each flat width, before traffic. Every row decodes at
+        position 0 through an all-null table, so each call writes only
+        the null block, whatever the slots hold."""
+        batch = {"tokens": jnp.zeros((self.n_slots, 1), jnp.int32),
+                 "pos": jnp.zeros((self.n_slots,), jnp.int32),
+                 "block_tables": jnp.zeros(self.block_tables.shape,
+                                           jnp.int32)}
+        for width in (0,) + self.decode_buckets:
+            if width in self._decode_shapes:
+                continue
+            live = np.zeros((3, width), np.int32)
+            live[1] = -1
+            _, self.cache = self._decode(
+                self.params, self.cache,
+                dict(batch, live_blocks=jnp.asarray(live)) if width
+                else batch)
+            self._decode_shapes.add(width)
 
     def _retire(self, active: List[int],
                 nxt: np.ndarray) -> List[ContinuousResult]:
@@ -2430,6 +2504,8 @@ class ContinuousBatchingEngine:
                 a.nbytes for a in jax.tree.leaves(self.params))),
             "n_compiled_steps": float(self.n_compiled_steps),
             "n_decode_rows": float(self.n_decode_rows),
+            "n_decode_flat_steps": float(self.n_decode_flat_steps),
+            "n_decode_kv_blocks": float(self.n_decode_kv_blocks),
             "n_prefill_chunk_tokens": float(self.n_prefill_chunk_tokens),
             "n_prefill_pad_rows": float(self.n_prefill_pad_rows),
             "n_admitted": float(self.n_admitted),

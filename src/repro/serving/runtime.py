@@ -72,7 +72,8 @@ _SAMPLE_WINDOW = 512
 
 #: engine counters ``stats()`` sums over the live instances
 ENGINE_COUNTERS = ("n_decode_rows", "n_prefill_chunk_tokens",
-                   "n_prefill_pad_rows", "n_compiled_steps")
+                   "n_prefill_pad_rows", "n_compiled_steps",
+                   "n_decode_flat_steps", "n_decode_kv_blocks")
 
 
 @dataclasses.dataclass
@@ -1287,8 +1288,9 @@ class ModelInstancePool:
                seed: int = 0) -> None:
         """Compile the serving shapes before traffic: one prompt per
         length bucket per model (at an effectively-infinite SLO), drained
-        to completion, then metrics reset — so neither compile time nor
-        the warmup traffic pollutes SLO stats or the contention fit.
+        to completion, then every decode form of each live engine
+        (``warmup_decode``), then metrics reset — so neither compile time
+        nor the warmup traffic pollutes SLO stats or the contention fit.
         Callers scale first; models at m_c = 0 are skipped."""
         rng = np.random.default_rng(seed)
         submitted = False
@@ -1301,6 +1303,8 @@ class ModelInstancePool:
                 submitted = True
         if submitted:
             self.run_until_drained()
+        for inst in self.live():
+            inst.engine.warmup_decode()
         self.reset_metrics()
 
     # ---- accounting ------------------------------------------------------

@@ -55,6 +55,17 @@ KIND_CFGS = {
 }
 
 
+def flat_live_blocks(tables, pos, active, bs: int, width: int):
+    """A flat decode input built by a plain loop, the reference for the
+    engine's: each active row's blocks through ``pos`` as (block id,
+    row, first position), padded with the null block (row -1) to
+    ``width``."""
+    cols = [(int(tables[b, c]), b, c * bs)
+            for b in active for c in range(int(pos[b]) // bs + 1)]
+    cols += [(0, -1, 0)] * (width - len(cols))
+    return np.asarray(cols, np.int32).T
+
+
 def tiny_variant(**overrides) -> ModelConfig:
     """A one-off TINY derivative (name it, or collide in jit caches)."""
     return dataclasses.replace(TINY, **overrides)

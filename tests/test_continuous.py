@@ -3,7 +3,8 @@ simulator parity with round mode (docs/ARCHITECTURE.md §5/§6)."""
 import numpy as np
 import pytest
 
-from conftest import KIND_CFGS, TINY, make_cont_engine
+from conftest import (KIND_CFGS, TINY, flat_live_blocks, make_cont_engine,
+                      make_pool)
 from repro.config.base import ServingConfig
 from repro.core.baselines import FixedScheduler
 from repro.serving.bcedge import run_episode
@@ -112,6 +113,129 @@ def test_engine_rejects_enc_dec():
                               n_enc_layers=1)
     with pytest.raises(NotImplementedError):
         ContinuousBatchingEngine(enc, max_slots=2, max_seq=32)
+
+
+# ------------------------------------------------------------ decode forms
+#: prompt lengths, one wave each, whose live blocks (4 slots, blocks of
+#: 4, 512 positions) take each flat width in turn and then outgrow them:
+#: 5 blocks -> 32, 34 -> 64, 83 -> 128, 164 -> 256, 260 -> the tables
+DECODE_WAVES = ((16,), (64, 64), (128, 128, 64), (256, 256, 64, 64),
+                (256, 256, 256, 256))
+
+
+def _decode_forms(eng):
+    """Wrap ``eng._decode`` to record each call's form (its flat width,
+    0 for the table gather) and check its flat input against the loop
+    reference."""
+    forms, decode = [], eng._decode
+
+    def call(params, cache, batch):
+        live = batch.get("live_blocks")
+        forms.append(0 if live is None else live.shape[1])
+        if live is not None:
+            want = flat_live_blocks(eng.block_tables, eng.pos,
+                                    eng.decoding_slots, eng.block_size,
+                                    live.shape[1])
+            np.testing.assert_array_equal(np.asarray(live), want)
+        return decode(params, cache, batch)
+
+    eng._decode = call
+    return forms
+
+
+def _run_waves(submit, drain):
+    rng = np.random.default_rng(7)
+    for wave in DECODE_WAVES:
+        for n in wave:
+            submit(rng.integers(1, 97, n).astype(np.int32))
+        drain()
+
+
+def _wave_engine():
+    return make_cont_engine(TINY, max_slots=4, max_seq=512, block_size=4)
+
+
+@pytest.mark.parametrize("total", [1, 32, 33, 256, 257])
+def test_live_blocks_take_the_smallest_width_that_holds_them(total):
+    eng = _wave_engine()
+    n = [total // 4 + (i < total % 4) for i in range(4)]
+    active = [i for i in range(4) if n[i]]
+    ids = iter(range(1, total + 1))
+    for i in active:
+        eng.block_tables[i, :n[i]] = [next(ids) for _ in range(n[i])]
+        eng.pos[i] = (n[i] - 1) * eng.block_size + i % eng.block_size
+    live = eng._live_blocks(active)
+    width = next((b for b in (32, 64, 128, 256) if total <= b), None)
+    if width is None:
+        assert live is None
+    else:
+        np.testing.assert_array_equal(live, flat_live_blocks(
+            eng.block_tables, eng.pos, active, eng.block_size, width))
+
+
+def test_decode_form_keeps_greedy_tokens(monkeypatch):
+    """Greedy tokens of a mixed-length trace are the same whichever form
+    each decode step takes: all tables (ceiling 0), flat up to 64
+    blocks, flat up to 256."""
+    import repro.serving.engine as engine_mod
+
+    tokens, forms = {}, {}
+    for ceiling in (0, 64, 256):
+        monkeypatch.setattr(engine_mod, "DECODE_FLAT_MAX_BLOCKS", ceiling)
+        eng = _wave_engine()
+        forms[ceiling] = _decode_forms(eng)
+        done = []
+        _run_waves(lambda p: eng.submit(p, max_new_tokens=3),
+                   lambda: done.extend(eng.run([], max_new_tokens=3)))
+        tokens[ceiling] = {r.request_id: r.tokens.tolist() for r in done}
+    assert set(forms[0]) == {0}
+    assert set(forms[64]) == {0, 32, 64}
+    assert set(forms[256]) == {0, 32, 64, 128, 256}
+    assert tokens[64] == tokens[0] and tokens[256] == tokens[0]
+    assert len(tokens[0]) == sum(map(len, DECODE_WAVES))
+
+
+@pytest.mark.parametrize("kind", ["global", "swa"])
+def test_decode_counters_count_what_ran(kind):
+    """The counters add each step's flat width, or the tables' blocks;
+    an all-windowed stack keeps no KV in the pool, so it gathers none."""
+    eng = make_cont_engine(KIND_CFGS[kind], max_slots=4, max_seq=512,
+                           block_size=4)
+    forms = _decode_forms(eng)
+    _run_waves(lambda p: eng.submit(p, max_new_tokens=3),
+               lambda: eng.run([], max_new_tokens=3))
+    st = eng.stats()
+    assert st["n_decode_flat_steps"] == sum(w > 0 for w in forms)
+    assert st["n_decode_kv_blocks"] == sum(
+        w or eng.table_blocks for w in forms)
+    if kind == "global":
+        assert eng.decode_buckets == (32, 64, 128, 256)
+        assert eng.table_blocks == eng.n_slots * eng.blocks_per_slot
+        assert 0 in forms and st["n_decode_flat_steps"] > 0
+    else:
+        assert eng.decode_buckets == () and set(forms) == {0}
+        assert st["n_decode_kv_blocks"] == 0
+
+
+def test_warmup_compiles_every_decode_form():
+    """After the pool's warm-up, a trace that steps through every flat
+    width and the table form compiles nothing new."""
+    pool = make_pool(TINY, max_instances=1, max_slots=4, max_seq=512,
+                     block_size=4)
+    pool.scale_to(TINY.name, 1)
+    pool.warmup(prompt_lens=(16, 64, 128, 256))
+    eng = pool.live(TINY.name)[0].engine
+    if hasattr(eng._decode, "_cache_size"):
+        assert eng._decode._cache_size() == 1 + len(eng.decode_buckets)
+    compiled, flat = eng.n_compiled_steps, eng.n_decode_flat_steps
+    forms = _decode_forms(eng)
+    _run_waves(lambda p: pool.submit(TINY.name, p, slo_ms=600_000.0,
+                                     max_new_tokens=3),
+               pool.run_until_drained)
+    assert set(forms) == {0} | set(eng.decode_buckets)
+    assert eng.n_compiled_steps == compiled
+    assert pool.stats()["n_decode_flat_steps"] - flat \
+        == sum(w > 0 for w in forms)
 
 
 # ------------------------------------------------------------ donation

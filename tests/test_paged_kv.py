@@ -11,12 +11,12 @@ from hypothesis import strategies as st
 
 import jax.numpy as jnp
 
-from conftest import KIND_CFGS, TINY
+from conftest import KIND_CFGS, TINY, flat_live_blocks
 from repro.config.base import ModelConfig
 from repro.models.transformer import (gather_blocks, paged_layer_kind,
                                       scatter_blocks)
-from repro.serving.engine import (BlockAllocator, ContinuousBatchingEngine,
-                                  InferenceEngine)
+from repro.serving.engine import (DECODE_KV_BUCKETS, BlockAllocator,
+                                  ContinuousBatchingEngine, InferenceEngine)
 from repro.serving.runtime import ModelInstancePool
 
 
@@ -205,6 +205,61 @@ def test_scatter_gather_blocks_round_trip():
     np.testing.assert_array_equal(np.asarray(pool2[0]), 0.0)
     with pytest.raises(ValueError):
         scatter_blocks(pool, rows, jnp.asarray([1, 2], jnp.int32))
+
+
+@pytest.mark.parametrize("stacked", [False, True],
+                         ids=["unstacked", "stacked"])
+@pytest.mark.parametrize("pad", [0, 5], ids=["at_edge", "padded"])
+@pytest.mark.parametrize("width", DECODE_KV_BUCKETS)
+def test_flat_decode_matches_table_decode(width, pad, stacked):
+    """``attention_decode_paged`` over the flat live blocks attends what
+    the table form attends: rows 0-3 decode over ``width - pad`` live
+    blocks in all, each with a partly filled tail block, rows 0 and 1
+    sharing their first (prefix) block, row 4 inactive on the null
+    block; both forms write the same pool."""
+    import jax
+    from repro.models.attention import attention_decode_paged, attn_init
+
+    cfg = ModelConfig(name="t-gqa", family="dense", n_layers=2,
+                      d_model=32, n_heads=4, n_kv_heads=2, d_ff=64,
+                      vocab_size=97)
+    bs, B, total = 4, 5, width - pad
+    rng = np.random.default_rng(width + pad)
+    n = [total // 4 + (i < total % 4) for i in range(4)]
+    nb = max(n) + 2                       # dead columns on the null block
+    ids = rng.permutation(np.arange(1, total + 2)).astype(np.int32)
+    tables = np.zeros((B, nb), np.int32)
+    pos = np.zeros(B, np.int32)
+    start = 0
+    for b, k in enumerate(n):
+        tables[b, :k] = ids[start:start + k]
+        start += k
+        pos[b] = (k - 1) * bs + rng.integers(0, bs - 1)   # partial tail
+    tables[1, 0] = tables[0, 0]           # a prefix block both rows read
+    live = flat_live_blocks(tables, pos, range(4), bs, width)
+    assert live.shape == (3, width) and (live[1] >= 0).sum() == total
+    shape = (total + 3, bs, cfg.n_kv_heads, cfg.head_dim)
+    if stacked:
+        shape = (2,) + shape
+    cache = {name: jnp.asarray(rng.standard_normal(shape, np.float32))
+             for name in ("k", "v")}
+    p = attn_init(jax.random.PRNGKey(0), cfg, jnp.float32)
+    x = jnp.asarray(rng.standard_normal((B, 1, cfg.d_model), np.float32))
+    layer = jnp.int32(1) if stacked else None
+
+    @jax.jit
+    def decode(cache, live=None):
+        return attention_decode_paged(p, x, cache, jnp.asarray(tables),
+                                      jnp.asarray(pos), cfg, layer=layer,
+                                      live=live)
+
+    want, want_cache = decode(cache)
+    got, got_cache = decode(cache, jnp.asarray(live))
+    np.testing.assert_allclose(np.asarray(got[:4]), np.asarray(want[:4]),
+                               rtol=1e-5, atol=1e-5)
+    for name in ("k", "v"):
+        np.testing.assert_array_equal(np.asarray(got_cache[name]),
+                                      np.asarray(want_cache[name]))
 
 
 def test_paged_layer_kind_predicate():

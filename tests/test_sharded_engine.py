@@ -80,10 +80,11 @@ def _run_sub(code: str) -> str:
 @pytest.mark.parametrize("tp", [2, 4])
 def test_sharded_engine_token_identity(tp):
     """Sharded greedy outputs == unsharded, at TP degree 2 and 4, for
-    block size 8 (prefix cache on AND off, token budget on) and the
-    default block size 16. tp=4 head-shards a 4-head variant; the 2-head config
-    proves the divisibility filter (heads replicate, projections still
-    shard) on the 2-way mesh."""
+    block size 8 (prefix cache on AND off, token budget on; decode reads
+    the flat live blocks) and the default block size 16 (the tables).
+    tp=4 head-shards a 4-head variant; the 2-head config proves the
+    divisibility filter (heads replicate, projections still shard) on
+    the 2-way mesh."""
     cfg = "TINY2" if tp == 2 else "TINY4"
     out = _run_sub(f"""
 cfg = {cfg}
@@ -91,6 +92,9 @@ mesh = make_tp_mesh({tp})
 _, ref = toks(cfg, None, block_size=8)
 donor, got = toks(cfg, mesh, block_size=8)
 check("paged", ref, got)
+# blocks of 8: every decode step read the flat live blocks (width 32)
+assert donor.n_decode_flat_steps == donor.n_iters > 0
+print("flat OK")
 _, got = toks(cfg, mesh, block_size=8,
               prefix_cache=True, token_budget=24)
 check("paged_prefix_budget", ref, got)
@@ -108,8 +112,8 @@ except ValueError as e:
     assert "must divide" in str(e), e
     print("divide OK")
 """)
-    for name in ("paged", "paged_prefix_budget", "block16", "share_from",
-                 "divide"):
+    for name in ("paged", "flat", "paged_prefix_budget", "block16",
+                 "share_from", "divide"):
         assert f"{name} OK" in out
 
 

@@ -14,7 +14,8 @@ head_dim 128, block size 16), in float32 and bfloat16:
 * the engine's donated ``decode_step`` and ``prefill_chunk`` at the
   chat cell's shapes, which must update the pool in place: the whole
   pool aliased to the output, no pool-sized temporary, and no copy or
-  slice of the pool or of one layer of it;
+  slice of the pool or of one layer of it; the decode step's flat form
+  must also gather only its live blocks, not the tables' full width;
 * the same two steps of ChatGLM3-6B cut to 10 layers, the benchmark's
   largest model, which must fit one chip with both pools aliased;
 * both steps of both models with the weights stored in bfloat16 under
@@ -145,13 +146,14 @@ def test_qwen3_paged_decode_step_compiles_for_v5e(one_chip):
 CHAT_SLOTS, CHAT_SEQ, CHAT_BS = 16, 768, 16
 
 
-def _chat_step(one_chip, cfg, step, weight_dtype=jnp.float32):
+def _chat_step(one_chip, cfg, step, weight_dtype=jnp.float32, flat=0):
     """``step`` of ``cfg`` at the chat cell's shapes (16 slots, 768
     positions, 769 blocks of 16, a float32 pool and activations, weights
     in ``weight_dtype``), jitted as the engine jits it
-    (``jit_cache_step``, the cache donated) and compiled for the chip.
-    Returns the executable, the bytes of one stacked pool and the block
-    count."""
+    (``jit_cache_step``, the cache donated) and compiled for the chip;
+    with ``flat``, the decode step's flat form over that many live
+    blocks. Returns the executable, the bytes of one stacked pool and
+    the block count."""
     from repro.models import build_model
     from repro.serving.engine import jit_cache_step
 
@@ -171,6 +173,8 @@ def _chat_step(one_chip, cfg, step, weight_dtype=jnp.float32):
     batch = {"tokens": _sds((rows, width), jnp.int32, one_chip),
              "pos": _sds((rows,), jnp.int32, one_chip),
              "block_tables": _sds((rows, per_slot), jnp.int32, one_chip)}
+    if flat:
+        batch["live_blocks"] = _sds((3, flat), jnp.int32, one_chip)
     compiled = jit_cache_step(getattr(model, step)).lower(
         params, cache, batch).compile()
     pool_bytes = cfg.n_layers * n_blocks * bs * cfg.n_kv_heads \
@@ -201,6 +205,27 @@ def test_qwen3_paged_step_updates_pool_in_place(one_chip, step):
         rf"|= {shape}\S* (?:copy|dynamic-slice|dynamic-update-slice)\(")
     hlo = compiled.as_text()
     assert not [line for line in hlo.splitlines() if moved.search(line)]
+
+
+def test_qwen3_flat_decode_reads_only_live_blocks(one_chip):
+    """The decode step's flat form, at the chat cell's shapes with the
+    weights in bfloat16 as served and 128 live blocks: both pools still
+    aliased in place, no temporary as large as one, and the KV gathered
+    at the live width, never at the tables' full width (16 slots x 48
+    blocks)."""
+    from repro.config import get_config
+
+    cfg = get_config("qwen3-0.6b")
+    compiled, pool_bytes, _ = _chat_step(one_chip, cfg, "decode_step",
+                                         jnp.bfloat16, flat=128)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes
+    kv = rf"{CHAT_BS},{cfg.n_kv_heads},{cfg.head_dim}\]"
+    hlo = compiled.as_text()
+    assert re.search(rf"\[128,{kv}", hlo)
+    table = CHAT_SLOTS * CHAT_SEQ // CHAT_BS
+    assert not re.search(rf"\[{table},{kv}", hlo)
 
 
 #: one v5e chip's HBM as the runtime offers it to a program
